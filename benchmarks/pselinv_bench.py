@@ -38,7 +38,6 @@ timeline statistics off the ``profile_rounds`` segmented replay
 asserted under PlanLint's static imbalance WARN threshold)."""
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 import time
@@ -49,6 +48,7 @@ import jax
 
 from repro.core import sparse
 from repro.core.selinv import compare_with_oracle, selected_inverse
+from repro.jaxenv import ROOT, host_mesh_env, in_host_mesh
 
 from .common import csv_row, reemit_child_rows, timed
 
@@ -139,17 +139,13 @@ def _hlo_lint_bench():
 
 def _run_ir_compare(full: bool):
     """Re-exec the sweep comparison with 8 host devices."""
-    if len(jax.devices()) >= 8:
+    if in_host_mesh(8):
         return _ir_compare_child(full)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + root
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.pselinv_bench", "--ir-compare"]
         + (["--full"] if full else []),
-        env=env, cwd=root, capture_output=True, text=True, timeout=900)
+        env=host_mesh_env(8), cwd=ROOT, capture_output=True, text=True,
+        timeout=900)
     reemit_child_rows(r.stdout)
     if r.returncode != 0:
         raise RuntimeError(r.stderr[-2000:])
@@ -352,16 +348,13 @@ def _run_serve_bench(full: bool):
     identity between every batched result and its unbatched solve is
     only meaningful in double precision)."""
     import jax.numpy  # noqa: F401 — force config resolution
-    if jax.config.jax_enable_x64:
+    if in_host_mesh(1) and jax.config.jax_enable_x64:
         return _serve_bench_child(full)
-    env = dict(os.environ)
-    env["JAX_ENABLE_X64"] = "1"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + root
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.pselinv_bench",
          "--serve-bench"] + (["--full"] if full else []),
-        env=env, cwd=root, capture_output=True, text=True, timeout=900)
+        env=host_mesh_env(1, x64=True), cwd=ROOT, capture_output=True,
+        text=True, timeout=900)
     reemit_child_rows(r.stdout)
     if r.returncode != 0:
         raise RuntimeError(r.stderr[-2000:])

@@ -25,6 +25,9 @@ def main() -> None:
                          "selinv,treecomm")
     args = ap.parse_args()
 
+    from repro.jaxenv import enable_compile_cache
+    enable_compile_cache()
+
     from . import (fig5_heatmap, fig8_scaling, fig9_ratio, kernels_bench,
                    pselinv_bench, table1_volume, treecomm_bench)
 

@@ -1,8 +1,7 @@
 """Tree-collective HLO accounting: hierarchical (RS + tree cross-pod AR +
 AG) vs flat psum gradient sync — collective op counts/bytes from compiled
-HLO on an 8-device host mesh (2 pods × 4). Requires the bench process to
-be launched with XLA_FLAGS=--xla_force_host_platform_device_count=8;
-skips gracefully otherwise."""
+HLO on an 8-device CPU host mesh (2 pods × 4); re-execs itself as a
+host-mesh child when it is not one already. CPU smoke, not device speed."""
 from __future__ import annotations
 
 import numpy as np
@@ -14,24 +13,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.comm.hierarchical import hierarchical_allreduce
 from repro.compat import shard_map
 from repro.core.trees import TreeKind
+from repro.jaxenv import ROOT, host_mesh_env, in_host_mesh
 
 from .common import csv_row, reemit_child_rows
 
 
 def run(full: bool = False):
-    if len(jax.devices()) < 8:
-        # re-exec in a subprocess with 8 host devices
-        import os
+    if not in_host_mesh(8):
+        # re-exec in a subprocess with 8 CPU host devices
         import subprocess
         import sys
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + root
         r = subprocess.run(
             [sys.executable, "-m", "benchmarks.treecomm_bench"]
             + (["--full"] if full else []),
-            env=env, cwd=root, capture_output=True, text=True, timeout=600)
+            env=host_mesh_env(8), cwd=ROOT, capture_output=True,
+            text=True, timeout=600)
         reemit_child_rows(r.stdout)
         if r.returncode != 0:
             raise RuntimeError(r.stderr[-2000:])

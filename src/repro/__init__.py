@@ -1,3 +1,3 @@
-"""repro — tree-based asynchronous restricted collectives for parallel
-selected inversion (PSelInv), as a multi-pod JAX framework."""
+"""repro — parallel selected inversion (PSelInv) with tree-based
+restricted collectives, as a JAX engine and server that run on TPU."""
 __version__ = "0.1.0"

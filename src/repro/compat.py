@@ -1,14 +1,9 @@
-"""Version compatibility shims for the JAX surface this repo touches.
+"""The JAX surface this repo touches, in one place.
 
-Two APIs moved/changed shape across the JAX versions we support:
-
-* ``shard_map`` — exported as ``jax.shard_map`` on newer releases, lives
-  in ``jax.experimental.shard_map`` on older ones (e.g. 0.4.x).  Import
-  :func:`shard_map` from here everywhere instead of touching ``jax``
-  directly.
-* ``Compiled.cost_analysis()`` — returns a single dict on new JAX, a
-  per-computation *list* of dicts on older releases.  Use
-  :func:`cost_analysis_dict` to always get one flat dict.
+* ``shard_map`` — ``jax.shard_map``; import it from here everywhere.
+* ``Compiled.cost_analysis()`` — may return ``None`` for a program XLA
+  has no cost model for; use :func:`cost_analysis_dict` to always get a
+  flat dict.
 """
 from __future__ import annotations
 
@@ -16,27 +11,12 @@ from typing import Any, Dict
 
 import jax
 
-try:  # JAX >= 0.4.35 with the top-level export
-    shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # older JAX: experimental home
-    from jax.experimental.shard_map import shard_map  # type: ignore
+shard_map = jax.shard_map
 
 __all__ = ["shard_map", "cost_analysis_dict"]
 
 
 def cost_analysis_dict(compiled: Any) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to a single flat dict
-    (older JAX returns a list with one entry per computation)."""
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: Dict[str, float] = {}
-        for entry in cost:
-            for k, v in (entry or {}).items():
-                if isinstance(v, (int, float)):
-                    merged[k] = merged.get(k, 0.0) + float(v)
-                else:
-                    merged.setdefault(k, v)
-        return merged
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a dict (empty when XLA reports
+    none)."""
+    return dict(compiled.cost_analysis() or {})

@@ -34,6 +34,7 @@ multipliers only) — ``launch/dryrun.py`` re-exports it unchanged.
 """
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -45,9 +46,12 @@ __all__ = [
     "jaxpr_collectives", "jaxpr_converts", "is_stablehlo",
 ]
 
+#: a collective op, synchronous or the ``-start`` half of an async pair
+#: (TPU HLO spells collectives as ``*-start``/``*-done``; the ``-done``
+#: half moves nothing new and never matches)
 _COLL_RE = re.compile(
     r"\b(all-gather|all-reduce|reduce-scatter|all-to-all"
-    r"|collective-permute)\b")
+    r"|collective-permute)(-start)?\b(?!-)")
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\]")
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -118,8 +122,6 @@ def computation_multipliers(txt: str, *,
     while body; the dryrun byte pricing keeps the historical
     while-edges-only behavior."""
     blocks = split_computations(txt)
-    mult: Dict[str, int] = {name: 1 for name in blocks}
-
     edges = []  # (parent, callee, trip)
     for parent, body_txt in blocks.items():
         for cond, body in _WHILE_RE.findall(body_txt):
@@ -138,35 +140,52 @@ def computation_multipliers(txt: str, *,
                 for callee in _CALLS_RE.findall(line):
                     edges.append((parent, callee, 1))
 
-    changed = True
-    while changed:                      # propagate through nesting
-        changed = False
-        for parent, body, trip in edges:
-            want = mult.get(parent, 1) * trip
-            if mult.get(body, 1) != want:
-                mult[body] = want
-                changed = True
-    return mult
+    return _execution_counts(edges, blocks)
 
 
-def _line_bytes(line: str, opname: str) -> int:
-    lhs_rhs = line.split("=", 1)[1]
-    head = lhs_rhs[:lhs_rhs.find(opname)]
-    if "%" in head:
-        # ``opname`` first appears inside the operand list (e.g.
-        # ``%add = f32[...] add(... %all-reduce.1)``): this line *uses* a
-        # collective result, it does not define one — don't count it.
-        return 0
-    nbytes = 0
-    for dt, dims in _SHAPE_RE.findall(head):
-        if dt not in DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        nbytes += n * DTYPE_BYTES[dt]
-    return nbytes
+def _execution_counts(edges, names) -> Dict[str, int]:
+    """Execution count of each computation (or function) in ``names``
+    and every callee of ``edges`` ((caller, callee, k): the callee runs
+    k times per caller execution). A callee runs once per execution of
+    each of its call sites, summed over callers — shared helpers are
+    called both inside and outside loops — and a computation nobody
+    calls (the entry) runs once."""
+    callers: Dict[str, List[Tuple[str, int]]] = {}
+    for caller, callee, k in edges:
+        callers.setdefault(callee, []).append((caller, k))
+    counts: Dict[str, int] = {}
+
+    def count(name: str) -> int:
+        if name not in counts:
+            sites = callers.get(name)
+            counts[name] = (sum(count(c) * k for c, k in sites)
+                            if sites else 1)
+        return counts[name]
+
+    for name in set(names) | set(callers):
+        count(name)
+    return counts
+
+
+def _defining_collective(line: str):
+    """``(op, shapes)`` when the HLO ``line`` defines a collective:
+    ``op`` in the dash vocabulary and ``shapes`` the (dtype, dims) of
+    its result. An async ``-start`` yields its largest buffer alone
+    (its tuple also carries the operand and context scalars). None for
+    other lines and for lines that only *use* a collective result, e.g.
+    ``%add = f32[...] add(... %all-reduce.1)``."""
+    if "=" not in line:
+        return None
+    rhs = line.split("=", 1)[1]
+    m = _COLL_RE.search(rhs)
+    if m is None or "%" in rhs[:m.start()]:
+        return None
+    shapes = [(dt, tuple(int(d) for d in dims.split(",") if d))
+              for dt, dims in _SHAPE_RE.findall(rhs[:m.start()])
+              if dt in DTYPE_BYTES]
+    if m.group(2) and shapes:
+        shapes = [max(shapes, key=lambda s: math.prod(s[1]))]
+    return m.group(1), shapes
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, float]:
@@ -181,13 +200,14 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
     for name, body in blocks.items():
         k = mult.get(name, 1)
         for line in body.splitlines():
-            line = line.strip()
-            m = _COLL_RE.search(line)
-            if not m or "=" not in line:
+            coll = _defining_collective(line.strip())
+            if coll is None:
                 continue
-            nbytes = _line_bytes(line, m.group(1))
+            op, shapes = coll
+            nbytes = sum(math.prod(dims) * DTYPE_BYTES[dt]
+                         for dt, dims in shapes)
             if nbytes:
-                out[m.group(1)] = out.get(m.group(1), 0.0) + float(nbytes) * k
+                out[op] = out.get(op, 0.0) + float(nbytes) * k
     return out
 
 
@@ -237,17 +257,6 @@ def _parse_sh_pairs(line: str) -> Optional[Tuple[Tuple[int, int], ...]]:
                  re.findall(r"\[(\d+),\s*(\d+)\]", m.group(1)))
 
 
-def _parse_hlo_result(line: str) -> Tuple[Tuple[int, ...], str]:
-    lhs_rhs = line.split("=", 1)[1]
-    coll = _COLL_RE.search(lhs_rhs)
-    head = lhs_rhs[:coll.start()] if coll else lhs_rhs
-    m = _SHAPE_RE.search(head)
-    if not m:
-        return (), ""
-    dims = tuple(int(d) for d in m.group(2).split(",") if d)
-    return dims, m.group(1)
-
-
 def _parse_sh_result(line: str) -> Tuple[Tuple[int, ...], str]:
     m = _SH_RESULT_RE.search(line.rstrip())
     if not m:
@@ -270,16 +279,11 @@ def _parse_hlo_collectives(txt: str, *, through_calls: bool
             cur = None
             continue
         ls = line.strip()
-        m = _COLL_RE.search(ls)
-        if not m or "=" not in ls:
+        coll = _defining_collective(ls)
+        if coll is None:
             continue
-        op = m.group(1)
-        # defining-line guard, same as _line_bytes: an operand reference
-        # like ``add(... %collective-permute.1)`` is a *use*
-        lhs_rhs = ls.split("=", 1)[1]
-        if "%" in lhs_rhs[:lhs_rhs.find(op)]:
-            continue
-        dims, dtype = _parse_hlo_result(ls)
+        op, shapes = coll
+        dtype, dims = shapes[0] if shapes else ("", ())
         out.append(CollectiveOp(
             op=op, pairs=_parse_hlo_pairs(ls) if
             op == "collective-permute" else None,
@@ -345,19 +349,10 @@ def _parse_sh_collectives(txt: str) -> List[CollectiveOp]:
         while loops and depth <= loops[-1][0]:
             loops.pop()
     # pass 2: propagate function execution counts through call edges
-    fmult: Dict[str, int] = {}
-    fmult["main"] = 1
-    changed = True
-    while changed:
-        changed = False
-        for caller, callee, k in edges:
-            want = fmult.get(caller, 1) * k
-            if fmult.get(callee, 1) != want:
-                fmult[callee] = want
-                changed = True
+    fmult = _execution_counts(edges, {f for f, _ in ops})
     return [CollectiveOp(op=c.op, pairs=c.pairs, dims=c.dims,
                          dtype=c.dtype, computation=c.computation,
-                         multiplier=c.multiplier * fmult.get(f, 1),
+                         multiplier=c.multiplier * fmult[f],
                          line=c.line)
             for f, c in ops]
 

@@ -463,7 +463,7 @@ def _traced_sweep(prog, *, batched: bool = False, dtype=None,
         mk = make_sweep
     P_dev = prog.pr * prog.pc
     if mesh is None:
-        mesh = AbstractMesh((("xy", P_dev),))
+        mesh = AbstractMesh((P_dev,), ("xy",))
     spec = P(None, "xy") if batched else P("xy")
     fn = shard_map(mk(prog, batched=batched), mesh=mesh,
                    in_specs=(spec, spec), out_specs=spec)

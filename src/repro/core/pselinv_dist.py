@@ -361,7 +361,8 @@ def make_sweep(prog: PSelInvProgram, batched: bool = False):
             krs = jnp.asarray(lv.krs)
             Arow = _gi(Ainv_f[:-1].reshape(nbr, nbc, b, b), krs)
             S = jnp.einsum("kjab,kjcb->kac",
-                           Arow * cm[:, :, None, None], Uh_m)
+                           Arow * cm[:, :, None, None], Uh_m,
+                           precision=lax.Precision.HIGHEST)
             rm = jnp.take(jnp.asarray(lv.diag_rowmask, dtype=dtype), r,
                           axis=0)                          # (nk,)
             S = S * rm[:, None, None]
@@ -428,7 +429,8 @@ def _phase_scomp(arena, ut, cm, krs, rm, N, nbr, nbc, b, base_s):
     Uh_m = _gi(arena, ut).reshape(nk, nbc, b, b) * cm[:, :, None, None]
     Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
     Arow = _gi(Ainv, krs)
-    S = jnp.einsum("kjab,kjcb->kac", Arow * cm[:, :, None, None], Uh_m)
+    S = jnp.einsum("kjab,kjcb->kac", Arow * cm[:, :, None, None], Uh_m,
+                   precision=lax.Precision.HIGHEST)
     return lax.dynamic_update_slice(
         arena, S * rm[:, None, None], (base_s, 0, 0))
 
@@ -1055,7 +1057,8 @@ def make_sweep_unrolled(prog: PSelInvProgram):
                              axis=1)                       # (nbc,)
             Uh_m = Uh * cmask[:, None, None]
             # A⁻¹(J,I) @ L̂(I,K) = Ainv[i,j] @ Uh[j]ᵀ
-            partial = jnp.einsum("ijab,jcb->iac", Ainv, Uh_m)
+            partial = jnp.einsum("ijab,jcb->iac", Ainv, Uh_m,
+                                 precision=lax.Precision.HIGHEST)
 
             # ---- (c) row-reduce onto column K%pc ------------------------
             partial = _apply_rounds(partial, it.reduce_rounds, "xy", "reduce")
@@ -1085,7 +1088,7 @@ def make_sweep_unrolled(prog: PSelInvProgram):
 
             # ---- (2,3) diagonal:  A⁻¹(K,K) = Dinv − (Σ A⁻¹(K,I)L̂(I,K))ᵀ
             S = jnp.einsum("jab,jcb->ac", Ainv[kr] * cmask[:, None, None],
-                           Uh_m)
+                           Uh_m, precision=lax.Precision.HIGHEST)
             S = jnp.where(r == krow, S, jnp.zeros_like(S))
             S = _apply_rounds(S, it.diag_reduce_rounds, "xy", "reduce")
             Ainv = Ainv.at[kr, kc].set(

@@ -19,7 +19,10 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_tiles: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: f32 operands take full-precision MXU passes, not one
+    # bf16 pass
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_tiles - 1)
@@ -42,7 +45,7 @@ def _pad_to(x, mult, axes):
                    static_argnames=("bm", "bn", "bk", "alpha", "interpret"))
 def block_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, bm: int = 128,
                       bn: int = 128, bk: int = 128, alpha: float = 1.0,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """alpha * (a @ b); shapes padded up to tile multiples."""
     m, k = a.shape
     k2, n = b.shape
@@ -63,7 +66,10 @@ def block_gemm_pallas(a: jnp.ndarray, b: jnp.ndarray, bm: int = 128,
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), a.dtype),
+        # inside shard_map the output varies over the operands' mesh axes
+        out_shape=jax.ShapeDtypeStruct(
+            (mp, np_), a.dtype,
+            vma=jax.typeof(ap).vma | jax.typeof(bp).vma),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(ap, bp)

@@ -1,12 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the
-kernel body executes in Python/XLA for correctness validation. On a real
-TPU backend the same calls compile to Mosaic. ``REPRO_FORCE_INTERPRET=0``
-overrides the auto-detection."""
+On the CPU backend kernels run in ``interpret=True`` mode — the kernel
+body executes in Python/XLA for correctness validation. On a TPU the
+same calls compile to Mosaic."""
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +19,7 @@ __all__ = ["block_gemm", "block_gemm_acc", "flash_attention", "rmsnorm",
 
 
 def use_interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
+    """Interpret mode exactly when the default backend is the CPU."""
     return jax.default_backend() == "cpu"
 
 
@@ -50,10 +45,11 @@ def pselinv_level_gemm(Ainv, Uh_m):
     nk = Uh_m.shape[0]
     a2 = Ainv.transpose(0, 2, 1, 3).reshape(nbr * b, nbc * b)
     b2 = Uh_m.transpose(1, 3, 0, 2).reshape(nbc * b, nk * b)
-    if jax.default_backend() == "cpu":
-        p2 = jnp.dot(a2, b2)      # interpret-mode Pallas is trace-hostile
+    if use_interpret():
+        # interpret-mode Pallas is trace-hostile
+        p2 = jnp.dot(a2, b2, precision=jax.lax.Precision.HIGHEST)
     else:
-        p2 = block_gemm_pallas(a2, b2, interpret=use_interpret())
+        p2 = block_gemm_pallas(a2, b2)
     return p2.reshape(nbr, b, nk, b).transpose(2, 0, 1, 3)
 
 

@@ -34,7 +34,7 @@ def _trsm_kernel(b_ref, u_ref, o_ref, *, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def trsm_pallas(b, u, bm: int = 128, interpret: bool = True):
+def trsm_pallas(b, u, bm: int = 128, interpret: bool = False):
     """Solve X·U = B; b: (m, k), u: (k, k) upper triangular."""
     m, k = b.shape
     assert u.shape == (k, k)

@@ -12,14 +12,10 @@ def run_sub(code: str, ndev: int = 8, x64: bool = False, timeout=420):
     import subprocess
     import sys
     import textwrap
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    if x64:
-        env["JAX_ENABLE_X64"] = "1"
+
+    from repro.jaxenv import host_mesh_env
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       env=env, capture_output=True, text=True,
-                       timeout=timeout)
+                       env=host_mesh_env(ndev, x64=x64),
+                       capture_output=True, text=True, timeout=timeout)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     return r.stdout

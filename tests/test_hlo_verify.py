@@ -302,6 +302,38 @@ def test_hlo_multiplier_propagation():
     assert ops["body"].pairs == ((0, 1), (1, 0))
 
 
+_ASYNC_HLO = """\
+HloModule async_synth
+
+%body (p: f32[2,8,8]) -> f32[2,8,8] {
+  %p = f32[2,8,8]{2,1,0} parameter(0)
+  %collective-permute-start.1 = (f32[2,8,8]{2,1,0}, f32[2,8,8]{2,1,0}, u32[], u32[]) collective-permute-start(%p), channel_id=1, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-done.1 = f32[2,8,8]{2,1,0} collective-permute-done(%collective-permute-start.1)
+  ROOT %r = f32[2,8,8]{2,1,0} add(%p, %collective-permute-done.1)
+}
+
+%cond (s: f32[2,8,8]) -> pred[] {
+  %c = s32[] constant(3)
+  ROOT %lt = pred[] compare(%c, %c), direction=LT
+}
+
+ENTRY %main (x: f32[2,8,8]) -> f32[2,8,8] {
+  %x = f32[2,8,8]{2,1,0} parameter(0)
+  ROOT %w = f32[2,8,8]{2,1,0} while(%x), condition=%cond, body=%body
+}
+"""
+
+
+def test_hlo_async_collective_counted_once():
+    """TPU HLO splits a permute into ``-start``/``-done``: the pair is one
+    op with the start's pairs and buffer shape, priced once per trip."""
+    ops = hlo_ir.parse_collectives(_ASYNC_HLO)
+    assert [(op.op, op.pairs, op.dims, op.multiplier) for op in ops] == \
+        [("collective-permute", ((0, 1), (1, 0)), (2, 8, 8), 3)]
+    assert hlo_ir.collective_bytes(_ASYNC_HLO) == \
+        {"collective-permute": 2 * 8 * 8 * 4 * 3}
+
+
 def test_collective_bytes_keeps_dryrun_semantics():
     """The dryrun pricing stays while-edges-only: the fused permute
     counts once, the loop-body one trip-count times."""

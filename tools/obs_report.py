@@ -30,8 +30,10 @@ PlanLint static threshold, ``verify.IMBALANCE_MAX``).
     PYTHONPATH=src python tools/obs_report.py --chunk 4 --serve 24
     PYTHONPATH=src python tools/obs_report.py -o sweep.trace.json
 
-Needs ``pr*pc`` devices; when the host has fewer the tool re-execs
-itself under ``XLA_FLAGS=--xla_force_host_platform_device_count``.
+Runs on a CPU host mesh of ``pr*pc`` devices: unless its environment
+already is one, the tool re-execs itself under ``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count`` (CPU smoke, not
+device speed).
 """
 from __future__ import annotations
 
@@ -46,15 +48,9 @@ sys.path.insert(0, os.path.join(_ROOT, "src"))
 
 
 def _reexec(ndev: int, argv) -> int:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={ndev}"
-                        ).strip()
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
-    env["_OBS_REPORT_CHILD"] = "1"
+    from repro.jaxenv import host_mesh_env
     r = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                       + list(argv), env=env, cwd=_ROOT)
+                       + list(argv), env=host_mesh_env(ndev), cwd=_ROOT)
     return r.returncode
 
 
@@ -152,13 +148,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     pr, pc = (int(x) for x in args.grid.lower().split("x"))
 
-    import jax
-    if len(jax.devices()) < pr * pc:
-        if os.environ.get("_OBS_REPORT_CHILD"):
-            print(f"[obs-report] need {pr * pc} devices, have "
-                  f"{len(jax.devices())} even after re-exec",
-                  file=sys.stderr)
-            return 2
+    from repro.jaxenv import in_host_mesh
+    if not in_host_mesh(pr * pc):
         return _reexec(pr * pc, sys.argv[1:])
 
     return run_case(args.nb, pr, pc, chunk=args.chunk, reps=args.reps,

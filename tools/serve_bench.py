@@ -56,6 +56,9 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from repro.jaxenv import enable_compile_cache
+    enable_compile_cache()
+
     from repro.core.engine import Grid
     from repro.serve.batcher import BatchWindow
     from repro.serve.traffic import run_traffic
