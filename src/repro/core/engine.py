@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ..compat import shard_map
+from ..obs import compiles
 from ..obs.registry import REGISTRY
 from ..obs.trace import TRACER
 from .plan import (PlanOptions, peak_arena_blocks, ppermute_round_count)
@@ -59,7 +60,10 @@ from .schedule import Grid2D
 from .symbolic import BlockStructure
 
 __all__ = ["Grid", "PlanOptions", "PSelInvEngine", "SolveValues",
-           "structure_key", "stack_values", "bucket_size"]
+           "structure_key", "stack_values", "bucket_size", "to_device"]
+
+# jax.compile spans for every trace / lower / compile while TRACER is on
+compiles.install()
 
 #: the session API's name for the 2-D process grid (one definition —
 #: ``schedule.Grid2D`` — reused, not duplicated)
@@ -107,6 +111,25 @@ def bucket_size(B: int) -> int:
     if B < 1:
         raise ValueError(f"batch size must be >= 1, got {B}")
     return 1 << (B - 1).bit_length()
+
+
+def to_device(Lh, Dinv, dtype=None, bucket: Optional[int] = None):
+    """Cast ``Lh``/``Dinv`` to ``dtype`` (None keeps theirs) and move them
+    to the device; with ``bucket``, zero-pad the leading batch axis up to
+    it. Traced as ``engine.h2d`` (``B``, ``bytes``): while ``TRACER`` is
+    enabled the span ends when the arrays are on the device, so it times
+    the transfer and not its dispatch; disabled, nothing waits."""
+    B = Lh.shape[0] if Lh.ndim == 6 else 1
+    with TRACER.span("engine.h2d", B=B) as sp:
+        Lh = jnp.asarray(Lh, dtype=dtype)
+        Dinv = jnp.asarray(Dinv, dtype=dtype)
+        if bucket is not None and bucket != B:
+            pad = ((0, bucket - B),) + ((0, 0),) * (Lh.ndim - 1)
+            Lh, Dinv = jnp.pad(Lh, pad), jnp.pad(Dinv, pad)
+        if TRACER.enabled:
+            jax.block_until_ready((Lh, Dinv))
+            sp.set(bytes=int(Lh.nbytes + Dinv.nbytes))
+    return Lh, Dinv
 
 
 def _approx_nbytes(obj, _seen=None, _depth=0) -> int:
@@ -175,11 +198,6 @@ class PSelInvEngine:
                                       repr=False)
     _round_schedule: Optional[object] = None
     _table_bytes: Optional[int] = field(default=None, repr=False)
-    #: span-derived gauges (µs): wall of the most recent solve dispatch
-    #: and the most recent host value-prep — surfaced by :meth:`stats`
-    #: and published to the global metrics registry
-    _last_solve_us: Optional[float] = field(default=None, repr=False)
-    _last_prepare_us: Optional[float] = field(default=None, repr=False)
 
     # ---- the structure cache (class-level, all sessions) --------------
     _cache: ClassVar["OrderedDict[Tuple, PSelInvEngine]"] = OrderedDict()
@@ -339,13 +357,11 @@ class PSelInvEngine:
     def prepare_values(self, A, dtype=None) -> SolveValues:
         """Numeric host factorization of one matrix against the cached
         structure → device-layout shards. No symbolic work."""
-        t0 = time.perf_counter()
         with TRACER.span("engine.prepare_values"):
             Lh, Dinv = prepare_values(A, self.bs, self.nb, self.b,
                                       self.grid.pr, self.grid.pc)
             if dtype is not None:
                 Lh, Dinv = Lh.astype(dtype), Dinv.astype(dtype)
-        self._last_prepare_us = (time.perf_counter() - t0) * 1e6
         return SolveValues(Lh, Dinv)
 
     def prepare_values_many(self, mats: Sequence,
@@ -358,14 +374,12 @@ class PSelInvEngine:
         dominates single-matrix prep amortizes across the batch (~9×
         cheaper per matrix at B=16). The serving layer's host half of
         the coalescing win."""
-        t0 = time.perf_counter()
         with TRACER.span("engine.prepare_values_many", B=len(mats)):
             Lh, Dinv = prepare_values_many(mats, self.bs, self.nb,
                                            self.b, self.grid.pr,
                                            self.grid.pc)
             if dtype is not None:
                 Lh, Dinv = Lh.astype(dtype), Dinv.astype(dtype)
-        self._last_prepare_us = (time.perf_counter() - t0) * 1e6
         return SolveValues(Lh, Dinv)
 
     def solve(self, values, dtype=jnp.float32, *, bucket: bool = False):
@@ -390,33 +404,20 @@ class PSelInvEngine:
         if _is_matrix(values):
             values = self.prepare_values(values)
         Lh, Dinv = values
-        if dtype is not None:
-            Lh = jnp.asarray(Lh, dtype=dtype)
-            Dinv = jnp.asarray(Dinv, dtype=dtype)
         if Lh.ndim not in (5, 6):
             raise ValueError(
                 f"values must be rank 5 (single) or rank 6 (leading "
                 f"batch axis), got shape {Lh.shape}")
         self.solve_calls += 1
-        t0 = time.perf_counter()
-        with TRACER.span("engine.solve",
-                         B=Lh.shape[0] if Lh.ndim == 6 else 1):
-            if Lh.ndim == 6 and bucket:
-                B = Lh.shape[0]
-                Bp = bucket_size(B)
-                if Bp != B:
-                    pad = ((0, Bp - B),) + ((0, 0),) * (Lh.ndim - 1)
-                    out = self.jitted(batched=True)(jnp.pad(Lh, pad),
-                                                    jnp.pad(Dinv, pad))
-                    out = out[:B]
-                else:
-                    out = self.jitted(batched=True)(Lh, Dinv)
-            else:
-                out = self.jitted(batched=(Lh.ndim == 6))(Lh, Dinv)
-        # dispatch wall, not device wall: the result stays async (the
-        # caller decides when to block), so this gauge measures host
-        # prep + jit dispatch — and trace+compile when it's a cold class
-        self._last_solve_us = (time.perf_counter() - t0) * 1e6
+        batched = Lh.ndim == 6
+        B = Lh.shape[0] if batched else 1
+        with TRACER.span("engine.solve", B=B):
+            Lh, Dinv = to_device(Lh, Dinv, dtype,
+                                 bucket_size(B) if batched and bucket
+                                 else None)
+            out = self.jitted(batched=batched)(Lh, Dinv)
+            if batched and Lh.shape[0] != B:
+                out = out[:B]
         return out
 
     def solve_many(self, mats: Sequence, dtype=jnp.float32, *,
@@ -615,11 +616,8 @@ class PSelInvEngine:
         gated slot tables, padding included) and
         ``stream_shifts_per_round`` (mean gated permutes executed per
         comm round) — the two numbers the grid-factored encoding exists
-        to shrink. The span-derived gauges ``last_solve_us`` /
-        ``prepare_us`` report the most recent solve-dispatch and host
-        value-prep walls (None until the session has solved/prepared).
-        ``compile=True`` additionally reports compile metrics for the
-        f32 single-matrix shape class (:meth:`compile_stats` —
+        to shrink. ``compile=True`` additionally reports compile metrics
+        for the f32 single-matrix shape class (:meth:`compile_stats` —
         trace+lower / compile wall time, jaxpr line count, HLO text
         size), so the stream's compile-time/program-size win is
         inspectable straight off the session; call
@@ -640,9 +638,7 @@ class PSelInvEngine:
                "cache_hits": cls.cache_hits,
                "cache_misses": cls.cache_misses,
                "cache_evictions": cls.cache_evictions,
-               "solve_calls": self.solve_calls,
-               "last_solve_us": self._last_solve_us,
-               "prepare_us": self._last_prepare_us}
+               "solve_calls": self.solve_calls}
         if self.options.stream:
             from .stream import stream_shifts_per_round, stream_wire_bytes
             st = self.program.stream_tables

@@ -37,6 +37,7 @@ zeros for structurally-zero blocks: numerics are unaffected, while the
 """
 from __future__ import annotations
 
+import contextlib
 import warnings
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
@@ -49,6 +50,7 @@ from jax import lax
 
 from ..compat import shard_map
 from ..kernels.ops import pselinv_level_gemm, pselinv_round_gemm
+from ..obs.trace import TRACER
 from .plan import (CommPlan, CommRound, ExecPlan, LocalRound,
                    OverlappedExec, PlanOptions, build_plan, compile_exec,
                    merge_round_lists, schedule_overlapped, schedule_stream)
@@ -189,6 +191,20 @@ def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
     return prog
 
 
+@contextlib.contextmanager
+def _scope(step: str, level: Optional[int] = None):
+    """``jax.named_scope`` ``sweep.<step>`` (nesting ``level<k>`` when the
+    level is static): a name in the ops' ``op_name`` metadata, so a
+    profiler trace puts each device op under the sweep step it belongs
+    to. Names only — the compiled program is otherwise unchanged."""
+    with jax.named_scope(f"sweep.{step}"):
+        if level is None:
+            yield
+        else:
+            with jax.named_scope(f"level{level}"):
+                yield
+
+
 def _dyn(buf, i):
     return lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
 
@@ -210,19 +226,22 @@ def _apply_comm_rounds(dst, rounds: Sequence[CommRound], idx, op: str,
     in the number of concurrent collectives."""
     if not rounds:
         return dst
-    # one fused (R, P, 2) table per phase — a single closed-over constant,
-    # and a single dynamic lookup of this device's (R, 2) slot column
-    tables = jnp.asarray(np.stack([r.slots for r in rounds]))
-    slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
-    for i, rnd in enumerate(rounds):
-        buf = dst if src is None else src
-        payload = _dyn(buf, slots[i, 0])
-        moved = lax.ppermute(payload, "xy", rnd.perm)
-        if transpose:
-            moved = jnp.swapaxes(moved, -1, -2)
-        if op != "set":
-            moved = moved + _dyn(dst, slots[i, 1])
-        dst = lax.dynamic_update_index_in_dim(dst, moved, slots[i, 1], 0)
+    with _scope("permute"):
+        # one fused (R, P, 2) table per phase — a single closed-over
+        # constant, and a single dynamic lookup of this device's (R, 2)
+        # slot column
+        tables = jnp.asarray(np.stack([r.slots for r in rounds]))
+        slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
+        for i, rnd in enumerate(rounds):
+            buf = dst if src is None else src
+            payload = _dyn(buf, slots[i, 0])
+            moved = lax.ppermute(payload, "xy", rnd.perm)
+            if transpose:
+                moved = jnp.swapaxes(moved, -1, -2)
+            if op != "set":
+                moved = moved + _dyn(dst, slots[i, 1])
+            dst = lax.dynamic_update_index_in_dim(dst, moved, slots[i, 1],
+                                                  0)
     return dst
 
 
@@ -232,14 +251,15 @@ def _apply_local_rounds(dst, rounds: Sequence[LocalRound], idx,
     no communication; non-participants copy into the trash slot."""
     if not rounds:
         return dst
-    tables = jnp.asarray(np.stack([r.slots for r in rounds]))
-    slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
-    for i, rnd in enumerate(rounds):
-        buf = dst if src is None else src
-        blk = _dyn(buf, slots[i, 0])
-        if transpose:
-            blk = jnp.swapaxes(blk, -1, -2)
-        dst = lax.dynamic_update_index_in_dim(dst, blk, slots[i, 1], 0)
+    with _scope("lanes"):
+        tables = jnp.asarray(np.stack([r.slots for r in rounds]))
+        slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
+        for i, rnd in enumerate(rounds):
+            buf = dst if src is None else src
+            blk = _dyn(buf, slots[i, 0])
+            if transpose:
+                blk = jnp.swapaxes(blk, -1, -2)
+            dst = lax.dynamic_update_index_in_dim(dst, blk, slots[i, 1], 0)
     return dst
 
 
@@ -391,62 +411,71 @@ def make_sweep(prog: PSelInvProgram, batched: bool = False):
 # tables): the delta-add and masking tricks below are the bit-identity
 # contract between the two and must never drift. Each helper derives the
 # supernode count from its table operands, so both shape regimes flow
-# through the same code.
+# through the same code. Each runs under its ``sweep.<kind>`` scope;
+# ``level`` (static in the overlapped executor, a loop index in the
+# stream one) nests under it where known.
 
-def _phase_gemm(arena, ut, cm, N, nbr, nbc, b, base_p):
+def _phase_gemm(arena, ut, cm, N, nbr, nbc, b, base_p, level=None):
     """Level GEMM: partial[k, i] = Σ_j A⁻¹[i, j] · Û_m[k, j]ᵀ into the
     shared partial region. ``ut`` are the (nk*nbc,) arena addresses of
     the Û lanes (trash where struct-absent — ``cm`` zeroes those)."""
-    nk = ut.shape[0] // nbc
-    U = _gi(arena, ut).reshape(nk, nbc, b, b)
-    Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
-    partial = pselinv_round_gemm(Ainv, U, cm)
-    return lax.dynamic_update_slice(
-        arena, partial.reshape(nk * nbr, b, b), (base_p, 0, 0))
+    with _scope("gemm", level):
+        nk = ut.shape[0] // nbc
+        U = _gi(arena, ut).reshape(nk, nbc, b, b)
+        Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
+        partial = pselinv_round_gemm(Ainv, U, cm)
+        return lax.dynamic_update_slice(
+            arena, partial.reshape(nk * nbr, b, b), (base_p, 0, 0))
 
 
-def _phase_write(arena, kcs, wr, wc, N, nbr, nbc, b, base_p):
+def _phase_write(arena, kcs, wr, wc, N, nbr, nbc, b, base_p, level=None):
     """A⁻¹(C, K) column write for every K of the level: masked delta +
     scatter-add — same-level K's write disjoint (device, slot) pairs, so
     duplicate ``kcs`` entries add zeros."""
-    nk = kcs.shape[0]
-    partial = lax.slice_in_dim(
-        arena, base_p, base_p + nk * nbr).reshape(nk, nbr, b, b)
-    w = jnp.transpose(wr * wc[:, None])                # (nbr, nk)
-    Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
-    old = Ainv.at[:, kcs].get(mode="promise_in_bounds")
-    new = -jnp.swapaxes(partial, 0, 1)                 # (nbr, nk, b, b)
-    Ainv = Ainv.at[:, kcs].add(w[:, :, None, None] * (new - old),
-                               mode="promise_in_bounds")
-    return lax.dynamic_update_slice(
-        arena, Ainv.reshape(N, b, b), (0, 0, 0))
+    with _scope("write", level):
+        nk = kcs.shape[0]
+        partial = lax.slice_in_dim(
+            arena, base_p, base_p + nk * nbr).reshape(nk, nbr, b, b)
+        w = jnp.transpose(wr * wc[:, None])            # (nbr, nk)
+        Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
+        old = Ainv.at[:, kcs].get(mode="promise_in_bounds")
+        new = -jnp.swapaxes(partial, 0, 1)             # (nbr, nk, b, b)
+        Ainv = Ainv.at[:, kcs].add(w[:, :, None, None] * (new - old),
+                                   mode="promise_in_bounds")
+        return lax.dynamic_update_slice(
+            arena, Ainv.reshape(N, b, b), (0, 0, 0))
 
 
-def _phase_scomp(arena, ut, cm, krs, rm, N, nbr, nbc, b, base_s):
+def _phase_scomp(arena, ut, cm, krs, rm, N, nbr, nbc, b, base_s,
+                 level=None):
     """Diagonal partial sum S(K) = Σ_I A⁻¹(K, I) · L̂(I, K) into the
     shared S region (masked to row K%pr by ``rm``)."""
-    nk = krs.shape[0]
-    Uh_m = _gi(arena, ut).reshape(nk, nbc, b, b) * cm[:, :, None, None]
-    Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
-    Arow = _gi(Ainv, krs)
-    S = jnp.einsum("kjab,kjcb->kac", Arow * cm[:, :, None, None], Uh_m,
-                   precision=lax.Precision.HIGHEST)
-    return lax.dynamic_update_slice(
-        arena, S * rm[:, None, None], (base_s, 0, 0))
+    with _scope("scomp", level):
+        nk = krs.shape[0]
+        Uh_m = _gi(arena, ut).reshape(nk, nbc, b, b) * cm[:, :, None, None]
+        Ainv = lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
+        Arow = _gi(Ainv, krs)
+        S = jnp.einsum("kjab,kjcb->kac", Arow * cm[:, :, None, None],
+                       Uh_m, precision=lax.Precision.HIGHEST)
+        return lax.dynamic_update_slice(
+            arena, S * rm[:, None, None], (base_s, 0, 0))
 
 
-def _phase_diagw(arena, Dinv_f, slots, root, idx, N, base_s, dtype):
+def _phase_diagw(arena, Dinv_f, slots, root, idx, N, base_s, dtype,
+                 level=None):
     """Diagonal write A⁻¹(K,K) = D⁻¹ − Sᵀ at the owner. ``slots`` may be
     padded with the trash block (stream path): those lanes carry a
     no-device root (mask 0) and the D⁻¹ gather clamps them in-bounds —
     an identity for the real, always-< N, slots."""
-    nk = slots.shape[0]
-    S = lax.slice_in_dim(arena, base_s, base_s + nk)
-    m = (root == idx).astype(dtype)
-    newd = _gi(Dinv_f, jnp.minimum(slots, N - 1)) - jnp.swapaxes(S, -1, -2)
-    return arena.at[slots].add(
-        m[:, None, None] * (newd - _gi(arena, slots)),
-        mode="promise_in_bounds")
+    with _scope("diagw", level):
+        nk = slots.shape[0]
+        S = lax.slice_in_dim(arena, base_s, base_s + nk)
+        m = (root == idx).astype(dtype)
+        newd = (_gi(Dinv_f, jnp.minimum(slots, N - 1))
+                - jnp.swapaxes(S, -1, -2))
+        return arena.at[slots].add(
+            m[:, None, None] * (newd - _gi(arena, slots)),
+            mode="promise_in_bounds")
 
 # The overlapped per-device body, factored into module-level pieces so
 # the normal executor (`make_sweep_overlapped`) and the profiling replay
@@ -457,13 +486,14 @@ def _phase_diagw(arena, Dinv_f, slots, root, idx, N, base_s, dtype):
 def _overlap_init(ov, b, Dinv_f, idx, dtype):
     """Fresh arena + structless-supernode diagonal seeds (leaves without
     fill + grid padding get A⁻¹(K,K) = D⁻¹ up front)."""
-    arena = jnp.zeros((ov.arena_blocks, b, b), dtype=dtype)
-    if len(ov.diag_set_root):
-        slots = jnp.asarray(ov.diag_set_slot)
-        m = (jnp.asarray(ov.diag_set_root) == idx).astype(dtype)
-        arena = arena.at[slots].add(
-            m[:, None, None] * _gi(Dinv_f, slots),
-            mode="promise_in_bounds")
+    with _scope("init"):
+        arena = jnp.zeros((ov.arena_blocks, b, b), dtype=dtype)
+        if len(ov.diag_set_root):
+            slots = jnp.asarray(ov.diag_set_slot)
+            m = (jnp.asarray(ov.diag_set_root) == idx).astype(dtype)
+            arena = arena.at[slots].add(
+                m[:, None, None] * _gi(Dinv_f, slots),
+                mode="promise_in_bounds")
     return arena
 
 
@@ -479,24 +509,25 @@ def _overlap_compute(ov, op, arena, Dinv_f, idx, r, c, b, dtype):
     cm = jnp.take(jnp.asarray(lv.cmask, dtype=dtype), c, axis=0)
     if op.kind == "gemm":
         ut = jnp.take(jnp.asarray(lv.u_gather), idx, axis=0)
-        return _phase_gemm(arena, ut, cm, N, nbr, nbc, b, lv.base_p)
+        return _phase_gemm(arena, ut, cm, N, nbr, nbc, b, lv.base_p,
+                           op.level)
     if op.kind == "write":
         wr = jnp.take(jnp.asarray(lv.col_write_row, dtype=dtype),
                       r, axis=0)                        # (nk, nbr)
         wc = jnp.take(jnp.asarray(lv.col_write_col, dtype=dtype),
                       c, axis=0)                        # (nk,)
         return _phase_write(arena, jnp.asarray(lv.kcs), wr, wc,
-                            N, nbr, nbc, b, lv.base_p)
+                            N, nbr, nbc, b, lv.base_p, op.level)
     if op.kind == "scomp":
         ut = jnp.take(jnp.asarray(lv.u_gather), idx, axis=0)
         rm = jnp.take(jnp.asarray(lv.diag_rowmask, dtype=dtype),
                       r, axis=0)                        # (nk,)
         return _phase_scomp(arena, ut, cm, jnp.asarray(lv.krs),
-                            rm, N, nbr, nbc, b, lv.base_s)
+                            rm, N, nbr, nbc, b, lv.base_s, op.level)
     # "diagw":  A⁻¹(K,K) = D⁻¹ − (Σ A⁻¹(K,I)L̂(I,K))ᵀ
     return _phase_diagw(arena, Dinv_f, jnp.asarray(lv.diag_slot),
                         jnp.asarray(lv.diag_root), idx, N,
-                        lv.base_s, dtype)
+                        lv.base_s, dtype, op.level)
 
 
 def _overlap_round(ov, t, arena, Lh_f, Dinv_f, idx, r, c, b, dtype):
@@ -508,29 +539,33 @@ def _overlap_round(ov, t, arena, Lh_f, Dinv_f, idx, r, c, b, dtype):
                                  dtype)
     rnd = ov.rounds[t]
     if rnd.lwidth:
-        lg = jnp.take(jnp.asarray(rnd.lgather), idx, axis=0)
-        ls = jnp.take(jnp.asarray(rnd.lscatter), idx, axis=0)
-        lt = jnp.take(jnp.asarray(rnd.ltmask), idx, axis=0)
-        llh = jnp.take(jnp.asarray(rnd.lglh), idx, axis=0)
-        blks = _gather_lanes(arena, Lh_f, lg, llh, bool(rnd.lglh.any()))
-        blks = jnp.where(lt[:, None, None],
-                         jnp.swapaxes(blks, -1, -2), blks)
-        # non-participating lanes land in the trash block
-        arena = arena.at[ls].set(blks, mode="promise_in_bounds")
+        with _scope("lanes"):
+            lg = jnp.take(jnp.asarray(rnd.lgather), idx, axis=0)
+            ls = jnp.take(jnp.asarray(rnd.lscatter), idx, axis=0)
+            lt = jnp.take(jnp.asarray(rnd.ltmask), idx, axis=0)
+            llh = jnp.take(jnp.asarray(rnd.lglh), idx, axis=0)
+            blks = _gather_lanes(arena, Lh_f, lg, llh,
+                                 bool(rnd.lglh.any()))
+            blks = jnp.where(lt[:, None, None],
+                             jnp.swapaxes(blks, -1, -2), blks)
+            # non-participating lanes land in the trash block
+            arena = arena.at[ls].set(blks, mode="promise_in_bounds")
     if rnd.perm:
-        g = jnp.take(jnp.asarray(rnd.gather), idx, axis=0)
-        s_ = jnp.take(jnp.asarray(rnd.scatter), idx, axis=0)
-        am = jnp.take(jnp.asarray(rnd.addm, dtype=dtype), idx, axis=0)
-        tm = jnp.take(jnp.asarray(rnd.tmask), idx, axis=0)
-        lh = jnp.take(jnp.asarray(rnd.glh), idx, axis=0)
-        payload = _gather_lanes(arena, Lh_f, g, lh, bool(rnd.glh.any()))
-        moved = lax.ppermute(payload, "xy", rnd.perm)
-        moved = jnp.where(tm[:, None, None],
-                          jnp.swapaxes(moved, -1, -2), moved)
-        cur = _gi(arena, s_)
-        arena = arena.at[s_].set(
-            moved + am[:, None, None] * cur,
-            mode="promise_in_bounds")
+        with _scope("permute"):
+            g = jnp.take(jnp.asarray(rnd.gather), idx, axis=0)
+            s_ = jnp.take(jnp.asarray(rnd.scatter), idx, axis=0)
+            am = jnp.take(jnp.asarray(rnd.addm, dtype=dtype), idx, axis=0)
+            tm = jnp.take(jnp.asarray(rnd.tmask), idx, axis=0)
+            lh = jnp.take(jnp.asarray(rnd.glh), idx, axis=0)
+            payload = _gather_lanes(arena, Lh_f, g, lh,
+                                    bool(rnd.glh.any()))
+            moved = lax.ppermute(payload, "xy", rnd.perm)
+            moved = jnp.where(tm[:, None, None],
+                              jnp.swapaxes(moved, -1, -2), moved)
+            cur = _gi(arena, s_)
+            arena = arena.at[s_].set(
+                moved + am[:, None, None] * cur,
+                mode="promise_in_bounds")
     return arena
 
 
@@ -539,8 +574,9 @@ def _overlap_finish(ov, arena, Dinv_f, idx, r, c, b, dtype):
     for op in ov.compute_at[len(ov.rounds)]:
         arena = _overlap_compute(ov, op, arena, Dinv_f, idx, r, c, b,
                                  dtype)
-    return lax.slice_in_dim(
-        arena, 0, ov.n_ainv).reshape(ov.nbr, ov.nbc, b, b)
+    with _scope("finish"):
+        return lax.slice_in_dim(
+            arena, 0, ov.n_ainv).reshape(ov.nbr, ov.nbc, b, b)
 
 
 def make_sweep_overlapped(prog: PSelInvProgram, batched: bool = False):
@@ -718,15 +754,15 @@ def make_sweep_stream(prog: PSelInvProgram, batched: bool = False):
         dtype = Lh.dtype
         Lh_f = Lh.reshape(N, b, b)
         Dinv_f = Dinv.reshape(N, b, b)
-        arena = jnp.zeros((st.arena_blocks, b, b), dtype=dtype)
-
-        # structless supernodes (leaves without fill + grid padding)
-        if len(st.diag_set_root):
-            slots = jnp.asarray(st.diag_set_slot)
-            m = (jnp.asarray(st.diag_set_root) == idx).astype(dtype)
-            arena = arena.at[slots].add(
-                m[:, None, None] * _gi(Dinv_f, slots),
-                mode="promise_in_bounds")
+        with _scope("init"):
+            arena = jnp.zeros((st.arena_blocks, b, b), dtype=dtype)
+            # structless supernodes (leaves without fill + grid padding)
+            if len(st.diag_set_root):
+                slots = jnp.asarray(st.diag_set_slot)
+                m = (jnp.asarray(st.diag_set_root) == idx).astype(dtype)
+                arena = arena.at[slots].add(
+                    m[:, None, None] * _gi(Dinv_f, slots),
+                    mode="promise_in_bounds")
 
         # round-stacked device tables: one closed-over constant each,
         # sliced per round inside the loop body
@@ -806,14 +842,17 @@ def make_sweep_stream(prog: PSelInvProgram, batched: bool = False):
                     arena = lax.switch(ck[j], branches, cl[j], arena)
             # (b) owner-local copy lanes
             if st.LW:
-                lg = jnp.take(at(LG, t), idx, axis=0)
-                ls = jnp.take(at(LS, t), idx, axis=0)
-                ltm = jnp.take(at(LT, t), idx, axis=0)
-                llh = jnp.take(at(LLH, t), idx, axis=0)
-                blks = _gather_lanes(arena, Lh_f, lg, llh, local_any_lh)
-                blks = jnp.where(ltm[:, None, None],
-                                 jnp.swapaxes(blks, -1, -2), blks)
-                arena = arena.at[ls].set(blks, mode="promise_in_bounds")
+                with _scope("lanes"):
+                    lg = jnp.take(at(LG, t), idx, axis=0)
+                    ls = jnp.take(at(LS, t), idx, axis=0)
+                    ltm = jnp.take(at(LT, t), idx, axis=0)
+                    llh = jnp.take(at(LLH, t), idx, axis=0)
+                    blks = _gather_lanes(arena, Lh_f, lg, llh,
+                                         local_any_lh)
+                    blks = jnp.where(ltm[:, None, None],
+                                     jnp.swapaxes(blks, -1, -2), blks)
+                    arena = arena.at[ls].set(blks,
+                                             mode="promise_in_bounds")
             # (c) comm: the device's one outgoing lane stack is gathered
             # once; each comm slot — gated by the round's active mask —
             # ships the stack's leading slot_width lanes along its
@@ -824,37 +863,40 @@ def make_sweep_stream(prog: PSelInvProgram, batched: bool = False):
             # ships nothing (zeros branch); no device receives on an
             # inactive slot, so the zeros are never selected.
             if S:
-                g = jnp.take(at(G, t), idx, axis=0)      # (W,)
-                lh = jnp.take(at(GLH, t), idx, axis=0)
-                payload = _gather_lanes(arena, Lh_f, g, lh, comm_any_lh)
-                rsl = jnp.take(at(RSL, t), idx, axis=0)  # scalar
-                act = at(ACT, t)                         # (S,) bool
-                moved = jnp.zeros_like(payload)
-                for si in range(S):
-                    w = slot_w[si]
-                    mv = lax.cond(
-                        act[si],
-                        lambda p, perm=slot_perms[si]:
-                            lax.ppermute(p, "xy", perm),
-                        lambda p: jnp.zeros_like(p),
-                        lax.slice_in_dim(payload, 0, w))
-                    moved = moved.at[:w].set(
-                        jnp.where(rsl == si, mv, moved[:w]))
-                tm = jnp.take(at(TM, t), idx, axis=0)
-                moved = jnp.where(tm[:, None, None],
-                                  jnp.swapaxes(moved, -1, -2), moved)
-                s_ = jnp.take(at(SCT, t), idx, axis=0)
-                am = jnp.take(at(AM, t), idx, axis=0)
-                cur = _gi(arena, s_)
-                arena = arena.at[s_].set(
-                    moved + am[:, None, None] * cur,
-                    mode="promise_in_bounds")
+                with _scope("permute"):
+                    g = jnp.take(at(G, t), idx, axis=0)      # (W,)
+                    lh = jnp.take(at(GLH, t), idx, axis=0)
+                    payload = _gather_lanes(arena, Lh_f, g, lh,
+                                            comm_any_lh)
+                    rsl = jnp.take(at(RSL, t), idx, axis=0)  # scalar
+                    act = at(ACT, t)                         # (S,) bool
+                    moved = jnp.zeros_like(payload)
+                    for si in range(S):
+                        w = slot_w[si]
+                        mv = lax.cond(
+                            act[si],
+                            lambda p, perm=slot_perms[si]:
+                                lax.ppermute(p, "xy", perm),
+                            lambda p: jnp.zeros_like(p),
+                            lax.slice_in_dim(payload, 0, w))
+                        moved = moved.at[:w].set(
+                            jnp.where(rsl == si, mv, moved[:w]))
+                    tm = jnp.take(at(TM, t), idx, axis=0)
+                    moved = jnp.where(tm[:, None, None],
+                                      jnp.swapaxes(moved, -1, -2), moved)
+                    s_ = jnp.take(at(SCT, t), idx, axis=0)
+                    am = jnp.take(at(AM, t), idx, axis=0)
+                    cur = _gi(arena, s_)
+                    arena = arena.at[s_].set(
+                        moved + am[:, None, None] * cur,
+                        mode="promise_in_bounds")
             return arena
 
         # steps = nrounds + 1: the final iteration's comm tables are
         # all-trash no-ops and only the last boundary's compute fires
         arena = lax.fori_loop(0, st.steps, round_body, arena)
-        return lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
+        with _scope("finish"):
+            return lax.slice_in_dim(arena, 0, N).reshape(nbr, nbc, b, b)
 
     return _wrap_sweep(body, batched)
 
@@ -1211,29 +1253,34 @@ def prepare_values(A, bs: BlockStructure, nb: int, b: int, pr: int,
     Returns (Lh, Dinv) with shape (pr*pc, nbr, nbc, b, b) for
     ``in_specs=P("xy")``. The caller guarantees ``A`` has the sparsity
     structure that produced ``bs`` — this is the engine's analyze-once /
-    solve-many hot path, so no symbolic work happens here."""
+    solve-many hot path, so no symbolic work happens here.
+
+    Traced as ``prep.check``, ``prep.densify`` (the supernodal LU),
+    ``prep.factor`` (L̂ and D⁻¹) and ``prep.layout``, each with ``B=1``."""
     import scipy.linalg as sla
 
-    A = check_values_pattern(A, bs, b)
+    with TRACER.span("prep.check", B=1):
+        A = check_values_pattern(A, bs, b)
     nb0 = bs.nsuper
 
-    lu = factorize(A, bs=bs)
-    Lhat, _ = normalize_factors(lu)
-
-    Lh_g = np.zeros((nb, nb, b, b))
-    Dinv_g = np.zeros((nb, nb, b, b))
-    for (I, K), blk in Lhat.items():
-        Lh_g[I, K] = np.asarray(blk)
-    for K in range(nb0):
-        linv = sla.solve_triangular(np.asarray(lu.Ldiag[K]), np.eye(b),
-                                    lower=True, unit_diagonal=True)
-        Dinv_g[K, K] = sla.solve_triangular(np.asarray(lu.Udiag[K]), linv,
-                                            lower=False)
-    for K in range(nb0, nb):       # padding supernodes: identity diag
-        Dinv_g[K, K] = np.eye(b)
-
-    return (_shard_blocks(Lh_g, nb, b, pr, pc),
-            _shard_blocks(Dinv_g, nb, b, pr, pc))
+    with TRACER.span("prep.densify", B=1):
+        lu = factorize(A, bs=bs)
+    with TRACER.span("prep.factor", B=1):
+        Lhat, _ = normalize_factors(lu)
+        Lh_g = np.zeros((nb, nb, b, b))
+        Dinv_g = np.zeros((nb, nb, b, b))
+        for (I, K), blk in Lhat.items():
+            Lh_g[I, K] = np.asarray(blk)
+        for K in range(nb0):
+            linv = sla.solve_triangular(np.asarray(lu.Ldiag[K]), np.eye(b),
+                                        lower=True, unit_diagonal=True)
+            Dinv_g[K, K] = sla.solve_triangular(np.asarray(lu.Udiag[K]),
+                                                linv, lower=False)
+    with TRACER.span("prep.layout", B=1):
+        for K in range(nb0, nb):   # padding supernodes: identity diag
+            Dinv_g[K, K] = np.eye(b)
+        return (_shard_blocks(Lh_g, nb, b, pr, pc),
+                _shard_blocks(Dinv_g, nb, b, pr, pc))
 
 
 def _batched_lu_nopivot(Akk: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -1274,50 +1321,58 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     Raises ``ValueError`` naming the offending batch *index* when any
     matrix's pattern escapes the analyzed structure — callers that need
     per-request isolation (the serving layer) validate each matrix with
-    :func:`check_values_pattern` first."""
+    :func:`check_values_pattern` first.
+
+    Traced as ``prep.check``, ``prep.densify`` (CSR → the dense
+    workspace), ``prep.factor`` (the supernode loop, L̂ and D⁻¹) and
+    ``prep.layout``, each with ``B``."""
     if not len(mats):
         raise ValueError("prepare_values_many needs at least one matrix")
+    B, nb0 = len(mats), bs.nsuper
     csr = []
-    for i, M in enumerate(mats):
-        try:
-            csr.append(check_values_pattern(M, bs, b))
-        except ValueError as e:
-            raise ValueError(f"matrix {i} of {len(mats)}: {e}") from e
-    B, nb0 = len(csr), bs.nsuper
+    with TRACER.span("prep.check", B=B):
+        for i, M in enumerate(mats):
+            try:
+                csr.append(check_values_pattern(M, bs, b))
+            except ValueError as e:
+                raise ValueError(f"matrix {i} of {B}: {e}") from e
     eye = np.eye(b)
 
     # dense (B, nb0, nb0, b, b) block workspace holding the evolving
     # Schur complement; fill lands in blocks the symbolic structure
     # already owns, so reading only struct blocks below is exact
-    W = np.stack([np.asarray(M.todense()) for M in csr])
-    W = (W.reshape(B, nb0, b, nb0, b).transpose(0, 1, 3, 2, 4)
-          .astype(np.float64, copy=True))
-    Lh = np.zeros((B, nb, nb, b, b))
-    Dinv = np.zeros((B, nb, nb, b, b))
-    bidx = np.arange(B)
-    for K in range(nb0):
-        L, U = _batched_lu_nopivot(W[:, K, K])
-        C = [int(i) for i in bs.struct[K]]
-        if C:
-            # L(C,K): X·U = A  ⇔  Uᵀ·Xᵀ = Aᵀ (batched, broadcast over C)
-            LCK = np.linalg.solve(
-                U.transpose(0, 2, 1)[:, None],
-                W[:, C, K].transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
-            UKC = np.linalg.solve(L[:, None], W[:, K, C])   # L·X = A
-            W[:, C, K] = LCK
-            W[:, K, C] = UKC
-            # Schur update over the whole struct(K) × struct(K) clique
-            W[np.ix_(bidx, C, C)] -= np.einsum(
-                'bikl,bjlm->bijkm', LCK, UKC)
-            # L̂(C,K) = L(C,K)·L(K,K)⁻¹:  X·L = A  ⇔  Lᵀ·Xᵀ = Aᵀ
-            Lh[:, C, K] = np.linalg.solve(
-                L.transpose(0, 2, 1)[:, None],
-                LCK.transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
-        linv = np.linalg.solve(L, np.broadcast_to(eye, (B, b, b)))
-        Dinv[:, K, K] = np.linalg.solve(U, linv)   # (U_KK)⁻¹(L_KK)⁻¹
-    Dinv[:, range(nb0, nb), range(nb0, nb)] = eye   # padding supernodes
-    return (_shard_blocks(Lh, nb, b, pr, pc),
-            _shard_blocks(Dinv, nb, b, pr, pc))
+    with TRACER.span("prep.densify", B=B):
+        W = np.stack([np.asarray(M.todense()) for M in csr])
+        W = (W.reshape(B, nb0, b, nb0, b).transpose(0, 1, 3, 2, 4)
+              .astype(np.float64, copy=True))
+    with TRACER.span("prep.factor", B=B):
+        Lh = np.zeros((B, nb, nb, b, b))
+        Dinv = np.zeros((B, nb, nb, b, b))
+        bidx = np.arange(B)
+        for K in range(nb0):
+            L, U = _batched_lu_nopivot(W[:, K, K])
+            C = [int(i) for i in bs.struct[K]]
+            if C:
+                # L(C,K): X·U = A ⇔ Uᵀ·Xᵀ = Aᵀ (batched, broadcast over C)
+                LCK = np.linalg.solve(
+                    U.transpose(0, 2, 1)[:, None],
+                    W[:, C, K].transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
+                UKC = np.linalg.solve(L[:, None], W[:, K, C])  # L·X = A
+                W[:, C, K] = LCK
+                W[:, K, C] = UKC
+                # Schur update over the whole struct(K) × struct(K) clique
+                W[np.ix_(bidx, C, C)] -= np.einsum(
+                    'bikl,bjlm->bijkm', LCK, UKC)
+                # L̂(C,K) = L(C,K)·L(K,K)⁻¹:  X·L = A  ⇔  Lᵀ·Xᵀ = Aᵀ
+                Lh[:, C, K] = np.linalg.solve(
+                    L.transpose(0, 2, 1)[:, None],
+                    LCK.transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
+            linv = np.linalg.solve(L, np.broadcast_to(eye, (B, b, b)))
+            Dinv[:, K, K] = np.linalg.solve(U, linv)  # (U_KK)⁻¹(L_KK)⁻¹
+    with TRACER.span("prep.layout", B=B):
+        Dinv[:, range(nb0, nb), range(nb0, nb)] = eye  # padding supernodes
+        return (_shard_blocks(Lh, nb, b, pr, pc),
+                _shard_blocks(Dinv, nb, b, pr, pc))
 
 
 def prepare_inputs(A, b: int, pr: int, pc: int):

@@ -7,6 +7,9 @@ about what the schedule *should* do; this package measures what it
 * ``trace``    — nested span tracer with a thread-safe ring buffer and a
   near-zero-cost disabled path; the engine and serve layers emit spans
   through the module-level ``TRACER``.
+* ``compiles`` — a ``jax.monitoring`` listener that files every jax
+  trace, lowering and compile as a ``jax.compile`` span while the
+  tracer is enabled (installed by ``core.engine``).
 * ``registry`` — unified metrics registry (counters / gauges /
   histograms with labels), one ``snapshot()`` and a prometheus-style
   text dump; ``engine.stats()`` and ``serve.metrics`` register into it.

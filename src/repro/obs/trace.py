@@ -6,13 +6,21 @@ a bounded, thread-safe ring buffer on the owning :class:`Tracer`; the
 Chrome-trace exporter (``obs.export``) serialises them one lane per
 thread.
 
+While a tracer is enabled, every span also holds a
+``jax.profiler.TraceAnnotation`` of its name and attributes open for its
+lifetime, so under ``jax.profiler.trace`` the program's spans land on the
+profiler's ``/host:CPU`` plane, on the device trace's clock, beside the
+device ops (TensorBoard, Perfetto).  The ring buffer keeps its own copy
+on ``time.perf_counter_ns``.
+
 Design constraints, in order:
 
 1. **Near-zero overhead when disabled.**  ``tracer.span(...)`` on a
    disabled tracer returns a shared ``_NullSpan`` singleton — no span
-   object is allocated, no clock is read, nothing is buffered.  This is
-   what lets the engine leave trace calls inline on the ``solve`` hot
-   path (the bench asserts ≤2 % overhead even *enabled*).
+   object is allocated, no clock is read, no jax call is made, nothing
+   is buffered.  This is what lets the engine leave trace calls inline
+   on the ``solve`` hot path (the bench asserts ≤2 % overhead even
+   *enabled*).
 2. **Thread safety.**  The span stack is thread-local (nesting never
    crosses threads — a serve worker's spans parent to that worker's
    stack); the ring buffer append is guarded by a lock shared with
@@ -28,6 +36,10 @@ Typical use::
         ...
         sp.set(bucket=8)
     events = TRACER.spans()
+
+:meth:`Tracer.record` files a span that has just ended, from its
+duration, for events known only afterwards (the compile listener of
+``obs.compiles``); it goes to the ring buffer only.
 """
 from __future__ import annotations
 
@@ -84,7 +96,7 @@ class _ActiveSpan:
     """A live span: context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
-                 "tid", "_t0_ns")
+                 "tid", "_t0_ns", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Dict[str, Any]) -> None:
@@ -95,23 +107,29 @@ class _ActiveSpan:
         self.parent_id = None
         self.tid = 0
         self._t0_ns = 0
+        self._annotation = None
 
     def set(self, **attrs: Any) -> "_ActiveSpan":
         """Attach/overwrite attributes mid-span; chainable."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "_ActiveSpan":
+        self._annotation = self._tracer._annotation(self.name, **self.attrs)
         stack = self._tracer._stack()
         self.parent_id = stack[-1].span_id if stack else None
         self.tid = threading.get_ident()
         stack.append(self)
+        self._annotation.__enter__()
         # read the clock last so setup cost is outside the measured window
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -147,9 +165,15 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._ids = itertools.count(1)
+        self._annotation = None
+        if self.enabled:
+            self.enable()
 
     # -- control ----------------------------------------------------------
     def enable(self) -> "Tracer":
+        # resolved here, so a disabled tracer never touches jax
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.enabled = True
         return self
 
@@ -176,6 +200,21 @@ class Tracer:
         now = time.perf_counter_ns() / 1e3
         stack = self._stack()
         self._record(Span(name=name, t0_us=now, dur_us=0.0,
+                          span_id=next(self._ids),
+                          parent_id=stack[-1].span_id if stack else None,
+                          tid=threading.get_ident(), attrs=attrs))
+
+    def record(self, name: str, dur_s: float, **attrs: Any) -> None:
+        """File a span that has just ended, ``dur_s`` seconds long: its
+        start is now minus ``dur_s``.  For events known only afterwards
+        (a compile reported by its duration); ring buffer only, with no
+        profiler annotation."""
+        if not self.enabled:
+            return
+        t1 = time.perf_counter_ns() / 1e3
+        stack = self._stack()
+        dur_us = dur_s * 1e6
+        self._record(Span(name=name, t0_us=t1 - dur_us, dur_us=dur_us,
                           span_id=next(self._ids),
                           parent_id=stack[-1].span_id if stack else None,
                           tid=threading.get_ident(), attrs=attrs))

@@ -34,10 +34,11 @@ from typing import Dict, List, Optional, Set
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..core.engine import (Grid, PlanOptions, PSelInvEngine, SolveValues,
-                           bucket_size, stack_values)
+                           bucket_size, stack_values, to_device)
 from ..core.pselinv_dist import check_values_pattern
 from ..obs.trace import TRACER
 from .batcher import (BatchWindow, RequestStatus, RequestTimedOut,
@@ -183,7 +184,12 @@ class SelInvServer:
     def _serve_batch(self, reqs: List[SolveRequest]) -> None:
         """Serve one same-structure batch end to end. Never raises:
         per-request pattern failures and whole-batch solve failures
-        land on the affected requests as FAILED."""
+        land on the affected requests as FAILED.
+
+        Traced as ``serve.batch`` (``rids``: its requests' ids) over
+        ``serve.pattern_check``, ``serve.prepare``, ``serve.sweep``
+        (dispatch until the result is ready; it waits for the device
+        only while ``TRACER`` is enabled) and ``serve.d2h``."""
         eng = self._engines[reqs[0].skey]
         cause = getattr(reqs, "cause", None)
         now = time.monotonic()
@@ -192,33 +198,42 @@ class SelInvServer:
             r.batched_at = now
 
         with TRACER.span("serve.batch", skey=reqs[0].skey[:12],
-                         n=len(reqs), cause=cause or "?") as sp:
+                         n=len(reqs), cause=cause or "?",
+                         rids=[r.rid for r in reqs]) as sp:
             # per-request admission of the *values* against the claimed
             # structure: a matrix whose pattern escapes it fails alone
             live: List[SolveRequest] = []
-            for r in reqs:
-                if r.matrix is not None:
-                    try:
-                        check_values_pattern(r.matrix, eng.bs, eng.b)
-                    except ValueError as e:
-                        self.metrics.inc("failed")
-                        r._finish(RequestStatus.FAILED, error=ServeError(
-                            f"request {r.rid}: {e}"))
-                        continue
-                live.append(r)
+            with TRACER.span("serve.pattern_check", B=len(reqs)):
+                for r in reqs:
+                    if r.matrix is not None:
+                        try:
+                            check_values_pattern(r.matrix, eng.bs, eng.b)
+                        except ValueError as e:
+                            self.metrics.inc("failed")
+                            r._finish(RequestStatus.FAILED,
+                                      error=ServeError(
+                                          f"request {r.rid}: {e}"))
+                            continue
+                    live.append(r)
             self._remember(reqs)
             if not live:
                 return
 
             try:
-                vals = self._prepare(eng, live)
+                with TRACER.span("serve.prepare", B=len(live)):
+                    vals = self._prepare(eng, live)
                 B = vals.Lh.shape[0]
                 bkt = bucket_size(B) if self.cfg.bucket else B
                 sp.set(B=B, bucket=bkt)
+                with TRACER.span("serve.sweep", B=B, bucket=bkt):
+                    res = self._execute(eng, vals, B, bkt)
+                    if TRACER.enabled:
+                        jax.block_until_ready(res)
                 # one device→host gather for the whole batch: per-request
                 # jax-array slicing would dispatch a gather op per request
                 # (measured ~3 ms each — more than the solve itself)
-                out = np.asarray(self._execute(eng, vals, B, bkt))
+                with TRACER.span("serve.d2h", B=B):
+                    out = np.asarray(res)
                 self.metrics.observe_batch(B, bkt, cause=cause)
                 self._buckets_used.setdefault(reqs[0].skey, set()).add(bkt)
                 for i, r in enumerate(live):
@@ -262,11 +277,7 @@ class SelInvServer:
         AOT executable from the program cache."""
         if self.cfg.prog_cache is not None:
             comp = self.cfg.prog_cache.get(eng, bkt, self.cfg.dtype)
-            Lh = jnp.asarray(vals.Lh, dtype=self.cfg.dtype)
-            Dv = jnp.asarray(vals.Dinv, dtype=self.cfg.dtype)
-            if bkt != B:
-                pad = ((0, bkt - B),) + ((0, 0),) * (Lh.ndim - 1)
-                Lh, Dv = jnp.pad(Lh, pad), jnp.pad(Dv, pad)
+            Lh, Dv = to_device(vals.Lh, vals.Dinv, self.cfg.dtype, bkt)
             return comp(Lh, Dv)[:B]
         return eng.solve(vals, dtype=self.cfg.dtype,
                          bucket=self.cfg.bucket)
