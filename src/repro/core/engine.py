@@ -370,10 +370,11 @@ class PSelInvEngine:
         matrices → stacked (B, P, nbr, nbc, b, b) shards in one
         structure-driven pass (:func:`~.pselinv_dist
         .prepare_values_many`) — the supernode loop runs once with
-        (B, b, b) block stacks, so the interpreter overhead that
-        dominates single-matrix prep amortizes across the batch (~9×
-        cheaper per matrix at B=16). The serving layer's host half of
-        the coalescing win."""
+        (B, b, b) block stacks and does each supernode's arithmetic as
+        a few f64 BLAS-3 calls per matrix: one block inverse and two
+        GEMMs, the larger of them the whole Schur update of the
+        supernode's clique. The serving layer's host half of the
+        coalescing win."""
         with TRACER.span("engine.prepare_values_many", B=len(mats)):
             Lh, Dinv = prepare_values_many(mats, self.bs, self.nb,
                                            self.b, self.grid.pr,

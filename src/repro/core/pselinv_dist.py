@@ -1283,21 +1283,6 @@ def prepare_values(A, bs: BlockStructure, nb: int, b: int, pr: int,
                 _shard_blocks(Dinv_g, nb, b, pr, pc))
 
 
-def _batched_lu_nopivot(Akk: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Doolittle LU without pivoting over a (B, b, b) block stack —
-    the batched twin of ``supernodal_lu.dense_lu_nopivot`` (same
-    elimination order, so the factors agree to rounding)."""
-    B, b = Akk.shape[0], Akk.shape[1]
-    lu = Akk.copy()
-    for k in range(b - 1):
-        piv = lu[:, k, k]
-        lu[:, k + 1:, k] /= piv[:, None]
-        lu[:, k + 1:, k + 1:] -= (lu[:, k + 1:, k, None]
-                                  * lu[:, None, k, k + 1:])
-    L = np.tril(lu, -1) + np.eye(b)
-    return L, np.triu(lu)
-
-
 def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
                         b: int, pr: int, pc: int
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -1305,18 +1290,23 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     ``(B, pr*pc, nbr, nbc, b, b)`` shards in ONE structure-driven pass.
 
     Same math as B :func:`prepare_values` calls — right-looking
-    supernodal LU over the filled structure, factor normalization,
-    diagonal inverses — but the Python loop over supernodes runs once
-    with every block stacked ``(B, b, b)``, so the interpreter overhead
-    that dominates the single-matrix path (measured ~11 ms/matrix at
-    nb=16) amortizes across the batch (~1.3 ms/matrix at B=16). This is
-    the serving layer's host-side half of the batching win: without it a
-    coalesced batch still pays B sequential GIL-bound factorizations.
+    supernodal elimination over the filled structure, in supernode
+    order, with no pivoting across supernodes — all in f64, with every
+    block stacked ``(B, b, b)``. Each supernode K costs a few BLAS-3
+    calls per batch member, on the Schur-updated blocks ``A``:
+
+    - ``D⁻¹(K,K) = A(K,K)⁻¹`` (one batched LAPACK inverse; it equals
+      ``U_KK⁻¹·L_KK⁻¹`` of the no-pivot LU, which is never formed);
+    - ``L̂(C,K) = L(C,K)·L_KK⁻¹ = A(C,K)·A(K,K)⁻¹``, one
+      ``(|C|·b, b) @ (b, b)`` GEMM over ``C = struct(K)``;
+    - the Schur update of the whole ``C × C`` clique,
+      ``A(C,C) -= L(C,K)·U(K,C) = L̂(C,K)·A(K,C)``, one
+      ``(|C|·b, b) @ (b, |C|·b)`` GEMM.
 
     The dense (nb0, nb0) block workspace is the same asymptotic
     footprint as the device layout :func:`prepare_values` already
     emits. Numerics match the single-matrix scipy path to rounding
-    (≤1e-12 asserted in tests; observed ~1e-18).
+    (≤1e-12 of each block's largest entry, asserted in tests).
 
     Raises ``ValueError`` naming the offending batch *index* when any
     matrix's pattern escapes the analyzed structure — callers that need
@@ -1336,7 +1326,6 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
                 csr.append(check_values_pattern(M, bs, b))
             except ValueError as e:
                 raise ValueError(f"matrix {i} of {B}: {e}") from e
-    eye = np.eye(b)
 
     # dense (B, nb0, nb0, b, b) block workspace holding the evolving
     # Schur complement; fill lands in blocks the symbolic structure
@@ -1350,27 +1339,20 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
         Dinv = np.zeros((B, nb, nb, b, b))
         bidx = np.arange(B)
         for K in range(nb0):
-            L, U = _batched_lu_nopivot(W[:, K, K])
+            DKK = np.linalg.inv(W[:, K, K])        # = U_KK⁻¹·L_KK⁻¹
+            Dinv[:, K, K] = DKK
             C = [int(i) for i in bs.struct[K]]
-            if C:
-                # L(C,K): X·U = A ⇔ Uᵀ·Xᵀ = Aᵀ (batched, broadcast over C)
-                LCK = np.linalg.solve(
-                    U.transpose(0, 2, 1)[:, None],
-                    W[:, C, K].transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
-                UKC = np.linalg.solve(L[:, None], W[:, K, C])  # L·X = A
-                W[:, C, K] = LCK
-                W[:, K, C] = UKC
-                # Schur update over the whole struct(K) × struct(K) clique
-                W[np.ix_(bidx, C, C)] -= np.einsum(
-                    'bikl,bjlm->bijkm', LCK, UKC)
-                # L̂(C,K) = L(C,K)·L(K,K)⁻¹:  X·L = A  ⇔  Lᵀ·Xᵀ = Aᵀ
-                Lh[:, C, K] = np.linalg.solve(
-                    L.transpose(0, 2, 1)[:, None],
-                    LCK.transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2)
-            linv = np.linalg.solve(L, np.broadcast_to(eye, (B, b, b)))
-            Dinv[:, K, K] = np.linalg.solve(U, linv)  # (U_KK)⁻¹(L_KK)⁻¹
+            if not C:
+                continue
+            c = len(C)
+            LhCK = W[:, C, K].reshape(B, c * b, b) @ DKK
+            Lh[:, C, K] = LhCK.reshape(B, c, b, b)
+            # A(C,C) -= L̂(C,K)·A(K,C) over the whole clique, one GEMM
+            AKC = W[:, K, C].transpose(0, 2, 1, 3).reshape(B, b, c * b)
+            W[np.ix_(bidx, C, C)] -= (LhCK @ AKC).reshape(
+                B, c, b, c, b).transpose(0, 1, 3, 2, 4)
     with TRACER.span("prep.layout", B=B):
-        Dinv[:, range(nb0, nb), range(nb0, nb)] = eye  # padding supernodes
+        Dinv[:, range(nb0, nb), range(nb0, nb)] = np.eye(b)  # padding
         return (_shard_blocks(Lh, nb, b, pr, pc),
                 _shard_blocks(Dinv, nb, b, pr, pc))
 

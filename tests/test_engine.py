@@ -349,26 +349,42 @@ def test_engine_bucketed_solve_shares_pow2_programs():
     """, x64=True)
 
 
-def test_prepare_values_many_matches_per_matrix_path():
+@pytest.mark.parametrize("nx,ny,b,shifts", [
+    (12, 8, 8, (0.0, 0.25, 1.0, 2.0)),
+    # b=16: supernodes whose struct(K) cliques hold 3 and 4 members, so
+    # the Schur update's (|C|·b, b) @ (b, |C|·b) GEMM spans several blocks
+    (16, 16, 16, (0.5,)),
+    (16, 16, 16, (0.0, 1.5)),
+    (16, 16, 16, (0.0, 0.25, 1.0, 2.0)),
+])
+def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts):
     """The stacked host factorization is numerically the per-matrix
     path: prepare_values_many over shifted copies matches a loop of
-    prepare_values to ≤1e-12 (f64), and a bad-pattern member fails with
-    its batch index named while the pure per-matrix error is unchanged."""
+    prepare_values to ≤1e-12 (f64), absolute and relative to each
+    block's largest entry, for batches of 1, 2 and 4 and cliques of up
+    to 4 supernodes; and a bad-pattern member fails with its batch
+    index named while the pure per-matrix error is unchanged."""
     import scipy.sparse as sp
     from repro.core.engine import stack_values
-    A = sparse.laplacian_2d(12, 8)
+    A = sparse.laplacian_2d(nx, ny)
     I_A = sp.identity(A.shape[0])
-    mats = [A + c * I_A for c in (0.0, 0.25, 1.0, 2.0)]
-    eng = PSelInvEngine.analyze(A, b=8, grid=Grid(1, 1),
+    mats = [A + c * I_A for c in shifts]
+    eng = PSelInvEngine.analyze(A, b=b, grid=Grid(1, 1),
                                 options=PlanOptions())
+    if b == 16:
+        assert max(len(s) for s in eng.bs.struct) >= 3
     many = eng.prepare_values_many(mats)
     loop = stack_values([eng.prepare_values(M) for M in mats])
     assert many.Lh.shape == loop.Lh.shape
-    assert abs(many.Lh - loop.Lh).max() <= 1e-12
-    assert abs(many.Dinv - loop.Dinv).max() <= 1e-12
+    for got, ref in ((many.Lh, loop.Lh), (many.Dinv, loop.Dinv)):
+        err = abs(got - ref).max(axis=(-2, -1))
+        assert err.max() <= 1e-12
+        assert (err <= 1e-12 * abs(ref).max(axis=(-2, -1))).all()
     # a member whose pattern escapes the structure names its index
+    n = A.shape[0]
     B = sp.lil_matrix(A)
-    B[0, 95] = B[95, 0] = 1.0
+    B[0, n - 1] = B[n - 1, 0] = 1.0
+    bad = [mats[0]] * 2 + [sp.csr_matrix(B)]
     with pytest.raises(ValueError,
                        match=r"matrix 2 of 3:.*outside the analyzed"):
-        eng.prepare_values_many([mats[0], mats[1], sp.csr_matrix(B)])
+        eng.prepare_values_many(bad)
