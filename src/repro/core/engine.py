@@ -56,7 +56,7 @@ from .pselinv_dist import (PSelInvProgram, analyze_structure, build_program,
                            make_sweep_overlapped, make_sweep_stream,
                            pad_nb, prepare_values, prepare_values_many,
                            validate_uniform_widths)
-from .schedule import Grid2D
+from .schedule import BYTES_PER_ELT, Grid2D
 from .symbolic import BlockStructure
 
 __all__ = ["Grid", "PlanOptions", "PSelInvEngine", "SolveValues",
@@ -198,6 +198,7 @@ class PSelInvEngine:
                                       repr=False)
     _round_schedule: Optional[object] = None
     _table_bytes: Optional[int] = field(default=None, repr=False)
+    _comm: Optional[Dict[str, float]] = field(default=None, repr=False)
 
     # ---- the structure cache (class-level, all sessions) --------------
     _cache: ClassVar["OrderedDict[Tuple, PSelInvEngine]"] = OrderedDict()
@@ -275,6 +276,7 @@ class PSelInvEngine:
             engine = cls(bs=bs, b=b, nb=nb, grid=grid, options=options,
                          program=program, mesh=Mesh(devs, ("xy",)),
                          key=key)
+            engine.comm_counters()
         with cls._cache_lock:
             # somebody may have raced us past the miss above; keep the
             # first published session so `analyze` stays idempotent
@@ -412,11 +414,17 @@ class PSelInvEngine:
         self.solve_calls += 1
         batched = Lh.ndim == 6
         B = Lh.shape[0] if batched else 1
-        with TRACER.span("engine.solve", B=B):
+        with TRACER.span("engine.solve", B=B) as sp:
             Lh, Dinv = to_device(Lh, Dinv, dtype,
                                  bucket_size(B) if batched and bucket
                                  else None)
             out = self.jitted(batched=batched)(Lh, Dinv)
+            if TRACER.enabled:
+                # per element shipped: the solve's dtype, every lane
+                lanes = Lh.shape[0] if batched else 1
+                scale = lanes * Lh.dtype.itemsize / BYTES_PER_ELT
+                sp.set(**{k: v if k == "rounds" else v * scale
+                          for k, v in self.comm_counters().items()})
             if batched and Lh.shape[0] != B:
                 out = out[:B]
         return out
@@ -435,6 +443,36 @@ class PSelInvEngine:
         else:
             vals = stack_values([self.prepare_values(A) for A in mats])
         return self.solve(vals, dtype=dtype, bucket=bucket)
+
+    def comm_counters(self) -> Dict[str, float]:
+        """The restricted collectives of one single-matrix sweep, read
+        off the cached plan once per session (:meth:`analyze` calls it):
+        ``rounds``, the sweep's ppermute rounds; ``wire_bytes``, what its
+        permutes ship over all devices, padding included
+        (:func:`~.simulator.executed_wire_bytes`); ``recv_bytes_max`` and
+        ``recv_bytes_mean``, the bytes one device receives, from the
+        plan's trees over every op kind
+        (:func:`~.simulator.volumes_from_plan`). Bytes are priced at
+        ``BYTES_PER_ELT`` per element, as the simulator prices them.
+        While tracing, :meth:`solve` stamps them on its ``engine.solve``
+        span, the bytes scaled to its dtype and lanes."""
+        if self._comm is None:
+            from .simulator import executed_wire_bytes, volumes_from_plan
+            _, inc = volumes_from_plan(self.program.plan)
+            recv = sum(inc.values(), np.zeros(self.grid.size))
+            self._comm = {
+                "rounds": ppermute_round_count(self._executed_plan()),
+                "wire_bytes": executed_wire_bytes(self.program),
+                "recv_bytes_max": float(recv.max()),
+                "recv_bytes_mean": float(recv.mean())}
+        return self._comm
+
+    def _executed_plan(self):
+        """The round tables the session's executor runs: the overlapped
+        stream (which the round-stream lowering replays) or the
+        level-serial levels."""
+        return (self.program.overlap_plan if self.options.overlap
+                else self.program.exec_plan)
 
     def table_bytes(self) -> int:
         """Approximate resident bytes of this session's compiled tables
@@ -611,7 +649,9 @@ class PSelInvEngine:
 
     def stats(self, compile: bool = False) -> Dict[str, float]:
         """Static schedule metrics of the cached program: ppermute round
-        count and peak per-device arena footprint (blocks). Stream
+        count, the other :meth:`comm_counters` (``wire_bytes``,
+        ``recv_bytes_max``, ``recv_bytes_mean``, at ``BYTES_PER_ELT``
+        per element) and peak per-device arena footprint (blocks). Stream
         sessions additionally report their executed wire traffic —
         ``stream_wire_bytes`` (physical permute bytes per sweep from the
         gated slot tables, padding included) and
@@ -626,10 +666,13 @@ class PSelInvEngine:
         Every scalar reported here is also published to the global
         metrics registry (``repro.obs.registry.REGISTRY``) under
         ``selinv_engine_*`` — the process-wide scrape surface."""
-        ex = (self.program.overlap_plan if self.options.overlap
-              else self.program.exec_plan)
+        ex = self._executed_plan()
         cls = type(self)
-        out = {"ppermute_rounds": ppermute_round_count(ex),
+        comm = self.comm_counters()
+        out = {"ppermute_rounds": comm["rounds"],
+               "wire_bytes": comm["wire_bytes"],
+               "recv_bytes_max": comm["recv_bytes_max"],
+               "recv_bytes_mean": comm["recv_bytes_mean"],
                "peak_arena_blocks": peak_arena_blocks(ex),
                # structure-cache health (class-level, all sessions) +
                # this session's own table footprint — the serving

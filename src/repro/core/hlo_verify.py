@@ -244,8 +244,9 @@ def check_collectives(ops: List[hlo_ir.CollectiveOp], prog, *,
                 f"({compiled_blocks * b * b * BYTES_PER_ELT:.0f} B) but "
                 f"the plan tables ship {want} blocks"))
         else:
-            ex_bytes = _executed_wire_bytes(prog)
-            if ex_bytes is not None and not np.isclose(
+            from .simulator import executed_wire_bytes
+            ex_bytes = executed_wire_bytes(prog)
+            if not np.isclose(
                     compiled_blocks * b * b * BYTES_PER_ELT, ex_bytes):
                 diags.append(_err(
                     "hlo/bytes-drift",
@@ -253,17 +254,6 @@ def check_collectives(ops: List[hlo_ir.CollectiveOp], prog, *,
                     f"{compiled_blocks * b * b * BYTES_PER_ELT:.0f} B "
                     f"!= executed_wire_bytes {ex_bytes:.0f} B"))
     return diags
-
-
-def _executed_wire_bytes(prog) -> Optional[float]:
-    """``simulator.executed_wire_bytes`` where defined (overlapped /
-    stream lowerings; the level-serial executor has no global round
-    stream to price)."""
-    if getattr(prog, "stream_tables", None) is None and \
-            getattr(prog, "overlap_plan", None) is None:
-        return None
-    from .simulator import executed_wire_bytes
-    return executed_wire_bytes(prog)
 
 
 def compiled_wire_blocks(ops: List[hlo_ir.CollectiveOp], prog, *,

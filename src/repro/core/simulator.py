@@ -598,7 +598,8 @@ def executed_wire_bytes(prog_or_engine) -> float:
     (tested, and asserted against the unrolled overlapped executor's
     wire in the bench). For an unrolled overlapped program it prices
     each round's single static permute (``len(perm) × width``
-    blocks)."""
+    blocks), for the level-serial one each single-block round
+    (``len(perm)`` blocks)."""
     prog = getattr(prog_or_engine, "program", prog_or_engine)
     b = prog.b
     st = getattr(prog, "stream_tables", None)
@@ -620,10 +621,15 @@ def executed_wire_bytes(prog_or_engine) -> float:
     if ov is not None:
         blocks = sum(len(rnd.perm) * rnd.width for rnd in ov.rounds)
         return float(blocks) * b * b * BYTES_PER_ELT
-    raise ValueError(
-        "executed wire accounting covers the overlapped and stream "
-        "lowerings — compile with PlanOptions(overlap=True) or "
-        "PlanOptions(stream=True)")
+    ex = getattr(prog, "exec_plan", None)
+    if ex is not None:
+        blocks = sum(len(rnd.perm) for lv in ex.levels
+                     for rounds in (lv.xfer_in, lv.bcast, lv.reduce,
+                                    lv.xfer_out, lv.diag_reduce)
+                     for rnd in rounds)
+        return float(blocks) * b * b * BYTES_PER_ELT
+    raise ValueError("executed wire accounting needs a program with "
+                     "stream_tables, overlap_plan or exec_plan")
 
 
 def round_schedule_of(prog_or_engine) -> RoundSchedule:

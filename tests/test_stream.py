@@ -544,15 +544,17 @@ def test_stream_engine_session_end_to_end():
         cache_keys = {"table_bytes", "cache_engines", "cache_hits",
                       "cache_misses", "cache_evictions"}
         gauge_keys = {"solve_calls"}
+        comm_keys = {"ppermute_rounds", "wire_bytes", "recv_bytes_max",
+                     "recv_bytes_mean"}
         s = eng.stats()
-        assert set(s) == {"ppermute_rounds", "peak_arena_blocks",
-                          "stream_wire_bytes",
+        assert set(s) == {"peak_arena_blocks", "stream_wire_bytes",
                           "stream_shifts_per_round"} \
-            | cache_keys | gauge_keys
+            | comm_keys | cache_keys | gauge_keys
         sb = base.stats()
-        assert set(sb) == {"ppermute_rounds",
-                           "peak_arena_blocks"} | cache_keys | gauge_keys
-        for k in ("ppermute_rounds", "peak_arena_blocks"):
+        assert set(sb) == {"peak_arena_blocks"} | comm_keys \
+            | cache_keys | gauge_keys
+        for k in ("ppermute_rounds", "peak_arena_blocks",
+                  "recv_bytes_max", "recv_bytes_mean"):
             assert s[k] == sb[k]       # same schedule, same arena
         assert s["stream_wire_bytes"] > 0
         # gating beats the flat-ring encoding's every-shift-every-round
@@ -561,7 +563,8 @@ def test_stream_engine_session_end_to_end():
         # simulated == executed wire: the simulator's independent lens
         # over the gated tables agrees with the table-derived number
         from repro.core.simulator import executed_wire_bytes
-        assert executed_wire_bytes(eng) == s["stream_wire_bytes"]
+        assert executed_wire_bytes(eng) == s["stream_wire_bytes"] \
+            == s["wire_bytes"]
         cs = eng.stats(compile=True)
         cu = base.stats(compile=True)
         for k in ("trace_lower_ms", "compile_ms", "jaxpr_lines",

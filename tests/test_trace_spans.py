@@ -290,6 +290,85 @@ def test_sweep_permute_scope_on_every_ppermute_2x2():
 
 
 # ---------------------------------------------------------------------------
+# the restricted collectives, counted once per session
+# ---------------------------------------------------------------------------
+
+_COMM = ("rounds", "wire_bytes", "recv_bytes_max", "recv_bytes_mean")
+
+_COMM_2X2 = """
+    import jax.numpy as jnp
+    from repro.core import engine as engine_mod, hlo_verify, simulator
+    from repro.core import sparse
+    from repro.core.engine import (Grid, PlanOptions, PSelInvEngine,
+                                   stack_values)
+    from repro.obs.registry import REGISTRY
+    from repro.obs.trace import TRACER
+    A = sparse.laplacian_2d(16, 8)
+    eng = PSelInvEngine.analyze(A, b=8, grid=Grid(2, 2),
+                                options=PlanOptions({opts}))
+    c = dict(eng.comm_counters())
+    assert c["wire_bytes"] == simulator.executed_wire_bytes(eng) > 0
+    assert c["recv_bytes_max"] >= c["recv_bytes_mean"] > 0
+    count = eng.compile_stats()["ppermute_count"]
+    if {stream}:
+        # one loop body replays the rounds; its permutes are the slots
+        slots = len(hlo_verify.expected_permutes(eng.program))
+        assert count == slots < c["rounds"], (count, slots, c)
+    else:
+        assert count == c["rounds"], (count, c)
+    st = eng.stats()
+    assert st["ppermute_rounds"] == c["rounds"]
+    for k in ("wire_bytes", "recv_bytes_max", "recv_bytes_mean"):
+        assert st[k] == c[k] == REGISTRY.get("selinv_engine_" + k).value
+
+    def recount(*a, **kw):
+        raise AssertionError("counted again")
+
+    simulator.volumes_from_plan = simulator.executed_wire_bytes = recount
+    engine_mod.ppermute_round_count = recount
+    vals = eng.prepare_values(A)
+    eng.solve(vals, dtype=jnp.float64).block_until_ready()
+    assert TRACER.spans() == []
+    TRACER.enable()
+    eng.solve(vals, dtype=jnp.float64).block_until_ready()
+    eng.solve(vals, dtype=jnp.float32).block_until_ready()
+    eng.solve(stack_values([vals] * 3), dtype=jnp.float32,
+              bucket=True).block_until_ready()
+    f64, f32, batch = [s.attrs for s in TRACER.spans()
+                       if s.name == "engine.solve"]
+    assert {{k: f64[k] for k in c}} == c
+    # priced at what each solve ships: 4-byte elements, 4 bucketed lanes
+    for attrs, scale in ((f32, 0.5), (batch, 4 * 0.5)):
+        assert attrs["rounds"] == c["rounds"]
+        for k in ("wire_bytes", "recv_bytes_max", "recv_bytes_mean"):
+            assert attrs[k] == c[k] * scale, (k, attrs, c)
+    print("OK")
+"""
+
+
+@pytest.mark.parametrize("opts", ["", "stream=True", "overlap=False"],
+                         ids=["overlapped", "stream", "level-serial"])
+def test_comm_counters_on_solve_span_2x2(opts):
+    """At grid 2x2: ``wire_bytes`` is the simulator's executed wire,
+    ``rounds`` the compiled permutes of an unrolled executor (the
+    stream's loop body holds one per comm slot), the most a device
+    receives is at least the mean, the gauges carry them, and a solve
+    counts nothing again: off it records nothing, on it stamps the
+    counts made at ``analyze``."""
+    out = run_sub(_COMM_2X2.format(opts=opts, stream="stream" in opts),
+                  ndev=4, x64=True)
+    assert "OK" in out
+
+
+def test_comm_counters_at_grid_1x1_are_zero(traced):
+    A = sparse.laplacian_2d(8, 8)
+    eng = PSelInvEngine.analyze(A, b=8, grid=Grid(1, 1))
+    eng.solve(eng.prepare_values(A)).block_until_ready()
+    (solve,) = [s for s in traced.spans() if s.name == "engine.solve"]
+    assert {k: solve.attrs[k] for k in _COMM} == dict.fromkeys(_COMM, 0)
+
+
+# ---------------------------------------------------------------------------
 # the benchmark's readers of the new spans
 # ---------------------------------------------------------------------------
 
