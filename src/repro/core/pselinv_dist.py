@@ -1283,6 +1283,40 @@ def prepare_values(A, bs: BlockStructure, nb: int, b: int, pr: int,
                 _shard_blocks(Dinv_g, nb, b, pr, pc))
 
 
+def _scatter_blocks(csr: Sequence, nb0: int, b: int, span) -> np.ndarray:
+    """B same-size CSR matrices → the zeroed f64 ``(B, nb0, nb0, b, b)``
+    block workspace holding each stored entry ``(r, c)`` at
+    ``[i, r // b, c // b, r % b, c % b]``: exactly what densifying each
+    matrix and blocking it gives, without the n×n copies. A matrix with
+    no duplicate entries (its indices sorted on a copy when they are
+    not) is one indexed assignment; one with duplicates sums them first,
+    in the data's own dtype and storage order, as ``todense`` does. Sets
+    ``nnz`` and ``summed`` (the matrices that took the summing path) on
+    ``span``; the matrices are only read."""
+    W = np.zeros((len(csr), nb0, nb0, b, b))
+    nnz = summed = 0
+    for i, M in enumerate(csr):
+        S = M if M.has_sorted_indices else M.sorted_indices()
+        dup = not S.has_canonical_format
+        if not dup:
+            M = S
+        r = np.repeat(np.arange(M.shape[0]), np.diff(M.indptr))
+        c = M.indices
+        flat = ((r // b * nb0 + c // b) * b + r % b) * b + c % b
+        Wi = W[i].reshape(-1)                       # a view of W[i]
+        if dup:
+            flat, at = np.unique(flat, return_inverse=True)
+            vals = np.zeros(flat.size, dtype=M.dtype)
+            np.add.at(vals, at, M.data)
+            Wi[flat] = vals
+            summed += 1
+        else:
+            Wi[flat] = M.data + 0     # + 0 folds -0.0 to 0.0, as todense
+        nnz += M.nnz
+    span.set(nnz=nnz, summed=summed)
+    return W
+
+
 def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
                         b: int, pr: int, pc: int
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -1313,9 +1347,11 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     per-request isolation (the serving layer) validate each matrix with
     :func:`check_values_pattern` first.
 
-    Traced as ``prep.check``, ``prep.densify`` (CSR → the dense
-    workspace), ``prep.factor`` (the supernode loop, L̂ and D⁻¹) and
-    ``prep.layout``, each with ``B``."""
+    Traced as ``prep.check``, ``prep.densify`` (the CSR entries
+    scattered into the zeroed block workspace; with ``nnz``, the entries
+    scattered, and ``summed``, how many matrices took the
+    duplicate-summing path), ``prep.factor`` (the supernode loop, L̂ and
+    D⁻¹) and ``prep.layout``, each with ``B``."""
     if not len(mats):
         raise ValueError("prepare_values_many needs at least one matrix")
     B, nb0 = len(mats), bs.nsuper
@@ -1328,12 +1364,12 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
                 raise ValueError(f"matrix {i} of {B}: {e}") from e
 
     # dense (B, nb0, nb0, b, b) block workspace holding the evolving
-    # Schur complement; fill lands in blocks the symbolic structure
-    # already owns, so reading only struct blocks below is exact
-    with TRACER.span("prep.densify", B=B):
-        W = np.stack([np.asarray(M.todense()) for M in csr])
-        W = (W.reshape(B, nb0, b, nb0, b).transpose(0, 1, 3, 2, 4)
-              .astype(np.float64, copy=True))
+    # Schur complement, zeroed and then written with each matrix's
+    # stored entries (no n×n dense copy); fill lands in blocks the
+    # symbolic structure already owns, so reading only struct blocks
+    # below is exact
+    with TRACER.span("prep.densify", B=B) as span:
+        W = _scatter_blocks(csr, nb0, b, span)
     with TRACER.span("prep.factor", B=B):
         Lh = np.zeros((B, nb, nb, b, b))
         Dinv = np.zeros((B, nb, nb, b, b))

@@ -388,3 +388,99 @@ def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts):
     with pytest.raises(ValueError,
                        match=r"matrix 2 of 3:.*outside the analyzed"):
         eng.prepare_values_many(bad)
+
+
+def _split_entries(A, parts=3):
+    """``A`` as a CSR whose every stored entry is split into ``parts``
+    duplicates (uneven shares, so their sum rounds) with each row's
+    indices reversed: unsorted, with duplicates (for ``parts`` > 1), the
+    same matrix."""
+    import scipy.sparse as sp
+    A = sp.csr_matrix(A)
+    w = np.arange(1, parts + 1) / (parts * (parts + 1) / 2)
+    rows = []
+    for r in range(A.shape[0]):
+        lo, hi = A.indptr[r], A.indptr[r + 1]
+        idx = np.tile(A.indices[lo:hi][::-1], parts)
+        val = np.concatenate([s * A.data[lo:hi][::-1] for s in w])
+        rows.append((idx, val))
+    indptr = np.cumsum([0] + [len(i) for i, _ in rows])
+    return sp.csr_matrix((np.concatenate([v for _, v in rows]),
+                          np.concatenate([i for i, _ in rows]), indptr),
+                         shape=A.shape)
+
+
+def _densified_workspace(csr, nb0, b, span):
+    """The block workspace as a dense copy of each matrix gives it:
+    ``todense`` blocked to ``(B, nb0, nb0, b, b)`` and cast to f64."""
+    W = np.stack([np.asarray(M.todense()) for M in csr])
+    return (W.reshape(len(csr), nb0, b, nb0, b).transpose(0, 1, 3, 2, 4)
+            .astype(np.float64, copy=True))
+
+
+@pytest.mark.parametrize("form", ["csr", "csr_duplicates_unsorted",
+                                  "csr_unsorted", "coo", "ndarray",
+                                  "csr_f32"])
+def test_prepare_values_many_scatter_is_bitwise_the_densified_workspace(
+        monkeypatch, form):
+    """The batched prep scatters each matrix's stored entries straight
+    into its block workspace: L̂ and D⁻¹ are bitwise what the workspace
+    built from a dense copy of each matrix gives, for every input form
+    the pattern check accepts (duplicates summed in storage order,
+    unsorted indices, COO, dense, f32 cast to f64), and the caller's
+    arrays are left as they were."""
+    import scipy.sparse as sp
+    from repro.core import pselinv_dist
+    A = sparse.laplacian_2d(16, 16)
+    I_A = sp.identity(A.shape[0], format="csr")
+    base = [A + c * I_A for c in (0.0, 0.5, 1.25)]
+    make = {"csr": lambda M: M,
+            "csr_duplicates_unsorted": _split_entries,
+            "csr_unsorted": lambda M: _split_entries(M, 1),
+            "coo": lambda M: sp.coo_matrix(_split_entries(M, 2)),
+            "ndarray": lambda M: M.toarray(),
+            "csr_f32": lambda M: M.astype(np.float32)}[form]
+    mats = [make(M) for M in base]
+    if form.startswith("csr_") and "unsorted" in form:
+        assert not any(M.has_sorted_indices for M in mats)
+    eng = PSelInvEngine.analyze(A, b=16, grid=Grid(1, 1),
+                                options=PlanOptions())
+    before = [tuple(np.copy(a) for a in (M.data, M.indices, M.indptr))
+              for M in mats if sp.issparse(M) and M.format == "csr"]
+    got = eng.prepare_values_many(mats)
+    monkeypatch.setattr(pselinv_dist, "_scatter_blocks",
+                        _densified_workspace)
+    ref = eng.prepare_values_many(mats)
+    assert np.array_equal(got.Lh, ref.Lh)
+    assert np.array_equal(got.Dinv, ref.Dinv)
+    after = [(M.data, M.indices, M.indptr)
+             for M in mats if sp.issparse(M) and M.format == "csr"]
+    for was, now in zip(before, after, strict=True):
+        for x, y in zip(was, now):
+            assert x.dtype == y.dtype and np.array_equal(x, y)
+
+
+def test_prepare_values_many_never_densifies(monkeypatch):
+    """With scipy's ``todense`` and ``toarray`` made to raise, the
+    batched prep still returns the same shards: no n×n dense copy of a
+    matrix is ever made on this path."""
+    import scipy.sparse as sp
+    A = sparse.laplacian_2d(16, 16)
+    mats = [A + c * sp.identity(A.shape[0]) for c in (0.0, 1.0)]
+    mats.append(_split_entries(mats[0]))
+    eng = PSelInvEngine.analyze(A, b=16, grid=Grid(1, 1),
+                                options=PlanOptions())
+    ref = eng.prepare_values_many(mats)
+
+    def dense(*a, **kw):
+        raise AssertionError("densified a sparse matrix")
+
+    for cls in (sp.csr_matrix, sp.csr_array, sp.coo_matrix, sp.coo_array,
+                sp.csc_matrix, sp.csc_array):
+        monkeypatch.setattr(cls, "todense", dense)
+        monkeypatch.setattr(cls, "toarray", dense)
+    with pytest.raises(AssertionError, match="densified"):
+        mats[0].todense()
+    got = eng.prepare_values_many(mats)
+    assert np.array_equal(got.Lh, ref.Lh)
+    assert np.array_equal(got.Dinv, ref.Dinv)

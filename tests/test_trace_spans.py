@@ -9,6 +9,7 @@ import sys
 import time
 import tracemalloc
 
+import numpy as np
 import pytest
 import scipy.sparse as sp
 
@@ -179,6 +180,9 @@ def test_serve_batch_children_cover_it_and_carry_rids(traced):
         assert parent(step) == "engine.prepare_values_many"
         (s,) = [x for x in spans if x.name == step]
         assert s.attrs["B"] == 3
+    (dens,) = [s for s in spans if s.name == "prep.densify"]
+    assert dens.attrs["nnz"] == sum(sp.csr_matrix(M).nnz for M in mats)
+    assert dens.attrs["summed"] == 0
     (h2d,) = [s for s in spans if s.name == "engine.h2d"]
     assert parent("engine.h2d") == "engine.solve"
     assert h2d.attrs["B"] == 3
@@ -186,6 +190,29 @@ def test_serve_batch_children_cover_it_and_carry_rids(traced):
     nb = srv.engine_for(A).nb
     assert h2d.attrs["bytes"] == 2 * 4 * nb * nb * 8 * 8 * 4
     assert not [s for s in spans if s.name == "jax.compile"]
+
+
+def test_prep_densify_counts_the_members_it_summed(traced):
+    """A batch member stored with duplicate entries takes the summing
+    path, and ``prep.densify`` says so: ``summed`` counts it and not the
+    member whose indices are merely unsorted; ``nnz`` counts every
+    stored entry, duplicates included."""
+    A = sparse.laplacian_2d(16, 16)
+    eng = PSelInvEngine.analyze(A, b=8, grid=Grid(1, 1))
+    dup = sp.csr_matrix((np.repeat(A.data / 2, 2),     # each entry as
+                         np.repeat(A.indices, 2),       # two halves
+                         2 * A.indptr), shape=A.shape)
+    rows = np.repeat(np.arange(A.shape[0]), np.diff(A.indptr))
+    desc = np.lexsort((-A.indices, rows))      # each row's columns reversed
+    rev = sp.csr_matrix((A.data[desc], A.indices[desc], A.indptr),
+                        shape=A.shape)
+    assert not dup.has_canonical_format and not rev.has_sorted_indices
+    traced.clear()
+    eng.prepare_values_many([A, dup, rev])
+    (dens,) = [s for s in traced.spans() if s.name == "prep.densify"]
+    assert dens.attrs["B"] == 3
+    assert dens.attrs["summed"] == 1
+    assert dens.attrs["nnz"] == 2 * A.nnz + dup.nnz
 
 
 class _NoClock:
