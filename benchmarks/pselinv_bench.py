@@ -1,16 +1,12 @@
 """Selected-inversion numeric benchmark: numpy vs jax vs pallas backends
 (the supernodal GEMM/TRSM hot spots through the kernel layer), plus the
-four-way distributed sweep comparison — legacy unrolled vs level-serial
-IR vs cross-level *overlapped* IR executor vs the uniform round-*stream*
-executor (one ``lax.fori_loop`` body; the latter three through the
-``PSelInvEngine`` session API) — on an 8-device host mesh (re-exec'd in
-a subprocess so the main process stays single-device): trace (lower)
-time, XLA compile time, HLO size, run time, ppermute round counts (the
-overlapped+coalesced stream must issue fewer), the simulated
-executed-schedule times of the IR paths, and their peak arena
-footprints (with the copy-free L̂ gathers the overlapped arena must stay
-within 1.1× of the level-serial executor's transient peak — it lands
-*below* it). The stream section records
+distributed sweep comparison — the cross-level *overlapped* executor vs
+the uniform round-*stream* executor (one ``lax.fori_loop`` body), both
+through the ``PSelInvEngine`` session API — on an 8-device host mesh
+(re-exec'd in a subprocess so the main process stays single-device):
+trace (lower) time, XLA compile time, HLO size, run time, ppermute round
+counts, the simulated executed-schedule times, and the peak arena
+footprints. The stream section records
 ``selinv/stream_compile_ms``/``stream_hlo_bytes``/``stream_us_per_call``
 plus the grid-factored wire metrics
 ``selinv/stream_wire_bytes``/``stream_shifts_per_round``, and asserts
@@ -22,8 +18,9 @@ staying bit-identical in the f32 run (≤1e-4 asserted; tests assert
 ≤1e-12 in f64). The engine section records
 multi-matrix batched solve throughput
 (``selinv/solve_batched_us_per_matrix_b{1,4,16}``), the speedup of one
-batched B=16 solve over sequential ``run_distributed`` calls (asserted
-≥5× per matrix, cold analyze excluded), and the engine structure-cache
+batched B=16 solve over sequential one-matrix ``engine.solve`` calls
+(asserted ≥5× per matrix, cold analyze excluded), and the engine
+structure-cache
 hit count. The serve section re-execs the mixed-structure Poisson
 traffic harness (``repro.serve.traffic``) with 8 devices + f64 and
 records the serving scorecard
@@ -153,14 +150,9 @@ def _run_ir_compare(full: bool):
 
 def _ir_compare_child(full: bool):
     import jax.numpy as jnp
-    from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.engine import Grid, PlanOptions, PSelInvEngine
-    from repro.core.pselinv_dist import (analyze_structure,
-                                         build_program_unrolled,
-                                         make_sweep_unrolled,
-                                         prepare_values, run_distributed)
+    from repro.core.pselinv_dist import analyze_structure, prepare_values
     from repro.core.trees import TreeKind
 
     nx = 32 if full else 16          # nb = nx (b=8 supernodes per grid row)
@@ -168,8 +160,6 @@ def _ir_compare_child(full: bool):
     b, pr, pc = 8, 4, 2
     bs, nb = analyze_structure(A, b, pr, pc)
     Lh_s, Dinv_s = prepare_values(A, bs, nb, b, pr, pc)
-    devs = np.array(jax.devices()[:pr * pc]).reshape(pr * pc)
-    mesh = Mesh(devs, ("xy",))
     Lh = jnp.asarray(Lh_s, jnp.float32)
     Dinv = jnp.asarray(Dinv_s, jnp.float32)
 
@@ -180,28 +170,13 @@ def _ir_compare_child(full: bool):
     hlo_bytes = {}
     times = {}
 
-    def lower_unrolled():
-        prog = build_program_unrolled(bs, nb, b, pr, pc, TreeKind.SHIFTED)
-        return jax.jit(shard_map(make_sweep_unrolled(prog), mesh=mesh,
-                                 in_specs=(P("xy"), P("xy")),
-                                 out_specs=P("xy")))
-
-    def lower_engine(overlap, stream=False):
-        eng = PSelInvEngine.analyze(
-            bs, b=b, grid=Grid(pr, pc),
-            options=PlanOptions(kind=TreeKind.SHIFTED, overlap=overlap,
-                                stream=stream))
-        return eng, eng.jitted()
-
-    for name in ("unrolled", "ir", "overlap", "stream"):
+    for name in ("overlap", "stream"):
         t0 = time.perf_counter()
-        if name == "unrolled":
-            fn = lower_unrolled()
-        else:
-            engines[name], fn = lower_engine(
-                overlap=(name in ("overlap", "stream")),
-                stream=(name == "stream"))
-        lowered = fn.lower(Lh, Dinv)
+        engines[name] = PSelInvEngine.analyze(
+            bs, b=b, grid=Grid(pr, pc),
+            options=PlanOptions(kind=TreeKind.SHIFTED,
+                                stream=(name == "stream")))
+        lowered = engines[name].jitted().lower(Lh, Dinv)
         t_trace = time.perf_counter() - t0
         hlo_text = lowered.as_text()
         hlo_lines = len(hlo_text.splitlines())
@@ -213,17 +188,16 @@ def _ir_compare_child(full: bool):
         out, dt = timed(
             lambda: jax.block_until_ready(compiled(Lh, Dinv)), reps=3)
         outs[name] = np.asarray(out)
-        if name in ("ir", "overlap", "stream"):
-            # static schedule metrics + executed-schedule timing, straight
-            # off the cached session (no re-lowering, no hand-wired
-            # round_schedule_from_* plumbing)
-            stats = engines[name].stats()
-            rounds[name] = stats["ppermute_rounds"]
-            peaks[name] = stats["peak_arena_blocks"]
-            sim = engines[name].simulate()
-            csv_row(f"selinv/sweep_{name}_simulated", sim.total_time * 1e6,
-                    f"nb={nb} rounds={rounds[name]} "
-                    f"peak_arena_blocks={sim.peak_arena_blocks}")
+        # static schedule metrics + executed-schedule timing, straight
+        # off the cached session (no re-lowering, no hand-wired
+        # round_schedule_from_* plumbing)
+        stats = engines[name].stats()
+        rounds[name] = stats["ppermute_rounds"]
+        peaks[name] = stats["peak_arena_blocks"]
+        sim = engines[name].simulate()
+        csv_row(f"selinv/sweep_{name}_simulated", sim.total_time * 1e6,
+                f"nb={nb} rounds={rounds[name]} "
+                f"peak_arena_blocks={sim.peak_arena_blocks}")
         csv_row(f"selinv/sweep_{name}_trace", t_trace * 1e6,
                 f"nb={nb} hlo_lines={hlo_lines}")
         csv_row(f"selinv/sweep_{name}_compile", t_compile * 1e6, f"nb={nb}")
@@ -232,12 +206,6 @@ def _ir_compare_child(full: bool):
         csv_row(f"selinv/sweep_{name}_run", dt * 1e6, f"nb={nb}")
         if name == "stream":
             csv_row("selinv/stream_us_per_call", dt * 1e6, f"nb={nb}")
-    err = float(abs(outs["ir"] - outs["unrolled"]).max())
-    csv_row("selinv/sweep_ir_vs_unrolled_maxdiff", 0.0, f"err={err:.2e}")
-    assert err < 1e-4, err
-    err_o = float(abs(outs["overlap"] - outs["ir"]).max())
-    csv_row("selinv/sweep_overlap_vs_ir_maxdiff", 0.0, f"err={err_o:.2e}")
-    assert err_o < 1e-4, err_o
     # the uniform round-stream executor replays the overlapped rounds
     # bit-for-bit (f64 identity asserted in tests; ≤1e-4 in this f32 run)
     err_t = float(abs(outs["stream"] - outs["overlap"]).max())
@@ -279,17 +247,10 @@ def _ir_compare_child(full: bool):
     assert wire_stream <= 2.0 * wire_unrolled, (wire_stream,
                                                 wire_unrolled)
     csv_row("selinv/sweep_ppermute_rounds", float(rounds["overlap"]),
-            f"nb={nb} serial={rounds['ir']} overlap={rounds['overlap']}")
-    assert rounds["overlap"] < rounds["ir"], rounds
-    # memory axis: with the copy-free L̂ gathers the overlapped arena
-    # peak must stay within 1.1× of the level-serial executor's
-    # transient peak (it lands *below* it; ~1.2× with the arena L̂ copy,
-    # ~3-4× before slot recycling)
+            f"nb={nb} overlap={rounds['overlap']}")
     csv_row("selinv/sweep_peak_arena_blocks", float(peaks["overlap"]),
-            f"nb={nb} serial={peaks['ir']} overlap={peaks['overlap']}")
-    assert peaks["overlap"] <= 1.1 * peaks["ir"], peaks
-    _engine_batched_bench(A, b, pr, pc, nb, engines["overlap"],
-                          run_distributed)
+            f"nb={nb} overlap={peaks['overlap']}")
+    _engine_batched_bench(A, nb, engines["overlap"])
     _obs_bench(engines["overlap"], Lh, Dinv, nb)
     return True
 
@@ -406,12 +367,12 @@ def _serve_bench_child(full: bool):
     return True
 
 
-def _engine_batched_bench(A, b, pr, pc, nb, eng, run_distributed):
+def _engine_batched_bench(A, nb, eng):
     """Analyze-once / solve-many throughput: batched engine solves at
     B∈{1,4,16} (per-matrix microseconds), the speedup of the batched
-    B=16 hot path over sequential ``run_distributed`` calls (warmed
-    first, so cold analyze/compile is excluded on both sides), and the
-    session structure-cache hit count."""
+    B=16 hot path over sequential one-matrix ``engine.solve`` calls
+    (warmed first, so cold analyze/compile is excluded on both sides),
+    and the session structure-cache hit count."""
     import jax.numpy as jnp
     from repro.core.engine import PSelInvEngine, stack_values
 
@@ -427,11 +388,11 @@ def _engine_batched_bench(A, b, pr, pc, nb, eng, run_distributed):
         per_matrix[B] = dt / B
         csv_row(f"selinv/solve_batched_us_per_matrix_b{B}",
                 dt / B * 1e6, f"nb={nb} B={B}")
-    # sequential run_distributed: one matrix per call through the shim
-    # (structure-cache warm — the 5× bar is about the per-call host
-    # factorization + dispatch the batched path amortizes away)
-    _, dt_seq = timed(lambda: run_distributed(
-        A, b=b, pr=pr, pc=pc, dtype=jnp.float32), reps=3, best=True)
+    # sequential engine.solve: one matrix per call (the 5× bar is about
+    # the per-call host factorization + dispatch the batched path
+    # amortizes away)
+    _, dt_seq = timed(lambda: np.asarray(eng.solve(A, dtype=jnp.float32)),
+                      reps=3, best=True)
     speedup = dt_seq / per_matrix[16]
     csv_row("selinv/engine_batched_speedup", speedup,
             f"nb={nb} B=16 seq_us={dt_seq * 1e6:.1f} "

@@ -27,7 +27,7 @@ def main():
     t0 = time.perf_counter()
     engine = PSelInvEngine.analyze(
         A, b=8, grid=Grid(4, 2),
-        options=PlanOptions(overlap=True, coalesce_max=8))
+        options=PlanOptions(coalesce_max=8))
     stats = engine.stats()
     print(f"analyze: {time.perf_counter() - t0:.2f}s  "
           f"rounds={stats['ppermute_rounds']} "
@@ -36,8 +36,7 @@ def main():
     # a second analyze of the same structure is a cache hit — same
     # engine object, nothing recompiled
     again = PSelInvEngine.analyze(A, b=8, grid=Grid(4, 2),
-                                  options=PlanOptions(overlap=True,
-                                                      coalesce_max=8))
+                                  options=PlanOptions(coalesce_max=8))
     print(f"re-analyze is cached: {again is engine} "
           f"(hits={PSelInvEngine.cache_hits})")
 
@@ -82,7 +81,7 @@ def main():
     #    dictionary of per-(grid-offset, lane-width) comm slots over the
     #    (pr, pc) torus, and each fori_loop round lax.cond-gates only the
     #    slots it actually uses — so the stream's executed wire bytes sit
-    #    near the unrolled executor's instead of shipping every device's
+    #    near the overlapped executor's instead of shipping every device's
     #    lane stack on every ring shift of every round.
     #    stats() reports both wire metrics; axis_factored=False recovers
     #    the old flat-ring encoding for an A/B, and shift_budget=k

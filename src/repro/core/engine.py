@@ -27,9 +27,6 @@ leading batch axis (``values`` shaped (B, P, nbr, nbc, b, b)) and runs
 all B matrices through one ``vmap``-ed sweep — one trace, one compile,
 B results (``solve_many`` stacks a list of matrices for you). This is
 the ROADMAP's "many matrices, same structure" serving path.
-
-``run_distributed``/``prepare_inputs`` in ``pselinv_dist`` remain as
-thin back-compat shims over this engine.
 """
 from __future__ import annotations
 
@@ -52,7 +49,7 @@ from ..obs.registry import REGISTRY
 from ..obs.trace import TRACER
 from .plan import (PlanOptions, peak_arena_blocks, ppermute_round_count)
 from .pselinv_dist import (PSelInvProgram, analyze_structure, build_program,
-                           check_grid_devices, make_sweep,
+                           check_grid_devices,
                            make_sweep_overlapped, make_sweep_stream,
                            pad_nb, prepare_values, prepare_values_many,
                            validate_uniform_widths)
@@ -323,12 +320,8 @@ class PSelInvEngine:
         handle); measurement paths pass False so they never touch the
         counter."""
         from jax.sharding import PartitionSpec as P
-        if self.options.stream:
-            mk = make_sweep_stream
-        elif self.options.overlap:
-            mk = make_sweep_overlapped
-        else:
-            mk = make_sweep
+        mk = (make_sweep_stream if self.options.stream
+              else make_sweep_overlapped)
         sweep = mk(self.program, batched=batched)
         if counted:
             inner = sweep
@@ -358,7 +351,8 @@ class PSelInvEngine:
     # ---- the value-only hot path --------------------------------------
     def prepare_values(self, A, dtype=None) -> SolveValues:
         """Numeric host factorization of one matrix against the cached
-        structure → device-layout shards. No symbolic work."""
+        structure → device-layout shards (the batched pass at B=1,
+        traced as ``engine.prepare_values``). No symbolic work."""
         with TRACER.span("engine.prepare_values"):
             Lh, Dinv = prepare_values(A, self.bs, self.nb, self.b,
                                       self.grid.pr, self.grid.pc)
@@ -394,9 +388,8 @@ class PSelInvEngine:
         solve one matrix; rank 6 ((B, P, nbr, nbc, b, b), the leading
         **batch axis**) solve B same-structure matrices through one
         vmapped sweep call. Returns the A⁻¹ shards in the same layout
-        (rank 5 or 6). ``dtype`` casts the values (f32 default,
-        matching ``run_distributed``); pass ``None`` to keep the
-        arrays' own dtype.
+        (rank 5 or 6). ``dtype`` casts the values (f32 default); pass
+        ``None`` to keep the arrays' own dtype.
 
         ``bucket=True`` pads a batched solve up to the next power-of-2
         bucket (:func:`bucket_size`) with zero-valued lanes and slices
@@ -430,19 +423,13 @@ class PSelInvEngine:
         return out
 
     def solve_many(self, mats: Sequence, dtype=jnp.float32, *,
-                   bucket: bool = False, batched_prep: bool = True):
-        """Convenience: numeric-factorize each same-structure matrix,
-        stack along the batch axis, and run ONE batched solve.
-        ``batched_prep`` routes the host factorization through the
-        stacked :meth:`prepare_values_many` pass (numerics match the
-        per-matrix path to rounding); ``bucket`` pads the batch to its
-        power-of-2 bucket so odd batch lengths share compiled
-        programs."""
-        if batched_prep and len(mats) > 1:
-            vals = self.prepare_values_many(mats)
-        else:
-            vals = stack_values([self.prepare_values(A) for A in mats])
-        return self.solve(vals, dtype=dtype, bucket=bucket)
+                   bucket: bool = False):
+        """Convenience: numeric-factorize the same-structure matrices in
+        one stacked :meth:`prepare_values_many` pass and run ONE batched
+        solve; ``bucket`` pads the batch to its power-of-2 bucket so odd
+        batch lengths share compiled programs."""
+        return self.solve(self.prepare_values_many(mats), dtype=dtype,
+                          bucket=bucket)
 
     def comm_counters(self) -> Dict[str, float]:
         """The restricted collectives of one single-matrix sweep, read
@@ -461,18 +448,11 @@ class PSelInvEngine:
             _, inc = volumes_from_plan(self.program.plan)
             recv = sum(inc.values(), np.zeros(self.grid.size))
             self._comm = {
-                "rounds": ppermute_round_count(self._executed_plan()),
+                "rounds": ppermute_round_count(self.program.overlap_plan),
                 "wire_bytes": executed_wire_bytes(self.program),
                 "recv_bytes_max": float(recv.max()),
                 "recv_bytes_mean": float(recv.mean())}
         return self._comm
-
-    def _executed_plan(self):
-        """The round tables the session's executor runs: the overlapped
-        stream (which the round-stream lowering replays) or the
-        level-serial levels."""
-        return (self.program.overlap_plan if self.options.overlap
-                else self.program.exec_plan)
 
     def table_bytes(self) -> int:
         """Approximate resident bytes of this session's compiled tables
@@ -529,9 +509,9 @@ class PSelInvEngine:
         traffic priced with while-loop trip counts —
         ``core/hlo_ir.collective_bytes``). This is
         how the uniform round-stream's program-size win over the
-        unrolled executors is inspected without running the bench — the
-        stream's jaxpr/HLO no longer grow with the round count. Uses
-        abstract ``ShapeDtypeStruct`` inputs: no values move, but trace,
+        unrolled overlapped executor is inspected without running the
+        bench — the stream's jaxpr/HLO no longer grow with the round
+        count. Uses abstract ``ShapeDtypeStruct`` inputs: no values move, but trace,
         lowering and XLA compilation really run (seconds, not
         microseconds). Pass the ``batched``/``dtype``/``batch_size``
         your solves use to measure that exact shape class (jit
@@ -666,14 +646,14 @@ class PSelInvEngine:
         Every scalar reported here is also published to the global
         metrics registry (``repro.obs.registry.REGISTRY``) under
         ``selinv_engine_*`` — the process-wide scrape surface."""
-        ex = self._executed_plan()
         cls = type(self)
         comm = self.comm_counters()
         out = {"ppermute_rounds": comm["rounds"],
                "wire_bytes": comm["wire_bytes"],
                "recv_bytes_max": comm["recv_bytes_max"],
                "recv_bytes_mean": comm["recv_bytes_mean"],
-               "peak_arena_blocks": peak_arena_blocks(ex),
+               "peak_arena_blocks": peak_arena_blocks(
+                   self.program.overlap_plan),
                # structure-cache health (class-level, all sessions) +
                # this session's own table footprint — the serving
                # layer's warm-engine dashboard reads these

@@ -14,8 +14,8 @@ the same typed :class:`~.verify.PlanDiagnostic` records.
 Check families (stable codes):
 
 * **collective conformance** — every compiled ``collective-permute``'s
-  source-target pairs must match a plan round (unrolled executors) or a
-  gated comm slot (stream; inside the fori_loop body, with the loop's
+  source-target pairs must match a plan round (the overlapped executor)
+  or a gated comm slot (stream; inside the fori_loop body, with the loop's
   trip count): a pair set no plan entry owns is ``hlo/perm-unknown``
   (a retargeted or foreign permute), a plan entry no compiled op
   matches is ``hlo/perm-missing`` (a dropped round/slot), and a
@@ -23,10 +23,9 @@ Check families (stable codes):
   trip count is ``hlo/loop-trip``.
 * **compiled byte conservation** — compiled wire blocks (pairs × payload
   width × slot activations) must equal the plan yardstick
-  (``stream.stream_wire_blocks`` / ``overlap_wire_blocks`` / the
-  level-serial round sum) and ``simulator.executed_wire_bytes``
-  (``hlo/bytes-drift``) — the compiled corner of the
-  simulated == executed == compiled triangle.
+  (``stream.stream_wire_blocks`` / ``overlap_wire_blocks``) and
+  ``simulator.executed_wire_bytes`` (``hlo/bytes-drift``) — the
+  compiled corner of the simulated == executed == compiled triangle.
 * **hot-path hygiene** — any all-gather/all-reduce/reduce-scatter/
   all-to-all in a program whose whole design is point-to-point rounds
   is ``hlo/stray-collective``; infeed/outfeed/host-placement transfers
@@ -107,8 +106,7 @@ class ExpectedPermute:
 def expected_permutes(prog) -> List[ExpectedPermute]:
     """The permute dictionary a compiled sweep of ``prog`` must realize,
     derived from whichever executor lowering the program carries (the
-    stream's gated slot tables, the overlapped global rounds, or the
-    level-serial per-phase rounds)."""
+    stream's gated slot tables or the overlapped global rounds)."""
     st = getattr(prog, "stream_tables", None)
     if st is not None:
         out = []
@@ -129,23 +127,9 @@ def expected_permutes(prog) -> List[ExpectedPermute]:
             width=int(rnd.width), trip=1, activations=1,
             where=f"round {t}")
             for t, rnd in enumerate(ov.rounds) if rnd.perm]
-    ex = getattr(prog, "exec_plan", None)
-    if ex is not None:
-        out = []
-        for lvl, lv in enumerate(ex.levels):
-            for phase in ("xfer_in", "bcast", "reduce", "xfer_out",
-                          "diag_reduce"):
-                for i, rnd in enumerate(getattr(lv, phase)):
-                    if rnd.perm:
-                        out.append(ExpectedPermute(
-                            pairs=frozenset((int(s), int(d))
-                                            for s, d in rnd.perm),
-                            width=1, trip=1, activations=1,
-                            where=f"level {lvl} {phase}[{i}]"))
-        return out
     raise ValueError(
-        "expected_permutes needs a program with stream_tables, "
-        "overlap_plan or exec_plan")
+        "expected_permutes needs a program with stream_tables or "
+        "overlap_plan")
 
 
 def expected_wire_blocks(prog) -> int:
@@ -441,16 +425,11 @@ def _traced_sweep(prog, *, batched: bool = False, dtype=None,
     from jax.sharding import PartitionSpec as P
 
     from ..compat import shard_map
-    from .pselinv_dist import (make_sweep, make_sweep_overlapped,
-                               make_sweep_stream)
+    from .pselinv_dist import make_sweep_overlapped, make_sweep_stream
     if dtype is None:
         dtype = jnp.float32
-    if getattr(prog, "stream_tables", None) is not None:
-        mk = make_sweep_stream
-    elif getattr(prog, "overlap_plan", None) is not None:
-        mk = make_sweep_overlapped
-    else:
-        mk = make_sweep
+    mk = (make_sweep_stream if getattr(prog, "stream_tables", None)
+          is not None else make_sweep_overlapped)
     P_dev = prog.pr * prog.pc
     if mesh is None:
         mesh = AbstractMesh((P_dev,), ("xy",))

@@ -15,10 +15,13 @@ One layering (host plan → device executor → simulator):
    * ``core/simulator.volumes`` / ``simulate`` walk ``CommPlan.ops``
      directly — the bytes they account are the bytes of the very trees
      the executor runs, *by construction*;
-   * ``core/pselinv_dist.make_sweep`` consumes the :class:`ExecPlan`
-     produced by :func:`compile_exec`: dense per-device index tables
-     (gather slot, scatter slot, receive mask, ppermute pairs) that
-     replace per-pair ``jnp.where`` chains with O(1) table lookups;
+   * ``core/pselinv_dist.make_sweep_overlapped`` consumes the
+     :class:`OverlappedExec` produced by :func:`schedule_overlapped`:
+     dense per-device index tables (gather slot, scatter slot,
+     accumulate / transpose masks, ppermute pairs) that replace per-pair
+     ``jnp.where`` chains with O(1) table lookups, and
+     ``core/stream.py`` lowers the same rounds for
+     ``make_sweep_stream``;
    * ``comm/treecomm.batched_rounds`` delegates its round merging to
      :func:`merge_round_lists`.
 
@@ -29,15 +32,15 @@ through :func:`tree_for` with zero schedule drift.
 Executor slot layout (uniform supernode width ``b``; ``nb`` padded so
 ``pr | nb`` and ``pc | nb``): global block (I, J) lives on device
 ``(I % pr, J % pc)`` at flat local slot ``(I//pr)*nbc + J//pc``; the
-level-stacked Û buffer keys slot ``k*nbc + I//pc`` and the partial-product
-buffer ``k*nbr + J//pr`` for the level's k-th supernode.
+level's GEMM lanes key Û slot ``k*nbc + I//pc`` and the partial product
+``k*nbr + J//pr`` for the level's k-th supernode.
 
-**Overlapped round stream** (:func:`schedule_overlapped`): the level
-batching above still barriers between elimination-tree levels, although
-only the GEMM→reduce→write→diag chain is actually serialized by data —
-a level's xfer-in and col-bcast traffic depends on nothing but the
-static L̂ shard and its own tree edges. The overlapped lowering
-therefore drops the level barrier entirely: every comm edge, local copy
+**Overlapped round stream** (:func:`schedule_overlapped`): batching
+each level behind a barrier would serialize the levels, although only
+the GEMM→reduce→write→diag chain is actually serialized by data — a
+level's xfer-in and col-bcast traffic depends on nothing but the static
+L̂ shard and its own tree edges. The overlapped lowering therefore has
+no level barrier: every comm edge, local copy
 and compute op of the whole sweep becomes a node of one dependence DAG
 (:func:`_overlap_items` documents the exact edge set), which is
 list-scheduled into a single global sequence of ppermute rounds over a
@@ -55,13 +58,9 @@ pipelining *across* levels, not just within one.
 carry up to ``coalesce_max`` blocks as extra lanes of the same permute
 (one latency, unique non-trash scatter slots, per-lane accumulate /
 transpose flags). Flat-tree roots and the xfer phases send many blocks
-between the same pair, so the global round count drops well below the
-level-serial path's — same bytes, fewer rounds
-(:func:`overlapped_byte_counts` == ``simulator.volumes``, tested).
-
-The level-barrier executor (:func:`compile_exec` + ``make_sweep``)
-remains fully supported for A/B comparison — ``run_distributed(...,
-overlap=False)`` and ``benchmarks/pselinv_bench.py`` drive it.
+between the same pair, so the global round count drops well below one
+single-block permute per tree edge — same bytes, fewer rounds
+(:func:`exec_byte_counts` == ``simulator.volumes``, tested).
 """
 from __future__ import annotations
 
@@ -80,10 +79,9 @@ from .trees import (HYBRID_FLAT_MAX, CommTree, TreeKind, build_tree,
 __all__ = [
     "PlanOptions", "PlanOp", "CommPlan", "build_plan", "tree_for",
     "merge_round_lists",
-    "pack_edges", "CommRound", "LocalRound", "LevelExec", "ExecPlan",
-    "compile_exec", "exec_byte_counts", "etree_levels",
+    "exec_byte_counts", "etree_levels",
     "GlobalRound", "ComputeOp", "OverlapLevel", "OverlappedExec",
-    "schedule_overlapped", "schedule_stream", "overlapped_byte_counts",
+    "schedule_overlapped", "schedule_stream",
     "ppermute_round_count", "peak_arena_blocks",
 ]
 
@@ -93,24 +91,22 @@ class PlanOptions:
     """The one knob bundle every schedule consumer reads.
 
     Collects what used to be scattered keyword arguments (``kind``,
-    ``overlap``, ``coalesce_max``, ``window``) across ``build_program``,
-    ``run_distributed``, :func:`schedule_overlapped` and the bench into a
-    single hashable value — it is part of the
-    :class:`~.engine.PSelInvEngine` structure-cache key, so two sessions
-    with equal structure but different options compile independently.
+    ``coalesce_max``, ``window``) across ``build_program``,
+    :func:`schedule_overlapped` and the bench into a single hashable
+    value — it is part of the :class:`~.engine.PSelInvEngine`
+    structure-cache key, so two sessions with equal structure but
+    different options compile independently.
 
     ``kind``: the tree family every restricted collective lowers through
-    (:func:`tree_for`). ``overlap``: compile the cross-level overlapped
-    round stream (the default executor) instead of the level-serial A/B
-    baseline. ``coalesce_max``: max blocks one (src, dst) pair may carry
-    as lanes of a single ppermute. ``window``: Û pool liveness window in
-    adjacent elimination-tree levels (``None`` = whole sweep resident;
-    see :func:`schedule_overlapped`). ``stream``: additionally lower the
-    overlapped round stream into the uniform round-indexed device tables
-    of ``core/stream.py`` and execute the whole sweep as one
-    ``lax.fori_loop`` body (program size independent of the round count
-    — the same rounds, replayed from tables instead of unrolled code;
-    requires ``overlap=True``).
+    (:func:`tree_for`). ``coalesce_max``: max blocks one (src, dst) pair
+    may carry as lanes of a single ppermute. ``window``: Û pool liveness
+    window in adjacent elimination-tree levels (``None`` = whole sweep
+    resident; see :func:`schedule_overlapped`). ``stream``: additionally
+    lower the overlapped round stream into the uniform round-indexed
+    device tables of ``core/stream.py`` and execute the whole sweep as
+    one ``lax.fori_loop`` body (program size independent of the round
+    count — the same rounds, replayed from tables instead of unrolled
+    code).
 
     ``axis_factored``: encode stream communication over the ``(pr, pc)``
     grid torus instead of the flat device ring — the packer groups
@@ -145,7 +141,6 @@ class PlanOptions:
     ``PSelInvEngine.lint_compiled`` adds the optimized-HLO layer from a
     real XLA compile."""
     kind: TreeKind = TreeKind.SHIFTED
-    overlap: bool = True
     coalesce_max: int = 8
     window: int | None = None
     stream: bool = False
@@ -163,11 +158,6 @@ class PlanOptions:
             raise ValueError(
                 f"PlanOptions(verify_compiled={self.verify_compiled!r}) "
                 "— expected one of 'error', 'warn', 'off'")
-        if self.stream and not self.overlap:
-            raise ValueError(
-                "PlanOptions(stream=True) lowers the *overlapped* round "
-                "stream — it requires overlap=True (the level-serial "
-                "executor has no global round stream to lower)")
         if self.shift_budget is not None:
             if not self.axis_factored:
                 raise ValueError(
@@ -365,172 +355,13 @@ def build_plan(bs: BlockStructure, grid: Grid2D, kind: TreeKind,
 
 
 # ---------------------------------------------------------------------------
-# executor compilation: ops -> packed rounds -> dense device tables
+# executor compilation: ops -> rounds -> dense device tables
 # ---------------------------------------------------------------------------
 
-# an edge is (src_dev, dst_dev, src_slot, dst_slot, nbytes)
-Edge = Tuple[int, int, int, int, float]
-
-
-def pack_edges(edges: Sequence[Edge]) -> List[List[Edge]]:
-    """Greedy-pack edges into ppermute rounds: per round each device
-    sources at most one transfer and sinks at most one transfer."""
-    rounds: List[List[Edge]] = []
-    for e in edges:
-        for rnd in rounds:
-            if all(e[0] != q[0] and e[1] != q[1] for q in rnd):
-                rnd.append(e)
-                break
-        else:
-            rounds.append([e])
-    return rounds
-
-
-@dataclass
-class CommRound:
-    """One ppermute with per-device gather/scatter tables.
-
-    ``slots[:, 0]`` is the flat gather index a sending device reads
-    (don't-care 0 for non-senders — ppermute drops their payload);
-    ``slots[:, 1]`` the flat scatter index a receiving device writes.
-    Non-receivers point at the buffer's **trash slot** (index = buffer
-    length): the executor allocates every writable buffer one block
-    larger, so no receive mask and no read-modify-write select is needed
-    — a write either lands or falls into the trash block."""
-    perm: List[Tuple[int, int]]
-    slots: np.ndarray         # (P, 2) int32 — [gather, scatter]
-    edges: List[Edge] = field(default_factory=list)
-
-
-@dataclass
-class LocalRound:
-    """Owner-local copy (src device == dst device): no communication,
-    same gather/scatter table shape as :class:`CommRound`."""
-    slots: np.ndarray         # (P, 2) int32
-
-
-def _round_tables(edges: Sequence[Edge], P: int, trash: int) -> CommRound:
-    slots = np.zeros((P, 2), np.int32)
-    slots[:, 1] = trash
-    perm = []
-    for (s, d, ss, ds, _nb) in edges:
-        perm.append((s, d))
-        slots[s, 0] = ss
-        slots[d, 1] = ds
-    return CommRound(perm=perm, slots=slots, edges=list(edges))
-
-
-def _local_rounds(ops: Sequence[Tuple[int, int, int]], P: int, trash: int
-                  ) -> List[LocalRound]:
-    """Pack (dev, src_slot, dst_slot) copies, one per device per round
-    (an owner-local copy is an edge with src device == dst device)."""
-    out = []
-    for rnd in pack_edges([(dev, dev, ss, ds, 0.0)
-                           for (dev, ss, ds) in ops]):
-        slots = np.zeros((P, 2), np.int32)
-        slots[:, 1] = trash
-        for (dev, _d, ss, ds, _nb) in rnd:
-            slots[dev, 0] = ss
-            slots[dev, 1] = ds
-        out.append(LocalRound(slots=slots))
-    return out
-
-
-def _schedule_tree_edges(per_op: Sequence[List[List[Edge]]], align: str,
-                         P: int, trash: int) -> List[CommRound]:
-    """Earliest-fire list scheduling of several collectives' tree edges
-    into shared executable rounds (the asynchronous pipelining: an edge
-    fires as soon as (1) its data dependency within its own tree is
-    satisfied — for a broadcast the edge that delivered to its source,
-    for a reduction every edge combining into its source — and (2) a
-    ppermute slot is free, i.e. its source/destination device is not
-    already used this round). Rounds are executed as barriers, so firing
-    strictly after all dependencies is sufficient for correctness."""
-    items: List[Tuple[Edge, List[int]]] = []
-    for rounds in per_op:
-        base = len(items)
-        delivered: Dict[int, int] = {}     # node -> item index that fed it
-        into: Dict[int, List[int]] = defaultdict(list)
-        flat = [e for rnd in rounds for e in rnd]
-        if align == "left":                # broadcast orientation
-            for j, e in enumerate(flat):
-                delivered[e[1]] = base + j
-            for j, e in enumerate(flat):
-                dep = delivered.get(e[0])
-                items.append((e, [dep] if dep is not None else []))
-        else:                              # reduce orientation
-            for j, e in enumerate(flat):
-                into[e[1]].append(base + j)
-            for e in flat:
-                items.append((e, list(into.get(e[0], ()))))
-
-    fired = [None] * len(items)
-    remaining = list(range(len(items)))
-    out: List[CommRound] = []
-    while remaining:
-        used_s, used_d, this = set(), set(), []
-        for i in remaining:
-            e, deps = items[i]
-            if any(fired[d] is None for d in deps):
-                continue
-            if e[0] in used_s or e[1] in used_d:
-                continue
-            this.append(i)
-            used_s.add(e[0])
-            used_d.add(e[1])
-        if not this:
-            raise ValueError("cyclic edge dependencies in tree schedule")
-        for i in this:
-            fired[i] = len(out)
-        remaining = [i for i in remaining if fired[i] is None]
-        out.append(_round_tables([items[i][0] for i in this], P, trash))
-    return out
-
-
-@dataclass
-class LevelExec:
-    """Dense tables driving one elimination-tree level of the sweep."""
-    Ks: np.ndarray                   # (nk,) supernode ids
-    xfer_in_local: List[LocalRound]  # Lh -> Uh (transpose), owner-local
-    xfer_in: List[CommRound]         # Lh -> Uh (transpose), p2p
-    bcast: List[CommRound]           # Uh -> Uh down grid columns
-    cmask: np.ndarray                # (pc, nk, nbc) struct mask
-    reduce: List[CommRound]          # partial -> partial along grid rows
-    kcs: np.ndarray                  # (nk,) K // pc
-    col_write_row: np.ndarray        # (pr, nk, nbr)
-    col_write_col: np.ndarray        # (pc, nk)
-    xfer_out_local: List[LocalRound]
-    xfer_out: List[CommRound]        # Ainv -> Ainv (transpose), p2p
-    krs: np.ndarray                  # (nk,) K // pr
-    diag_rowmask: np.ndarray         # (pr, nk)
-    diag_reduce: List[CommRound]     # S -> S within row K%pr
-    diag_root: np.ndarray            # (nk,) owner(K,K) device id
-    diag_slot: np.ndarray            # (nk,) flat Ainv slot of (K,K)
-
-
-@dataclass
-class ExecPlan:
-    nb: int
-    pr: int
-    pc: int
-    diag_set_root: np.ndarray        # (m,) device ids, empty-struct diag
-    diag_set_slot: np.ndarray        # (m,) flat Ainv slots
-    levels: List[LevelExec]
-
-    @property
-    def nbr(self) -> int:
-        return self.nb // self.pr
-
-    @property
-    def nbc(self) -> int:
-        return self.nb // self.pc
-
-
 def _level_tables(plan: CommPlan, Ks: Sequence[int]):
-    """The per-level dense mask/index tables both executor lowerings
-    share (one derivation — `compile_exec` and `_overlap_items` must
-    never drift): cmask, col_write_row, col_write_col, diag_rowmask,
-    kcs, krs, diag_root, diag_slot."""
+    """The per-level dense mask/index tables of one elimination-tree
+    level (:class:`OverlapLevel`'s masks): cmask, col_write_row,
+    col_write_col, diag_rowmask, kcs, krs, diag_root, diag_slot."""
     grid, nb = plan.grid, plan.nb
     pr, pc = grid.pr, grid.pc
     nbr, nbc = nb // pr, nb // pc
@@ -554,113 +385,6 @@ def _level_tables(plan: CommPlan, Ks: Sequence[int]):
         diag_root=np.array([grid.owner(K, K) for K in Ks], np.int32),
         diag_slot=np.array([(K // pr) * nbc + K // pc for K in Ks],
                            np.int32))
-
-
-def compile_exec(plan: CommPlan) -> ExecPlan:
-    """Compile the IR into the level-pipelined executable form: every
-    collective of a level shares rounds with its independent siblings."""
-    grid, nb = plan.grid, plan.nb
-    pr, pc, P = grid.pr, grid.pc, grid.size
-    if nb % pr or nb % pc:
-        raise ValueError(f"nb={nb} not divisible by grid {pr}x{pc}")
-    nbr, nbc = nb // pr, nb // pc
-    bs = plan.bs
-    by_sn = plan.ops_by_supernode()
-
-    droot = np.array([grid.owner(K, K) for K in plan.diag_only],
-                     dtype=np.int32)
-    dslot = np.array([(K // pr) * nbc + K // pc for K in plan.diag_only],
-                     dtype=np.int32)
-
-    levels: List[LevelExec] = []
-    for Ks in plan.sweep_levels:
-        nk = len(Ks)
-        k_of = {K: k for k, K in enumerate(Ks)}
-        xi_local: List[Tuple[int, int, int]] = []
-        xi_edges: List[Edge] = []
-        bcast_ops: List[List[List[Edge]]] = []
-        red_ops: List[List[List[Edge]]] = []
-        xo_local: List[Tuple[int, int, int]] = []
-        xo_edges: List[Edge] = []
-        dred_ops: List[List[List[Edge]]] = []
-        tabs = _level_tables(plan, Ks)
-
-        for K in Ks:
-            k = k_of[K]
-            C = [int(i) for i in bs.struct[K]]
-            for I in C:
-                # owner-local transfers are layout copies, not comm ops
-                if grid.owner(I, K) == grid.owner(K, I):
-                    xi_local.append((grid.owner(I, K),
-                                     (I // pr) * nbc + K // pc,
-                                     k * nbc + I // pc))
-                    xo_local.append((grid.owner(I, K),
-                                     (I // pr) * nbc + K // pc,
-                                     (K // pr) * nbc + I // pc))
-
-            for op in by_sn.get(K, ()):
-                if op.kind == "xfer":
-                    I = op.block
-                    dst = [r for r in op.participants if r != op.root][0]
-                    xi_edges.append((op.root, dst,
-                                     (I // pr) * nbc + K // pc,
-                                     k * nbc + I // pc, op.nbytes))
-                elif op.kind == "col-bcast":
-                    I = op.block
-                    slot = k * nbc + I // pc
-                    bcast_ops.append(
-                        [[(s, d, slot, slot, op.nbytes) for (s, d) in rnd]
-                         for rnd in op.tree.bcast_rounds()])
-                elif op.kind == "row-reduce":
-                    J = op.block
-                    slot = k * nbr + J // pr
-                    red_ops.append(
-                        [[(s, d, slot, slot, op.nbytes) for (s, d) in rnd]
-                         for rnd in op.tree.reduce_rounds()])
-                elif op.kind == "xfer-out":
-                    J = op.block
-                    dst = [r for r in op.participants if r != op.root][0]
-                    xo_edges.append((op.root, dst,
-                                     (J // pr) * nbc + K // pc,
-                                     (K // pr) * nbc + J // pc, op.nbytes))
-                elif op.kind == "diag-reduce":
-                    dred_ops.append(
-                        [[(s, d, k, k, op.nbytes) for (s, d) in rnd]
-                         for rnd in op.tree.reduce_rounds()])
-                elif op.kind == "diag-bcast":
-                    pass   # loop-1 normalization is absorbed on the host
-                           # (prepare_inputs ships L̂/D⁻¹ pre-normalized)
-                else:
-                    raise ValueError(
-                        f"compile_exec cannot lower op kind {op.kind!r} — "
-                        "teach it the new kind or the executed schedule "
-                        "silently drifts from the simulated one")
-
-        t_uh = nk * nbc           # trash slot of each writable buffer
-        t_pf = nk * nbr
-        t_ai = nbr * nbc
-        levels.append(LevelExec(
-            Ks=np.asarray(Ks, dtype=np.int64),
-            xfer_in_local=_local_rounds(xi_local, P, t_uh),
-            xfer_in=[_round_tables(r, P, t_uh)
-                     for r in pack_edges(xi_edges)],
-            bcast=_schedule_tree_edges(bcast_ops, "left", P, t_uh),
-            cmask=tabs["cmask"],
-            reduce=_schedule_tree_edges(red_ops, "right", P, t_pf),
-            kcs=tabs["kcs"],
-            col_write_row=tabs["col_write_row"],
-            col_write_col=tabs["col_write_col"],
-            xfer_out_local=_local_rounds(xo_local, P, t_ai),
-            xfer_out=[_round_tables(r, P, t_ai)
-                      for r in pack_edges(xo_edges)],
-            krs=tabs["krs"],
-            diag_rowmask=tabs["diag_rowmask"],
-            diag_reduce=_schedule_tree_edges(dred_ops, "right", P, nk),
-            diag_root=tabs["diag_root"],
-            diag_slot=tabs["diag_slot"]))
-
-    return ExecPlan(nb=nb, pr=pr, pc=pc, diag_set_root=droot,
-                    diag_set_slot=dslot, levels=levels)
 
 
 # ---------------------------------------------------------------------------
@@ -740,7 +464,7 @@ class ComputeOp:
 @dataclass
 class OverlapLevel:
     """Per-level compute metadata of the overlapped stream (the masks of
-    :class:`LevelExec`) plus the level's arena addressing. ``u_gather``
+    :func:`_level_tables`) plus the level's arena addressing. ``u_gather``
     replaces the dense Û base offset: the level's Û blocks live in
     compact recycled pool slots (:func:`_u_pool_layout`), and the table
     maps the GEMM's dense (k, j) lane grid back onto them (trash where
@@ -775,8 +499,7 @@ class OverlappedExec:
     shared trash block last. The read-only input L̂ shard is **not**
     copied in: xfer-in lanes gather straight from it through the
     per-lane ``glh``/``lglh`` masks of :class:`GlobalRound`, which
-    shaves ``n_ainv`` blocks off the footprint and puts the overlapped
-    peak *below* the level-serial executor's. Generations that alias
+    shaves ``n_ainv`` blocks off the footprint. Generations that alias
     the same physical slots are separated in time by the scheduler's
     generation-keyed anti-dependences (see :func:`_overlap_items`), so
     the arena footprint no longer grows with the number of levels."""
@@ -803,41 +526,13 @@ class OverlappedExec:
         return self.nb // self.pc
 
 
-def exec_byte_counts(ex: "ExecPlan | OverlappedExec"
+def exec_byte_counts(ov: OverlappedExec
                      ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray]]:
-    """Per-rank outgoing/incoming bytes by phase kind, summed over the
-    *compiled* rounds — the bytes the device program actually moves. The
-    equivalence test checks these against ``simulator.volumes`` (same
-    plan, independent accounting path). Accepts both the level-serial
-    :class:`ExecPlan` and the cross-level :class:`OverlappedExec`."""
-    if isinstance(ex, OverlappedExec):
-        return overlapped_byte_counts(ex)
-    P = ex.pr * ex.pc
-    out: Dict[str, np.ndarray] = defaultdict(lambda: np.zeros(P))
-    inc: Dict[str, np.ndarray] = defaultdict(lambda: np.zeros(P))
-
-    def add(kind: str, rounds: List[CommRound]):
-        for rnd in rounds:
-            for (s, d, _ss, _ds, nb_) in rnd.edges:
-                out[kind][s] += nb_
-                inc[kind][d] += nb_
-
-    for lv in ex.levels:
-        add("xfer", lv.xfer_in)
-        add("col-bcast", lv.bcast)
-        add("row-reduce", lv.reduce)
-        add("xfer-out", lv.xfer_out)
-        add("diag-reduce", lv.diag_reduce)
-    return dict(out), dict(inc)
-
-
-def overlapped_byte_counts(ov: OverlappedExec
-                           ) -> Tuple[Dict[str, np.ndarray],
-                                      Dict[str, np.ndarray]]:
-    """Per-rank outgoing/incoming bytes by op kind over the overlapped
-    global rounds. Coalescing moves the same bytes in fewer rounds, so
-    these must equal :func:`exec_byte_counts` of the level-serial path
-    and ``simulator.volumes`` (tested)."""
+    """Per-rank outgoing/incoming bytes by op kind, summed over the
+    *compiled* overlapped rounds — the bytes the device program actually
+    moves. Coalescing moves the same bytes in fewer rounds, so these
+    must equal ``simulator.volumes`` of the same plan (tested; an
+    independent accounting path)."""
     P = ov.pr * ov.pc
     out: Dict[str, np.ndarray] = defaultdict(lambda: np.zeros(P))
     inc: Dict[str, np.ndarray] = defaultdict(lambda: np.zeros(P))
@@ -848,41 +543,23 @@ def overlapped_byte_counts(ov: OverlappedExec
     return dict(out), dict(inc)
 
 
-def ppermute_round_count(ex: "ExecPlan | OverlappedExec") -> int:
+def ppermute_round_count(ov: OverlappedExec) -> int:
     """Number of ``lax.ppermute`` rounds a compiled sweep issues (local
     copy rounds are free and not counted)."""
-    if isinstance(ex, OverlappedExec):
-        return sum(1 for r in ex.rounds if r.perm)
-    return sum(len(lv.xfer_in) + len(lv.bcast) + len(lv.reduce)
-               + len(lv.xfer_out) + len(lv.diag_reduce)
-               for lv in ex.levels)
+    return sum(1 for r in ov.rounds if r.perm)
 
 
-def peak_arena_blocks(ex: "ExecPlan | OverlappedExec") -> int:
+def peak_arena_blocks(ov: OverlappedExec) -> int:
     """Peak per-device working-buffer footprint of a compiled sweep, in
     (b, b) blocks — the memory axis of the scalability story (the
-    symmetric-case PSelInv paper's per-process memory bound).
-
-    Level-serial: A⁻¹ (N + 1 trash) + the input L̂ shard (N, read in
-    place) + the largest level's transient Û/partial/S stacks (one
-    trash block each, freed at the level barrier). Overlapped: the flat
+    symmetric-case PSelInv paper's per-process memory bound): the flat
     arena (A⁻¹ + the compact recycled Û pool + the shared partial/S
     regions + trash, :class:`OverlappedExec`) **plus** the resident
     input L̂ shard — xfer-in lanes gather straight from the input
     through the per-lane ``glh`` masks, so the arena holds no L̂ copy
     and only the input's N blocks count. The read-only D⁻¹ shard
-    (N blocks) is input-resident in both paths and excluded, so the two
-    numbers compare like for like; before slot recycling the overlapped
-    arena dense-stacked *every* level's Û/partial/S and peaked at ~3×
-    the serial path at nb=32, compaction brought it to ~1.2×, and
-    dropping the arena L̂ copy lands it *below* the serial peak
-    (~0.9×, asserted in the bench and tests)."""
-    N = ex.nbr * ex.nbc
-    if isinstance(ex, OverlappedExec):
-        return ex.arena_blocks + N
-    lvl = max((len(lv.Ks) * (ex.nbc + ex.nbr + 1) + 3 for lv in ex.levels),
-              default=0)
-    return 2 * N + 1 + lvl
+    (N blocks) is excluded."""
+    return ov.arena_blocks + ov.nbr * ov.nbc
 
 
 def _u_pool_layout(plan: CommPlan, window: int | None
@@ -891,17 +568,15 @@ def _u_pool_layout(plan: CommPlan, window: int | None
     """The overlapped arena's Û **slot allocator**: compact, per-column,
     liveness-window recycled.
 
-    The level-serial executor's dense Û indexing (slot ``k*nbc + I//pc``)
-    reserves ``nk*nbc`` blocks per level although only struct-present
-    (K, I) pairs are ever filled; summed over every level of the sweep
-    that dense layout is what blew the overlapped arena to ~3-4× the
-    serial peak. Here each level's Û stack gets one compact slot per
-    live (K, I) entry instead, allocated **per grid column** (a block
-    Û(K, I) only exists on the devices of column ``I % pc``, so the two
-    columns' allocators share the same address range — the same arena
-    address holds different blocks on different columns, exactly like
-    the dense layout's repeated slot numbers, and the dependence keys
-    stay (device, slot, generation)).
+    A dense Û indexing (slot ``k*nbc + I//pc``) would reserve
+    ``nk*nbc`` blocks per level although only struct-present (K, I)
+    pairs are ever filled. Here each level's Û stack gets one compact
+    slot per live (K, I) entry instead, allocated **per grid column** (a
+    block Û(K, I) only exists on the devices of column ``I % pc``, so
+    the two columns' allocators share the same address range — the same
+    arena address holds different blocks on different columns, exactly
+    like the dense layout's repeated slot numbers, and the dependence
+    keys stay (device, slot, generation)).
 
     Liveness: a level's Û slots are written from its first xfer-in and
     last read by its ``scomp`` — so a slot is *dead* once its tenant
@@ -1010,10 +685,9 @@ def _overlap_items(plan: CommPlan, window: int | None = None
     :func:`_u_pool_layout`; a recycled slot's fill carries the previous
     tenant's ``scomp`` as an anti-dependence — ``scomp(T)`` dominates
     every reader of tenant T's slots (the broadcast forwards and the
-    gemm all precede it by RAW deps), so one dep per slot suffices. The
-    peak footprint drops from ~3× the level-serial executor's transient
-    peak (O(Σ_L nk_L · nbc) dense-stacked blocks) to ~1.2×
-    (:func:`peak_arena_blocks`, regression-guarded in the bench)."""
+    gemm all precede it by RAW deps), so one dep per slot suffices: the
+    pool holds compact slots, not O(Σ_L nk_L · nbc) dense-stacked
+    blocks (:func:`peak_arena_blocks`)."""
     grid, nb = plan.grid, plan.nb
     pr, pc = grid.pr, grid.pc
     if nb % pr or nb % pc:
@@ -1264,10 +938,10 @@ def schedule_overlapped(plan: CommPlan, coalesce_max: int = 8,
     Coalescing: within one round a (src, dst) device pair may carry up to
     ``coalesce_max`` blocks as extra payload lanes of the same permute
     (flat trees and the xfer phases send many blocks between the same
-    pair), so the global round count drops below the level-serial path's.
-    Ready edges are packed lowest-(level, phase) first, which keeps the
-    critical path as tight as the serial schedule while later levels'
-    traffic fills the idle lanes.
+    pair), so the global round count drops below one single-block
+    permute per tree edge. Ready edges are packed lowest-(level, phase)
+    first, which keeps the critical path as tight as a level-by-level
+    schedule while later levels' traffic fills the idle lanes.
 
     Arena memory: the partial and S stacks always live in one shared
     region per kind (their liveness never spans two levels), and the Û
@@ -1280,9 +954,8 @@ def schedule_overlapped(plan: CommPlan, coalesce_max: int = 8,
     traffic for permute slots) for arena blocks. The default ``None``
     keeps every level's compact Û slots resident, which preserves the
     unthrottled round count while compaction + partial/S recycling + the
-    copy-free L̂ gathers hold the peak footprint *below* the
-    level-serial executor's (~0.9×; :func:`peak_arena_blocks`, asserted
-    ≤1.1× in the bench and strictly below serial in the tests).
+    copy-free L̂ gathers bound the peak footprint
+    (:func:`peak_arena_blocks`).
 
     Shift-aware packing (``axis_factored``, the default): equal-priority
     ready edges are grouped by their grid-torus offset

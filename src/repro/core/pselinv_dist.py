@@ -7,13 +7,17 @@ is driven end-to-end by the **CommPlan IR** (`core/plan.py`) — the same
 plan object the simulator accounts, so the schedule that is *simulated*
 is the schedule that *runs*:
 
-  host plan   ``build_plan``      events → trees → per-edge bytes
-  compile     ``compile_exec``    level batching → packed ppermute rounds
-                                  → dense per-device index tables
-  device      ``make_sweep``      table-driven gather / ppermute / scatter
+  host plan   ``build_plan``           events → trees → per-edge bytes
+  compile     ``schedule_overlapped``  one cross-level stream of coalesced
+                                       ppermute rounds → dense per-device
+                                       index tables
+  device      ``make_sweep_overlapped`` (or ``make_sweep_stream``, the
+              same rounds as one ``lax.fori_loop``) — table-driven
+              gather / ppermute / scatter over a per-device block arena
 
 Per elimination-tree **level** (all supernodes at equal etree depth are
-independent — the paper's pipelining, executed rather than approximated):
+independent — the paper's pipelining, executed rather than approximated;
+level L+1's traffic rides the rounds of level L):
 
     (a) xfer-in    L̂(I,K) → owner of Û(K,I)        [p2p rounds, batched]
     (b) col-bcast  Û(K,I) down its grid column      [trees, shared rounds]
@@ -22,12 +26,10 @@ independent — the paper's pipelining, executed rather than approximated):
     (f) xfer-out   A⁻¹(J,K)ᵀ → A⁻¹(K,J) owner       [p2p, symmetric case]
     (2,3) diagonal update + restricted row reduce
 
-Every comm round is one ``lax.ppermute`` of a single (b, b) block with
-O(1) table lookups (``jnp.take`` + dynamic gather/scatter) — no per-pair
-``jnp.where`` chains — so trace/compile time stays flat in the number of
-concurrent collectives. The pre-IR per-supernode executor is kept as
-``build_program_unrolled``/``make_sweep_unrolled`` for the compile-time
-benchmark (``benchmarks/pselinv_bench.py``).
+Every comm round is one ``lax.ppermute`` of a stack of (b, b) blocks
+with O(1) table lookups (``jnp.take`` + dynamic gather/scatter) — no
+per-pair ``jnp.where`` chains — so trace/compile time stays flat in the
+number of concurrent collectives.
 
 Symmetric matrices (as the paper's implementation): Û(K,I) = L̂(I,K)ᵀ and
 A⁻¹(K,J) = A⁻¹(J,K)ᵀ — both identities hold blockwise for unpivoted LU.
@@ -38,9 +40,8 @@ zeros for structurally-zero blocks: numerics are unaffected, while the
 from __future__ import annotations
 
 import contextlib
-import warnings
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -48,31 +49,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import shard_map
-from ..kernels.ops import pselinv_level_gemm, pselinv_round_gemm
+from ..kernels.ops import pselinv_round_gemm
 from ..obs.trace import TRACER
-from .plan import (CommPlan, CommRound, ExecPlan, LocalRound,
-                   OverlappedExec, PlanOptions, build_plan, compile_exec,
-                   merge_round_lists, schedule_overlapped, schedule_stream)
+from .plan import (CommPlan, OverlappedExec, PlanOptions, build_plan,
+                   schedule_overlapped, schedule_stream)
 from .stream import (COMP_DIAGW, COMP_GEMM, COMP_NOOP, COMP_SCOMP,
                      COMP_WRITE, StreamTables)
 from .symbolic import BlockStructure, symbolic_factorize
-from .supernodal_lu import factorize
-from .selinv import normalize_factors
-from .trees import CommTree, TreeKind, build_tree, stable_hash
+from .trees import TreeKind
 
-__all__ = ["PSelInvProgram", "build_program", "build_program_unrolled",
-           "make_sweep", "make_sweep_overlapped", "make_sweep_stream",
-           "make_sweep_unrolled",
+__all__ = ["PSelInvProgram", "build_program",
+           "make_sweep_overlapped", "make_sweep_stream",
            "analyze_structure", "prepare_values", "prepare_values_many",
-           "check_values_pattern", "prepare_inputs",
-           "run_distributed", "gather_blocks"]
+           "check_values_pattern", "gather_blocks"]
 
 
 @dataclass
 class PSelInvProgram:
-    """A compiled sweep: grid geometry + (IR path) the CommPlan and its
-    executable tables, or (legacy path) the per-supernode schedules."""
+    """A compiled sweep: grid geometry, the CommPlan, its overlapped
+    round stream and, when asked for, the stream executor's tables."""
     nb: int
     b: int
     pr: int
@@ -80,10 +75,8 @@ class PSelInvProgram:
     kind: TreeKind
     bs: BlockStructure
     plan: Optional[CommPlan] = None
-    exec_plan: Optional[ExecPlan] = None
     overlap_plan: Optional[OverlappedExec] = None
     stream_tables: Optional[StreamTables] = None   # uniform round stream
-    iters: Optional[list] = None        # legacy unrolled schedule
 
     @property
     def nbr(self) -> int:
@@ -95,12 +88,11 @@ class PSelInvProgram:
 
 
 # ---------------------------------------------------------------------------
-# IR path: plan -> tables -> vectorized level-pipelined sweep
+# IR path: plan -> overlapped round stream -> tables
 # ---------------------------------------------------------------------------
 
 def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
                   kind: TreeKind = TreeKind.SHIFTED,
-                  overlap: bool = False,
                   coalesce_max: int = 8,
                   window: int | None = None,
                   stream: bool = False, *,
@@ -110,24 +102,18 @@ def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
     """Build the CommPlan IR and compile it to executable tables.
 
     ``options`` (a :class:`~.plan.PlanOptions`) bundles and overrides
-    the loose ``kind``/``overlap``/``coalesce_max``/``window``/``stream``
-    kwargs — the engine/session API passes the whole bundle through so
-    every consumer reads the same knobs.
+    the loose ``kind``/``coalesce_max``/``window``/``stream`` kwargs —
+    the engine/session API passes the whole bundle through so every
+    consumer reads the same knobs.
 
-    ``overlap=True`` compiles the cross-level overlapped round stream
+    The program always carries the cross-level overlapped round stream
     (`plan.schedule_overlapped`) consumed by
-    :func:`make_sweep_overlapped`; ``overlap=False`` the level-serial
-    :class:`ExecPlan` for :func:`make_sweep` (the A/B baseline). Only
-    the requested lowering is compiled — an A/B caller builds one
-    program per executor (as ``benchmarks/pselinv_bench.py`` does), or
-    runs ``plan.compile_exec(prog.plan)`` on the shared CommPlan.
-    ``window`` caps the overlapped arena's Û pool at that many live
-    levels (None = whole sweep resident; see
-    ``plan.schedule_overlapped``). ``stream=True`` (implies
-    ``overlap=True``) additionally lowers the overlapped rounds into the
-    uniform round-indexed tables of ``core/stream.py`` for
-    :func:`make_sweep_stream` — the whole sweep as one ``lax.fori_loop``
-    body.
+    :func:`make_sweep_overlapped`. ``window`` caps the arena's Û pool at
+    that many live levels (None = whole sweep resident; see
+    ``plan.schedule_overlapped``). ``stream=True`` additionally lowers
+    the overlapped rounds into the uniform round-indexed tables of
+    ``core/stream.py`` for :func:`make_sweep_stream` — the whole sweep
+    as one ``lax.fori_loop`` body.
 
     ``verify`` (overridden by ``options.verify`` when an options bundle
     is passed) runs the PlanLint static pass (``core/verify.py``) over
@@ -145,32 +131,27 @@ def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
     hot-path hygiene. Same three modes; default ``"off"`` because the
     pass costs a full re-trace + lowering of the sweep."""
     if options is not None:
-        kind, overlap = options.kind, options.overlap
+        kind = options.kind
         coalesce_max, window = options.coalesce_max, options.window
         stream = options.stream
         verify = options.verify
         verify_compiled = options.verify_compiled
-    if stream and not overlap:
-        raise ValueError(
-            "stream=True lowers the overlapped round stream — it "
-            "requires overlap=True")
     if nb % pr or nb % pc:
         raise ValueError(f"nb={nb} not divisible by grid {pr}x{pc}")
     from ..obs.trace import TRACER
     from .schedule import Grid2D
     with TRACER.span("plan.build", nb=nb):
         plan = build_plan(bs, Grid2D(pr, pc), kind, nb=nb)
-    ov = st = None
-    with TRACER.span("plan.schedule", stream=stream, overlap=overlap):
+    st = None
+    with TRACER.span("plan.schedule", stream=stream):
         if stream:
             ov, st = schedule_stream(plan, coalesce_max=coalesce_max,
                                      window=window, options=options)
-        elif overlap:
+        else:
             ov = schedule_overlapped(plan, coalesce_max=coalesce_max,
                                      window=window, options=options)
         prog = PSelInvProgram(
             nb=nb, b=b, pr=pr, pc=pc, kind=kind, bs=bs, plan=plan,
-            exec_plan=None if overlap else compile_exec(plan),
             overlap_plan=ov, stream_tables=st)
     if verify != "off":
         from .verify import enforce_verification, verify_program
@@ -178,7 +159,7 @@ def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
             enforce_verification(
                 verify_program(prog), mode=verify,
                 where=f"build_program(nb={nb}, grid={pr}x{pc}, "
-                      f"stream={stream}, overlap={overlap})")
+                      f"stream={stream})")
     if verify_compiled != "off":
         from .hlo_verify import lint_program
         from .verify import enforce_verification
@@ -186,8 +167,7 @@ def build_program(bs: BlockStructure, nb: int, b: int, pr: int, pc: int,
             enforce_verification(
                 lint_program(prog), mode=verify_compiled,
                 where=f"compiled sweep of build_program(nb={nb}, "
-                      f"grid={pr}x{pc}, stream={stream}, "
-                      f"overlap={overlap})")
+                      f"grid={pr}x{pc}, stream={stream})")
     return prog
 
 
@@ -205,62 +185,8 @@ def _scope(step: str, level: Optional[int] = None):
                 yield
 
 
-def _dyn(buf, i):
-    return lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-
-
 def _gi(buf, i):         # gather rows, bounds statically guaranteed
     return buf.at[i].get(mode="promise_in_bounds")
-
-
-def _apply_comm_rounds(dst, rounds: Sequence[CommRound], idx, op: str,
-                       src=None, transpose: bool = False):
-    """Run packed single-block ppermute rounds: gather the sender's slot,
-    permute, scatter at the receiver's slot. ``src=None`` gathers from the
-    (updating) destination buffer — required for multi-round trees where
-    internal nodes forward data received in earlier rounds.
-
-    ``dst`` carries one extra trash block (see ``CommRound.slots``), so
-    non-receivers need no mask: their write lands in the trash slot. Each
-    round is a handful of lean ``lax.dynamic_*`` ops — trace size is flat
-    in the number of concurrent collectives."""
-    if not rounds:
-        return dst
-    with _scope("permute"):
-        # one fused (R, P, 2) table per phase — a single closed-over
-        # constant, and a single dynamic lookup of this device's (R, 2)
-        # slot column
-        tables = jnp.asarray(np.stack([r.slots for r in rounds]))
-        slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
-        for i, rnd in enumerate(rounds):
-            buf = dst if src is None else src
-            payload = _dyn(buf, slots[i, 0])
-            moved = lax.ppermute(payload, "xy", rnd.perm)
-            if transpose:
-                moved = jnp.swapaxes(moved, -1, -2)
-            if op != "set":
-                moved = moved + _dyn(dst, slots[i, 1])
-            dst = lax.dynamic_update_index_in_dim(dst, moved, slots[i, 1],
-                                                  0)
-    return dst
-
-
-def _apply_local_rounds(dst, rounds: Sequence[LocalRound], idx,
-                        src=None, transpose: bool = False):
-    """Owner-local block copies (src owner == dst owner): same tables,
-    no communication; non-participants copy into the trash slot."""
-    if not rounds:
-        return dst
-    with _scope("lanes"):
-        tables = jnp.asarray(np.stack([r.slots for r in rounds]))
-        slots = lax.dynamic_index_in_dim(tables, idx, 1, keepdims=False)
-        for i, rnd in enumerate(rounds):
-            buf = dst if src is None else src
-            blk = _dyn(buf, slots[i, 0])
-            if transpose:
-                blk = jnp.swapaxes(blk, -1, -2)
-            dst = lax.dynamic_update_index_in_dim(dst, blk, slots[i, 1], 0)
-    return dst
 
 
 def _gather_lanes(arena, lh_f, g, lh_m, mixed: bool):
@@ -294,110 +220,6 @@ def _wrap_sweep(body, batched: bool):
         def sweep(Lh, Dinv):
             return body(Lh[0], Dinv[0])[None]
     return sweep
-
-
-def make_sweep(prog: PSelInvProgram, batched: bool = False):
-    """Build the level-pipelined SPMD sweep from the compiled IR tables.
-    Call inside shard_map over a 1-D mesh axis "xy" of size pr*pc, with
-    per-device blocks Lh: (nbr, nbc, b, b), Dinv: (nbr, nbc, b, b).
-    ``batched=True`` builds the multi-matrix variant (leading batch axis
-    on the value tensors; see :func:`_wrap_sweep`)."""
-    ex = prog.exec_plan
-    if ex is None:
-        raise ValueError("build_program() the IR path first")
-    b, pr, pc = prog.b, prog.pr, prog.pc
-    nbr, nbc = ex.nbr, ex.nbc
-
-    def body(Lh, Dinv):
-        idx = lax.axis_index("xy")
-        r = idx // pc
-        c = idx % pc
-        dtype = Lh.dtype
-        N = nbr * nbc
-        Lh_f = Lh.reshape(N, b, b)
-        Dinv_f = Dinv.reshape(N, b, b)
-        # one extra trash block: non-receiving devices scatter into it
-        Ainv_f = jnp.zeros((N + 1, b, b), dtype=dtype)
-
-        # structless supernodes (leaves without fill + grid padding):
-        # A⁻¹(K,K) = D(K)⁻¹ at the owner, one batched scatter
-        if len(ex.diag_set_root):
-            slots = jnp.asarray(ex.diag_set_slot)
-            m = (jnp.asarray(ex.diag_set_root) == idx).astype(dtype)
-            Ainv_f = Ainv_f.at[slots].add(
-                m[:, None, None] * _gi(Dinv_f, slots),
-                mode="promise_in_bounds")
-
-        for lv in ex.levels:
-            nk = len(lv.Ks)
-
-            # ---- (a) xfer-in: build the level's stacked Û buffer -------
-            Uh = jnp.zeros((nk * nbc + 1, b, b), dtype=dtype)
-            Uh = _apply_local_rounds(Uh, lv.xfer_in_local, idx, src=Lh_f,
-                                     transpose=True)
-            Uh = _apply_comm_rounds(Uh, lv.xfer_in, idx, "set", src=Lh_f,
-                                    transpose=True)
-
-            # ---- (b) col-bcast down each grid column -------------------
-            Uh = _apply_comm_rounds(Uh, lv.bcast, idx, "set")
-
-            # ---- (1) one masked block-GEMM for the whole level ---------
-            cm = jnp.take(jnp.asarray(lv.cmask, dtype=dtype), c, axis=0)
-            Uh_m = Uh[:-1].reshape(nk, nbc, b, b) * cm[:, :, None, None]
-            partial = pselinv_level_gemm(
-                Ainv_f[:-1].reshape(nbr, nbc, b, b), Uh_m)  # (nk, nbr, b, b)
-
-            # ---- (c) row-reduce onto the owners of A⁻¹(J,K) ------------
-            pf = jnp.concatenate(
-                [partial.reshape(nk * nbr, b, b),
-                 jnp.zeros((1, b, b), dtype=dtype)])
-            pf = _apply_comm_rounds(pf, lv.reduce, idx, "add")
-            partial = pf[:-1].reshape(nk, nbr, b, b)
-
-            # ---- write A⁻¹(C,K) for every K of the level ---------------
-            kcs = jnp.asarray(lv.kcs)
-            wr = jnp.take(jnp.asarray(lv.col_write_row, dtype=dtype), r,
-                          axis=0)                          # (nk, nbr)
-            wc = jnp.take(jnp.asarray(lv.col_write_col, dtype=dtype), c,
-                          axis=0)                          # (nk,)
-            w = jnp.transpose(wr * wc[:, None])            # (nbr, nk)
-            Ainv = Ainv_f[:-1].reshape(nbr, nbc, b, b)
-            old = Ainv.at[:, kcs].get(mode="promise_in_bounds")
-            new = -jnp.swapaxes(partial, 0, 1)             # (nbr, nk, b, b)
-            # masked delta + scatter-add: same-level K's write disjoint
-            # (device, slot) pairs, so duplicate kcs entries add zeros
-            Ainv = Ainv.at[:, kcs].add(w[:, :, None, None] * (new - old),
-                                       mode="promise_in_bounds")
-            Ainv_f = jnp.concatenate(
-                [Ainv.reshape(N, b, b), Ainv_f[N:]])
-
-            # ---- (f) xfer-out transposes A⁻¹(K,J) = A⁻¹(J,K)ᵀ ----------
-            Ainv_f = _apply_local_rounds(Ainv_f, lv.xfer_out_local, idx,
-                                         transpose=True)
-            Ainv_f = _apply_comm_rounds(Ainv_f, lv.xfer_out, idx, "set",
-                                        transpose=True)
-
-            # ---- (2,3) diagonal:  A⁻¹(K,K) = D⁻¹ − (Σ A⁻¹(K,I)L̂(I,K))ᵀ
-            krs = jnp.asarray(lv.krs)
-            Arow = _gi(Ainv_f[:-1].reshape(nbr, nbc, b, b), krs)
-            S = jnp.einsum("kjab,kjcb->kac",
-                           Arow * cm[:, :, None, None], Uh_m,
-                           precision=lax.Precision.HIGHEST)
-            rm = jnp.take(jnp.asarray(lv.diag_rowmask, dtype=dtype), r,
-                          axis=0)                          # (nk,)
-            S = S * rm[:, None, None]
-            S = jnp.concatenate([S, jnp.zeros((1, b, b), dtype=dtype)])
-            S = _apply_comm_rounds(S, lv.diag_reduce, idx, "add")[:-1]
-            slots = jnp.asarray(lv.diag_slot)
-            m = (jnp.asarray(lv.diag_root) == idx).astype(dtype)
-            newd = _gi(Dinv_f, slots) - jnp.swapaxes(S, -1, -2)
-            Ainv_f = Ainv_f.at[slots].add(
-                m[:, None, None] * (newd - _gi(Ainv_f, slots)),
-                mode="promise_in_bounds")
-
-        return Ainv_f[:-1].reshape(nbr, nbc, b, b)        # drop trash blk
-
-    return _wrap_sweep(body, batched)
 
 
 # ---------------------------------------------------------------------------
@@ -597,12 +419,13 @@ def make_sweep_overlapped(prog: PSelInvProgram, batched: bool = False):
     boundaries the dependence scheduler pinned them to — level L+1's
     xfer-in and col-bcast lanes ride the same rounds as level L's
     reduce / xfer-out / diag traffic instead of waiting for a level
-    barrier. Call under shard_map exactly like :func:`make_sweep`;
+    barrier. Call inside shard_map over a 1-D mesh axis "xy" of size
+    pr*pc, with per-device blocks Lh, Dinv: (nbr, nbc, b, b);
     ``batched=True`` builds the multi-matrix variant (leading batch
     axis on the value tensors; see :func:`_wrap_sweep`)."""
     ov = prog.overlap_plan
     if ov is None:
-        raise ValueError("build_program(..., overlap=True) first")
+        raise ValueError("build_program(...) first")
     b, pc = prog.b, prog.pc
     N = ov.n_ainv
 
@@ -653,7 +476,7 @@ def make_sweep_segments(prog: PSelInvProgram,
     their gated tables are lowered from it)."""
     ov = prog.overlap_plan
     if ov is None:
-        raise ValueError("build_program(..., overlap=True) first")
+        raise ValueError("build_program(...) first")
     b, pc = prog.b, prog.pc
     N = ov.n_ainv
     nrounds = len(ov.rounds)
@@ -719,15 +542,16 @@ def make_sweep_stream(prog: PSelInvProgram, batched: bool = False):
     inactive slot ships nothing, with per-round
     ``dynamic_slice``-selected gather/scatter/accumulate/transpose/
     L̂-gather lane tables (padded lanes scatter into the trash block,
-    exactly like the unrolled executor's coalescing padding; a gated-off
+    exactly like the overlapped executor's coalescing padding; a gated-off
     slot's zero arrival is never selected — no device receives on an
     inactive slot). The replayed round order, lane order and
     accumulation order are identical to :func:`make_sweep_overlapped`'s,
     so the f64 output is bit-identical — but jaxpr/HLO size no longer
     grows with the round count: the rounds are data (a few stacked
     tables), not code, and a round pays wire only for the slots it
-    actually uses. Call under shard_map exactly like :func:`make_sweep`;
-    ``batched=True`` builds the multi-matrix variant."""
+    actually uses. Call under shard_map exactly like
+    :func:`make_sweep_overlapped`; ``batched=True`` builds the
+    multi-matrix variant."""
     st = prog.stream_tables
     if st is None:
         raise ValueError(
@@ -902,246 +726,6 @@ def make_sweep_stream(prog: PSelInvProgram, batched: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# legacy unrolled path (pre-IR executor, kept for the compile benchmark)
-# ---------------------------------------------------------------------------
-
-def _pack_rounds(pairs: List[Tuple[int, int, int]]):
-    """Greedy-pack (src, dst, key) transfers into ppermute rounds with
-    unique sources and destinations per round."""
-    rounds: List[List[Tuple[int, int, int]]] = []
-    for p in pairs:
-        for rnd in rounds:
-            if all(p[0] != q[0] and p[1] != q[1] for q in rnd):
-                rnd.append(p)
-                break
-        else:
-            rounds.append([p])
-    return rounds
-
-
-def _merge_tree_rounds(trees: Sequence[Tuple[CommTree, callable]], op: str):
-    """Merge several disjoint-group trees into shared global-id rounds
-    (``mapper`` translates tree coordinates to global device ids) —
-    delegates the merge + disjointness check to the IR's
-    :func:`~.plan.merge_round_lists`."""
-    per_tree = []
-    for tree, mapper in trees:
-        rounds = tree.bcast_rounds() if op == "bcast" else tree.reduce_rounds()
-        per_tree.append([[(mapper(s), mapper(d)) for (s, d) in rnd]
-                         for rnd in rounds])
-    return merge_round_lists(per_tree, op)
-
-
-@dataclass
-class _IterSchedule:
-    K: int
-    C: List[int]
-    xfer_in_rounds: list          # rounds of (src, dst, I)
-    xfer_in_local: List[int]      # I with owner(I,K) == owner(K,I)
-    bcast_rounds: list            # merged global-id rounds
-    reduce_rounds: list
-    xfer_out_rounds: list         # rounds of (src, dst, J)
-    xfer_out_local: List[int]
-    diag_reduce_rounds: list
-    col_mask: np.ndarray          # (NBc, pc) 1.0 where global col in C
-    row_mask: np.ndarray          # (NBr, pr)
-
-
-def build_program_unrolled(bs: BlockStructure, nb: int, b: int, pr: int,
-                           pc: int, kind: TreeKind = TreeKind.SHIFTED
-                           ) -> PSelInvProgram:
-    """The pre-IR per-supernode schedule (one tree per mesh column/row per
-    supernode, re-derived here rather than read from the CommPlan).
-    Retained as the baseline of the compile-time benchmark."""
-    if nb % pr or nb % pc:
-        raise ValueError(f"nb={nb} not divisible by grid {pr}x{pc}")
-    nbr, nbc = nb // pr, nb // pc
-
-    def owner(I: int, J: int) -> int:
-        return (I % pr) * pc + (J % pc)
-
-    iters: List[_IterSchedule] = []
-    for K in range(nb - 1, -1, -1):
-        C = [int(i) for i in bs.struct[K]] if K < bs.nsuper else []
-        krow, kcol = K % pr, K % pc
-
-        # (a) xfer-in
-        pairs, local = [], []
-        for I in C:
-            s, d = owner(I, K), owner(K, I)
-            (local if s == d else pairs).append(
-                I if s == d else (s, d, I))
-        xfer_in_rounds = _pack_rounds([p for p in pairs])
-
-        # (b) col-bcast: per mesh column, tree over participant rows
-        rows = sorted({J % pr for J in C})
-        recv_rows = [r for r in rows if r != krow]
-        bcast_trees = []
-        if recv_rows:
-            for c in range(pc):
-                tag = stable_hash(K, c, 0xB)
-                tree = build_tree(kind, krow, recv_rows, tag=tag)
-                bcast_trees.append(
-                    (tree, (lambda cc: (lambda r: r * pc + cc))(c)))
-        bcast_rounds = _merge_tree_rounds(bcast_trees, "bcast")
-
-        # (c) row-reduce: per mesh row, tree over participant cols
-        cols = sorted({I % pc for I in C} | {kcol})
-        recv_cols = [c for c in cols if c != kcol]
-        red_trees = []
-        if recv_cols:
-            for r in range(pr):
-                tag = stable_hash(K, r, 0xC)
-                tree = build_tree(kind, kcol, recv_cols, tag=tag)
-                red_trees.append(
-                    (tree, (lambda rr: (lambda c: rr * pc + c))(r)))
-        reduce_rounds = _merge_tree_rounds(red_trees, "reduce")
-
-        # (f) xfer-out (transpose to upper)
-        pairs, localo = [], []
-        for J in C:
-            s, d = owner(J, K), owner(K, J)
-            (localo if s == d else pairs).append(
-                J if s == d else (s, d, J))
-        xfer_out_rounds = _pack_rounds([p for p in pairs])
-
-        # (g) diagonal reduce within mesh row krow
-        diag_trees = []
-        if recv_cols:
-            tag = stable_hash(K, 0xD)
-            tree = build_tree(kind, kcol, recv_cols, tag=tag)
-            diag_trees.append((tree, lambda c: krow * pc + c))
-        diag_reduce_rounds = _merge_tree_rounds(diag_trees, "reduce")
-
-        mask = np.zeros(nb)
-        for I in C:
-            mask[I] = 1.0
-        col_mask = mask.reshape(nbc, pc)
-        row_mask = mask.reshape(nbr, pr)
-
-        iters.append(_IterSchedule(
-            K=K, C=C, xfer_in_rounds=xfer_in_rounds, xfer_in_local=local,
-            bcast_rounds=bcast_rounds, reduce_rounds=reduce_rounds,
-            xfer_out_rounds=xfer_out_rounds, xfer_out_local=localo,
-            diag_reduce_rounds=diag_reduce_rounds,
-            col_mask=col_mask, row_mask=row_mask))
-
-    return PSelInvProgram(nb=nb, b=b, pr=pr, pc=pc, kind=kind, bs=bs,
-                          iters=iters)
-
-
-def _apply_rounds(x, rounds, axis, op):
-    idx = lax.axis_index(axis)
-    for rnd in rounds:
-        perm = [(s, d) for (s, d) in rnd]
-        moved = lax.ppermute(x, axis, perm)
-        recv = jnp.zeros((), dtype=bool)
-        for _, dst in perm:
-            recv = recv | (idx == dst)
-        if op == "bcast":
-            x = jnp.where(recv, moved, x)
-        else:
-            x = jnp.where(recv, x + moved, x)
-    return x
-
-
-def make_sweep_unrolled(prog: PSelInvProgram):
-    """The pre-IR sweep: per-supernode processing with per-pair
-    ``jnp.where`` chains. O(nb × rounds × pairs) trace size — the
-    benchmark baseline the IR executor is measured against."""
-    if prog.iters is None:
-        raise ValueError("use build_program_unrolled()")
-    nb, b, pr, pc = prog.nb, prog.b, prog.pr, prog.pc
-    nbr, nbc = prog.nbr, prog.nbc
-
-    def sweep(Lh, Dinv):
-        Lh = Lh[0]        # drop the size-1 sharded device axis
-        Dinv = Dinv[0]
-        idx = lax.axis_index("xy")
-        r = idx // pc
-        c = idx % pc
-        dtype = Lh.dtype
-        Ainv = jnp.zeros_like(Lh)
-
-        for it in prog.iters:
-            K = it.K
-            krow, kcol = K % pr, K % pc
-            kr, kc = K // pr, K // pc
-            root_id = krow * pc + kcol
-
-            if not it.C:
-                Ainv = Ainv.at[kr, kc].set(
-                    jnp.where(idx == root_id, Dinv[kr, kc], Ainv[kr, kc]))
-                continue
-
-            # ---- (a) xfer-in: build Û(K,·) buffer ----------------------
-            Uh = jnp.zeros((nbc, b, b), dtype=dtype)
-            for I in it.xfer_in_local:
-                dev = (I % pr) * pc + (K % pc)
-                Uh = Uh.at[I // pc].set(
-                    jnp.where(idx == dev,
-                              Lh[I // pr, kc].T, Uh[I // pc]))
-            for rnd in it.xfer_in_rounds:
-                payload = jnp.zeros((b, b), dtype=dtype)
-                for (s, d, I) in rnd:
-                    payload = jnp.where(idx == s, Lh[I // pr, kc], payload)
-                moved = lax.ppermute(payload, "xy",
-                                     [(s, d) for (s, d, _) in rnd])
-                for (s, d, I) in rnd:
-                    Uh = Uh.at[I // pc].set(
-                        jnp.where(idx == d, moved.T, Uh[I // pc]))
-
-            # ---- (b) col-bcast of Û down each grid column --------------
-            Uh = _apply_rounds(Uh, it.bcast_rounds, "xy", "bcast")
-
-            # ---- (1) local GEMM:  Σ_I A⁻¹(J,I)·L̂(I,K) ------------------
-            cmask = jnp.take(jnp.asarray(it.col_mask, dtype=dtype), c,
-                             axis=1)                       # (nbc,)
-            Uh_m = Uh * cmask[:, None, None]
-            # A⁻¹(J,I) @ L̂(I,K) = Ainv[i,j] @ Uh[j]ᵀ
-            partial = jnp.einsum("ijab,jcb->iac", Ainv, Uh_m,
-                                 precision=lax.Precision.HIGHEST)
-
-            # ---- (c) row-reduce onto column K%pc ------------------------
-            partial = _apply_rounds(partial, it.reduce_rounds, "xy", "reduce")
-
-            # ---- write A⁻¹(C,K) -----------------------------------------
-            rmask = jnp.take(jnp.asarray(it.row_mask, dtype=dtype), r,
-                             axis=1)                       # (nbr,)
-            sel = (idx % pc == kcol) & True
-            wr = (rmask[:, None, None] > 0) & sel
-            Ainv = Ainv.at[:, kc].set(jnp.where(wr, -partial, Ainv[:, kc]))
-
-            # ---- (f) xfer-out transposes A⁻¹(K,J) = A⁻¹(J,K)ᵀ -----------
-            for J in it.xfer_out_local:
-                dev = (J % pr) * pc + kcol
-                Ainv = Ainv.at[kr, J // pc].set(
-                    jnp.where(idx == dev, Ainv[J // pr, kc].T,
-                              Ainv[kr, J // pc]))
-            for rnd in it.xfer_out_rounds:
-                payload = jnp.zeros((b, b), dtype=dtype)
-                for (s, d, J) in rnd:
-                    payload = jnp.where(idx == s, Ainv[J // pr, kc], payload)
-                moved = lax.ppermute(payload, "xy",
-                                     [(s, d) for (s, d, _) in rnd])
-                for (s, d, J) in rnd:
-                    Ainv = Ainv.at[kr, J // pc].set(
-                        jnp.where(idx == d, moved.T, Ainv[kr, J // pc]))
-
-            # ---- (2,3) diagonal:  A⁻¹(K,K) = Dinv − (Σ A⁻¹(K,I)L̂(I,K))ᵀ
-            S = jnp.einsum("jab,jcb->ac", Ainv[kr] * cmask[:, None, None],
-                           Uh_m, precision=lax.Precision.HIGHEST)
-            S = jnp.where(r == krow, S, jnp.zeros_like(S))
-            S = _apply_rounds(S, it.diag_reduce_rounds, "xy", "reduce")
-            Ainv = Ainv.at[kr, kc].set(
-                jnp.where(idx == root_id, Dinv[kr, kc] - S.T, Ainv[kr, kc]))
-
-        return Ainv[None]   # restore the sharded device axis
-
-    return sweep
-
-
-# ---------------------------------------------------------------------------
 # host-side data preparation / gather
 # ---------------------------------------------------------------------------
 
@@ -1169,7 +753,7 @@ def pad_nb(nsuper: int, pr: int, pc: int) -> int:
 
 def analyze_structure(A, b: int, pr: int, pc: int
                       ) -> Tuple[BlockStructure, int]:
-    """The value-independent half of :func:`prepare_inputs`: symbolic
+    """The value-independent half of the host prep: symbolic
     factorization + uniform-width validation + grid padding. Everything
     the engine caches hangs off this (bs, nb) pair."""
     import scipy.sparse as sp
@@ -1195,10 +779,10 @@ def check_values_pattern(A, bs: BlockStructure, b: int):
     structure would be silently truncated into the selected inverse of a
     *different* matrix — reject it instead (O(nnz) block-coordinate
     check against the symmetric filled pattern). Returns the matrix as
-    CSR. Shared by :func:`prepare_values`, the batched
-    :func:`prepare_values_many`, and the serving layer's per-request
-    admission check (``repro.serve``) — a bad request must be rejectable
-    *before* it joins a batch, so its neighbors still solve."""
+    CSR. Shared by :func:`prepare_values_many` and the serving layer's
+    per-request admission check (``repro.serve``) — a bad request must
+    be rejectable *before* it joins a batch, so its neighbors still
+    solve."""
     import scipy.sparse as sp
 
     A = sp.csr_matrix(A)
@@ -1232,8 +816,8 @@ def _shard_blocks(G: np.ndarray, nb: int, b: int, pr: int,
                   pc: int) -> np.ndarray:
     """Dense (…, nb, nb, b, b) block grid → (…, pr*pc, nbr, nbc, b, b)
     device shards for ``in_specs=P("xy")`` (cyclic over both grid dims).
-    The one layout rule — :func:`prepare_values`,
-    :func:`prepare_values_many` and :func:`gather_blocks` must agree."""
+    The one layout rule — :func:`prepare_values_many` and
+    :func:`gather_blocks` must agree."""
     nbr, nbc = nb // pr, nb // pc
     lead = G.shape[:-4]
     G = G.reshape(lead + (nbr, pr, nbc, pc, b, b))
@@ -1246,41 +830,19 @@ def _shard_blocks(G: np.ndarray, nb: int, b: int, pr: int,
 
 def prepare_values(A, bs: BlockStructure, nb: int, b: int, pr: int,
                    pc: int) -> Tuple[np.ndarray, np.ndarray]:
-    """The numeric half of :func:`prepare_inputs`: factorize this
-    matrix's *values* on the host against an already-analyzed structure,
-    normalize, and lay out the dense-blocked shards.
+    """Numeric host prep of one matrix against an already-analyzed
+    structure: :func:`prepare_values_many` at B=1, its stacked shards'
+    only entry.
 
     Returns (Lh, Dinv) with shape (pr*pc, nbr, nbc, b, b) for
     ``in_specs=P("xy")``. The caller guarantees ``A`` has the sparsity
     structure that produced ``bs`` — this is the engine's analyze-once /
-    solve-many hot path, so no symbolic work happens here.
-
-    Traced as ``prep.check``, ``prep.densify`` (the supernodal LU),
-    ``prep.factor`` (L̂ and D⁻¹) and ``prep.layout``, each with ``B=1``."""
-    import scipy.linalg as sla
-
-    with TRACER.span("prep.check", B=1):
-        A = check_values_pattern(A, bs, b)
-    nb0 = bs.nsuper
-
-    with TRACER.span("prep.densify", B=1):
-        lu = factorize(A, bs=bs)
-    with TRACER.span("prep.factor", B=1):
-        Lhat, _ = normalize_factors(lu)
-        Lh_g = np.zeros((nb, nb, b, b))
-        Dinv_g = np.zeros((nb, nb, b, b))
-        for (I, K), blk in Lhat.items():
-            Lh_g[I, K] = np.asarray(blk)
-        for K in range(nb0):
-            linv = sla.solve_triangular(np.asarray(lu.Ldiag[K]), np.eye(b),
-                                        lower=True, unit_diagonal=True)
-            Dinv_g[K, K] = sla.solve_triangular(np.asarray(lu.Udiag[K]),
-                                                linv, lower=False)
-    with TRACER.span("prep.layout", B=1):
-        for K in range(nb0, nb):   # padding supernodes: identity diag
-            Dinv_g[K, K] = np.eye(b)
-        return (_shard_blocks(Lh_g, nb, b, pr, pc),
-                _shard_blocks(Dinv_g, nb, b, pr, pc))
+    solve-many hot path, so no symbolic work happens here. A pattern
+    that escapes the structure raises :func:`check_values_pattern`'s own
+    ``ValueError``. Traced as :func:`prepare_values_many` is, with
+    ``B=1``."""
+    Lh, Dinv = prepare_values_many([A], bs, nb, b, pr, pc)
+    return Lh[0], Dinv[0]
 
 
 def _scatter_blocks(csr: Sequence, nb0: int, b: int, span) -> np.ndarray:
@@ -1323,11 +885,10 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     """Batched host factorization: B same-structure matrices → stacked
     ``(B, pr*pc, nbr, nbc, b, b)`` shards in ONE structure-driven pass.
 
-    Same math as B :func:`prepare_values` calls — right-looking
-    supernodal elimination over the filled structure, in supernode
-    order, with no pivoting across supernodes — all in f64, with every
-    block stacked ``(B, b, b)``. Each supernode K costs a few BLAS-3
-    calls per batch member, on the Schur-updated blocks ``A``:
+    Right-looking supernodal elimination over the filled structure, in
+    supernode order, with no pivoting across supernodes — all in f64,
+    with every block stacked ``(B, b, b)``. Each supernode K costs a few
+    BLAS-3 calls per batch member, on the Schur-updated blocks ``A``:
 
     - ``D⁻¹(K,K) = A(K,K)⁻¹`` (one batched LAPACK inverse; it equals
       ``U_KK⁻¹·L_KK⁻¹`` of the no-pivot LU, which is never formed);
@@ -1338,14 +899,15 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
       ``(|C|·b, b) @ (b, |C|·b)`` GEMM.
 
     The dense (nb0, nb0) block workspace is the same asymptotic
-    footprint as the device layout :func:`prepare_values` already
-    emits. Numerics match the single-matrix scipy path to rounding
-    (≤1e-12 of each block's largest entry, asserted in tests).
+    footprint as the device layout emitted here, and is freed before
+    the layout copies. Numerics match the supernodal LU + triangular
+    solves of ``supernodal_lu.factorize`` to rounding (≤1e-12, asserted
+    in tests).
 
-    Raises ``ValueError`` naming the offending batch *index* when any
-    matrix's pattern escapes the analyzed structure — callers that need
-    per-request isolation (the serving layer) validate each matrix with
-    :func:`check_values_pattern` first.
+    Raises ``ValueError`` naming the offending batch *index* (for B > 1)
+    when any matrix's pattern escapes the analyzed structure — callers
+    that need per-request isolation (the serving layer) validate each
+    matrix with :func:`check_values_pattern` first.
 
     Traced as ``prep.check``, ``prep.densify`` (the CSR entries
     scattered into the zeroed block workspace; with ``nnz``, the entries
@@ -1361,6 +923,8 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
             try:
                 csr.append(check_values_pattern(M, bs, b))
             except ValueError as e:
+                if B == 1:
+                    raise
                 raise ValueError(f"matrix {i} of {B}: {e}") from e
 
     # dense (B, nb0, nb0, b, b) block workspace holding the evolving
@@ -1387,36 +951,16 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
             AKC = W[:, K, C].transpose(0, 2, 1, 3).reshape(B, b, c * b)
             W[np.ix_(bidx, C, C)] -= (LhCK @ AKC).reshape(
                 B, c, b, c, b).transpose(0, 1, 3, 2, 4)
+        del W                         # room for the layout's copies
     with TRACER.span("prep.layout", B=B):
         Dinv[:, range(nb0, nb), range(nb0, nb)] = np.eye(b)  # padding
         return (_shard_blocks(Lh, nb, b, pr, pc),
                 _shard_blocks(Dinv, nb, b, pr, pc))
 
 
-def prepare_inputs(A, b: int, pr: int, pc: int):
-    """Factorize (host), normalize, and lay out dense-blocked shards.
-
-    Returns (bs, nb, Lh_sharded_global, Dinv_sharded_global) where the
-    arrays have shape (pr*pc, nbr, nbc, b, b) for in_specs P("xy").
-
-    Back-compat composition of :func:`analyze_structure` (symbolic, the
-    part the engine caches) and :func:`prepare_values` (numeric) — new
-    code that solves many matrices of one structure should go through
-    :class:`~.engine.PSelInvEngine` instead."""
-    warnings.warn(
-        "prepare_inputs is deprecated: use PSelInvEngine.analyze(...) + "
-        "engine.prepare_values(...) (the analyze-once/solve-many split) "
-        "or analyze_structure/prepare_values directly",
-        DeprecationWarning, stacklevel=2)
-    bs, nb = analyze_structure(A, b, pr, pc)
-    Lh_s, Dinv_s = prepare_values(A, bs, nb, b, pr, pc)
-    return bs, nb, Lh_s, Dinv_s
-
-
 def check_grid_devices(pr: int, pc: int) -> None:
     """Raise the canonical diagnostic when the process grid oversubscribes
-    the available JAX devices (shared by the engine and the legacy
-    entry point)."""
+    the available JAX devices."""
     avail = len(jax.devices())
     if pr * pc > avail:
         raise ValueError(
@@ -1424,54 +968,6 @@ def check_grid_devices(pr: int, pc: int) -> None:
             f"{avail} JAX device(s) are available — shrink the grid or "
             "launch with more devices (e.g. XLA_FLAGS="
             f"--xla_force_host_platform_device_count={pr * pc})")
-
-
-def run_distributed(A, b: int, pr: int, pc: int,
-                    kind: TreeKind = TreeKind.SHIFTED, dtype=jnp.float32,
-                    pipelined: bool = True, overlap: bool = True):
-    """End-to-end distributed selected inversion on pr*pc devices.
-
-    .. deprecated:: PR 4
-       Thin back-compat shim over :class:`~.engine.PSelInvEngine` — one
-       call per matrix re-enters the engine's structure cache, so
-       repeated calls with one structure reuse the compiled sweep, but
-       the numeric host factorization still runs per call. New code
-       should ``PSelInvEngine.analyze(...)`` once and ``solve`` many
-       times (with a batch axis for multi-matrix workloads).
-
-    ``pipelined=True`` runs the IR executor — by default the cross-level
-    *overlapped* round stream; ``overlap=False`` selects the level-serial
-    executor (the A/B baseline). ``pipelined=False`` runs the legacy
-    unrolled sweep (same numerics, larger HLO)."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    warnings.warn(
-        "run_distributed is deprecated: use PSelInvEngine.analyze(...) "
-        "once and engine.solve(...) per matrix (batched solves via a "
-        "leading batch axis / solve_many)",
-        DeprecationWarning, stacklevel=2)
-    check_grid_devices(pr, pc)
-    if pipelined:
-        from .engine import PSelInvEngine
-        from .schedule import Grid2D
-        engine = PSelInvEngine.analyze(
-            A, b=b, grid=Grid2D(pr, pc),
-            options=PlanOptions(kind=kind, overlap=overlap))
-        out = engine.solve(A, dtype=dtype)
-        return np.asarray(out), engine.program
-
-    # composed directly (not through prepare_inputs) so one deprecated
-    # call warns once, attributed to the caller
-    bs, nb = analyze_structure(A, b, pr, pc)
-    Lh_s, Dinv_s = prepare_values(A, bs, nb, b, pr, pc)
-    prog = build_program_unrolled(bs, nb, b, pr, pc, kind=kind)
-    sweep = make_sweep_unrolled(prog)
-    devs = np.array(jax.devices()[:pr * pc]).reshape(pr * pc)
-    mesh = Mesh(devs, ("xy",))
-    fn = jax.jit(shard_map(
-        sweep, mesh=mesh, in_specs=(P("xy"), P("xy")), out_specs=P("xy")))
-    out = fn(jnp.asarray(Lh_s, dtype=dtype), jnp.asarray(Dinv_s, dtype=dtype))
-    return np.asarray(out), prog
 
 
 def gather_blocks(out: np.ndarray, prog) -> np.ndarray:

@@ -29,7 +29,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from .plan import (CommPlan, ExecPlan, OverlappedExec, PlanOp, build_plan,
+from .plan import (CommPlan, OverlappedExec, PlanOp, build_plan,
                    peak_arena_blocks)
 from .schedule import BYTES_PER_ELT, ComputeTask, Grid2D
 from .symbolic import BlockStructure
@@ -37,7 +37,7 @@ from .trees import HYBRID_FLAT_MAX, TreeKind, cached_tree
 
 __all__ = ["NetworkModel", "SimResult", "volumes", "volumes_from_plan",
            "volume_stats", "simulate", "RoundSchedule",
-           "round_schedule_from_exec", "round_schedule_from_overlap",
+           "round_schedule_from_overlap",
            "round_schedule_from_stream",
            "round_schedule_of", "simulate_schedule",
            "executed_wire_bytes"]
@@ -422,14 +422,14 @@ class RoundSchedule:
     transfer of one round ships in the same barriered permute; coalesced
     lanes of a pair appear as several tuples) and ``("comp", flops)``
     round boundaries (per-rank flops fired between two rounds). Built
-    from the same :class:`~.plan.ExecPlan` / :class:`~.plan.OverlappedExec`
-    the device program runs, so the time :func:`simulate_schedule` reports
-    is the time of the schedule that *executes* — the overlapped stream
-    is accounted round for round, not approximated per supernode.
+    from the same :class:`~.plan.OverlappedExec` the device program
+    runs, so the time :func:`simulate_schedule` reports is the time of
+    the schedule that *executes* — the overlapped stream is accounted
+    round for round, not approximated per supernode.
     ``peak_arena_blocks`` carries the compiled schedule's per-device
-    peak block footprint (``plan.peak_arena_blocks``) so the serial /
-    overlapped comparison covers the memory axis, not just time —
-    regression guard for the arena slot recycling."""
+    peak block footprint (``plan.peak_arena_blocks``) so a schedule
+    comparison covers the memory axis, not just time — regression guard
+    for the arena slot recycling."""
     nranks: int
     events: List[Tuple[str, object]]
     peak_arena_blocks: int = 0
@@ -442,29 +442,6 @@ def _level_task_flops(plan: CommPlan, Ks, kind: str) -> np.ndarray:
         if t.kind == kind and t.supernode in sel:
             flops[t.rank] += t.flops
     return flops
-
-
-def round_schedule_from_exec(ex: ExecPlan, plan: CommPlan) -> RoundSchedule:
-    """Flatten the level-serial executor: each level's phases in order,
-    with the level GEMM at the bcast→reduce boundary and the diagonal
-    update after the diag-reduce (the A/B baseline timeline)."""
-    events: List[Tuple[str, object]] = []
-
-    def comm(rounds, kind):
-        for rnd in rounds:
-            events.append(("comm", [(s, d, kind, nb_)
-                                    for (s, d, _ss, _ds, nb_) in rnd.edges]))
-
-    for lv in ex.levels:
-        comm(lv.xfer_in, "xfer")
-        comm(lv.bcast, "col-bcast")
-        events.append(("comp", _level_task_flops(plan, lv.Ks, "gemm")))
-        comm(lv.reduce, "row-reduce")
-        comm(lv.xfer_out, "xfer-out")
-        comm(lv.diag_reduce, "diag-reduce")
-        events.append(("comp", _level_task_flops(plan, lv.Ks, "diag")))
-    return RoundSchedule(nranks=ex.pr * ex.pc, events=events,
-                         peak_arena_blocks=peak_arena_blocks(ex))
 
 
 def _overlap_event_groups(ov: OverlappedExec, plan: CommPlan
@@ -539,8 +516,8 @@ def simulated_round_times(prog_or_engine,
     ov = getattr(prog, "overlap_plan", None)
     if ov is None:
         raise ValueError("per-round simulation needs an overlapped "
-                         "schedule — build with PlanOptions(overlap=True) "
-                         "or PlanOptions(stream=True)")
+                         "schedule — build the program through "
+                         "build_program()/PSelInvEngine.analyze()")
     model = model or NetworkModel()
     net = _Net(model, ov.pr * ov.pc)
     flop_rate = model.gemm_gflops * 1e9
@@ -595,11 +572,9 @@ def executed_wire_bytes(prog_or_engine) -> float:
     only then priced — so a gate table that drifted from the receive
     table fails loudly instead of producing an agreeing-but-wrong byte
     count. Must equal ``stream.stream_wire_bytes`` of the same tables
-    (tested, and asserted against the unrolled overlapped executor's
-    wire in the bench). For an unrolled overlapped program it prices
-    each round's single static permute (``len(perm) × width``
-    blocks), for the level-serial one each single-block round
-    (``len(perm)`` blocks)."""
+    (tested, and asserted against the overlapped executor's wire in the
+    bench). For an overlapped program it prices each round's single
+    static permute (``len(perm) × width`` blocks)."""
     prog = getattr(prog_or_engine, "program", prog_or_engine)
     b = prog.b
     st = getattr(prog, "stream_tables", None)
@@ -621,15 +596,8 @@ def executed_wire_bytes(prog_or_engine) -> float:
     if ov is not None:
         blocks = sum(len(rnd.perm) * rnd.width for rnd in ov.rounds)
         return float(blocks) * b * b * BYTES_PER_ELT
-    ex = getattr(prog, "exec_plan", None)
-    if ex is not None:
-        blocks = sum(len(rnd.perm) for lv in ex.levels
-                     for rounds in (lv.xfer_in, lv.bcast, lv.reduce,
-                                    lv.xfer_out, lv.diag_reduce)
-                     for rnd in rounds)
-        return float(blocks) * b * b * BYTES_PER_ELT
     raise ValueError("executed wire accounting needs a program with "
-                     "stream_tables, overlap_plan or exec_plan")
+                     "stream_tables or overlap_plan")
 
 
 def round_schedule_of(prog_or_engine) -> RoundSchedule:
@@ -644,12 +612,9 @@ def round_schedule_of(prog_or_engine) -> RoundSchedule:
         return round_schedule_from_stream(prog.stream_tables, prog.plan)
     if getattr(prog, "overlap_plan", None) is not None:
         return round_schedule_from_overlap(prog.overlap_plan, prog.plan)
-    if getattr(prog, "exec_plan", None) is not None:
-        return round_schedule_from_exec(prog.exec_plan, prog.plan)
     raise ValueError(
-        "program has no compiled IR lowering (exec_plan/overlap_plan) — "
-        "build it through build_program()/PSelInvEngine.analyze(), not "
-        "the legacy unrolled path")
+        "program has no compiled IR lowering (overlap_plan) — build it "
+        "through build_program()/PSelInvEngine.analyze()")
 
 
 def simulate_schedule(sched,
@@ -657,11 +622,9 @@ def simulate_schedule(sched,
     """α-β timing of a compiled round stream under the executed BSP
     semantics: a ppermute round completes when its slowest pair does
     (coalesced lanes of one pair share the latency and serialize their
-    bytes), a compute boundary when its busiest rank does. Comparing the
-    level-serial and the overlapped :class:`RoundSchedule` of one plan
-    quantifies the cross-level overlap win under the same network; the
-    result also carries the schedule's ``peak_arena_blocks`` so the
-    comparison covers per-device memory alongside time.
+    bytes), a compute boundary when its busiest rank does. The result
+    also carries the schedule's ``peak_arena_blocks``, so a comparison
+    of two schedules covers per-device memory alongside time.
 
     ``sched`` may be a ready :class:`RoundSchedule`, or a compiled
     program / engine — anything :func:`round_schedule_of` accepts — in

@@ -37,12 +37,12 @@ leading ``width`` lanes along its perm, and each receiver keeps only
 the arrival of its one receive slot (``recv_slot``) and scatters it
 once — the same gather-snapshot → permute → scatter semantics as the
 unrolled round, hence bit-identical (padded lanes scatter into the
-trash block exactly like the unrolled executor's coalescing padding; a
+trash block exactly like the overlapped executor's coalescing padding; a
 slot's spurious deliveries — union-perm sources that did not pack a
 lane this round — are discarded by the receive-slot select). A round
 therefore pays only the wire bytes of the slots it actually uses,
 ``Σ len(perm) × width`` blocks (:func:`stream_wire_blocks`, near the
-unrolled executor's instead of the flat-ring encoding's
+overlapped executor's instead of the flat-ring encoding's
 every-shift-every-round ~7–200×), while the program size stays
 **independent of the round count** (the tables are data, not code; the
 slot dictionary saturates with the grid, not with ``nb``).
@@ -67,7 +67,7 @@ tables padded to the widest level ``NK``. Padded supernode rows carry a
 zero struct mask (their GEMM/S rows compute exact zeros into the shared
 partial/S regions' tail, which only the masked readers ever touch) and
 their diagonal lanes target the trash block, so padding is numerically
-inert — the executed arithmetic on real rows is the unrolled executor's,
+inert — the executed arithmetic on real rows is the overlapped executor's,
 value for value.
 
 The lowering is pure host-side table construction (numpy); the executor

@@ -3,14 +3,14 @@
 The paper's contribution is a *schedule property*: tree-shaped
 asynchronous rounds stay correct only if no processor's in-flight
 payloads collide, and stay fast only if no processor's fan-in piles up
-(arXiv:1504.04714 §4). The stack lowers three executors from one
+(arXiv:1504.04714 §4). The stack lowers two executors from one
 CommPlan IR, and the worst bugs so far — (device, slot) dependence keys
 silently wiring a stale arena tenant — were exactly the class a static
 pass over the lowered tables catches at plan time instead of as f64
 mismatches. This module is that pass: a pipeline of checkers over any
-lowered artifact (:class:`~.plan.CommPlan`, level-serial
-:class:`~.plan.ExecPlan`, overlapped :class:`~.plan.OverlappedExec`
-round list, or :class:`~.stream.StreamTables`) emitting typed
+lowered artifact (:class:`~.plan.CommPlan`, the overlapped
+:class:`~.plan.OverlappedExec` round list, or
+:class:`~.stream.StreamTables`) emitting typed
 :class:`PlanDiagnostic` records instead of scattered asserts.
 
 Checker pipeline (each family owns a stable diagnostic ``code``):
@@ -25,7 +25,7 @@ Checker pipeline (each family owns a stable diagnostic ``code``):
   level's [producer boundary, consumer boundary) liveness window; and
   no two lanes of one round write the same (device, slot)
   (``race/waw-round``).
-* **permutation legality** — every ppermute (unrolled rounds, flat-ring
+* **permutation legality** — every ppermute (overlapped rounds, flat-ring
   and gated comm slots) has unique sources and destinations
   (``perm/dup-endpoint``), no self-edges (``perm/self-edge``), edge
   metadata consistent with the perm (``perm/edges-mismatch``), and
@@ -69,12 +69,12 @@ from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
-from .plan import CommPlan, ExecPlan, OverlappedExec
+from .plan import CommPlan, OverlappedExec
 from .stream import StreamTables
 
 __all__ = ["PlanDiagnostic", "PlanVerificationError", "VERIFY_MODES",
            "verify_artifact", "verify_program", "enforce_verification",
-           "check_plan", "check_exec", "check_overlap", "check_stream",
+           "check_plan", "check_overlap", "check_stream",
            "check_stream_gates", "lint_report"]
 
 #: the accepted ``PlanOptions(verify=...)`` / ``engine.analyze`` modes
@@ -270,7 +270,7 @@ def _round_lanes(ov: OverlappedExec):
 
 def _check_round_structure(ov: OverlappedExec) -> List[PlanDiagnostic]:
     """Permutation legality, in-round write uniqueness, and arena bounds
-    of the unrolled round list."""
+    of the overlapped round list."""
     diags: List[PlanDiagnostic] = []
     P = ov.pr * ov.pc
     trash = ov.trash
@@ -590,39 +590,6 @@ def check_overlap(ov: OverlappedExec, plan: CommPlan | None = None, *,
 
 
 # ---------------------------------------------------------------------------
-# level-serial executor tables
-# ---------------------------------------------------------------------------
-
-def check_exec(ex: ExecPlan) -> List[PlanDiagnostic]:
-    """Permutation legality of the level-serial executor's packed
-    rounds (its phase ordering is barriered, so the race surface is the
-    per-round ppermute constraint)."""
-    diags: List[PlanDiagnostic] = []
-    for L, lv in enumerate(ex.levels):
-        phases = (("xfer", lv.xfer_in), ("col-bcast", lv.bcast),
-                  ("row-reduce", lv.reduce), ("xfer-out", lv.xfer_out),
-                  ("diag-reduce", lv.diag_reduce))
-        for kind, rounds in phases:
-            for t, rnd in enumerate(rounds):
-                srcs = [s for s, _ in rnd.perm]
-                dsts = [d for _, d in rnd.perm]
-                if len(set(srcs)) != len(srcs) \
-                        or len(set(dsts)) != len(dsts):
-                    diags.append(_err(
-                        "perm/dup-endpoint",
-                        f"level {L} {kind} round {t}: perm "
-                        f"{sorted(rnd.perm)} reuses a source or "
-                        "destination", round=t))
-                for (s, d) in rnd.perm:
-                    if s == d:
-                        diags.append(_err(
-                            "perm/self-edge",
-                            f"level {L} {kind} round {t}: self-edge "
-                            f"{s}->{d}", round=t, device=s))
-    return diags
-
-
-# ---------------------------------------------------------------------------
 # stream tables: slot dictionary, gates, routing, bounds
 # ---------------------------------------------------------------------------
 
@@ -814,8 +781,8 @@ def check_stream(st: StreamTables, plan: CommPlan | None = None
 def verify_artifact(obj, plan: CommPlan | None = None, *,
                     fanin_max: int = FANIN_MAX) -> List[PlanDiagnostic]:
     """Run the checker pipeline appropriate to one lowered artifact:
-    a :class:`~.plan.CommPlan`, :class:`~.plan.ExecPlan`,
-    :class:`~.plan.OverlappedExec`, or :class:`~.stream.StreamTables`.
+    a :class:`~.plan.CommPlan`, :class:`~.plan.OverlappedExec`, or
+    :class:`~.stream.StreamTables`.
     Passing the originating ``plan`` alongside an executor artifact adds
     the byte-conservation cross-check."""
     if isinstance(obj, CommPlan):
@@ -824,26 +791,21 @@ def verify_artifact(obj, plan: CommPlan | None = None, *,
         return check_overlap(obj, plan, fanin_max=fanin_max)
     if isinstance(obj, StreamTables):
         return check_stream(obj, plan)
-    if isinstance(obj, ExecPlan):
-        return check_exec(obj)
     raise TypeError(
         f"verify_artifact cannot lint {type(obj).__name__} — expected "
-        "CommPlan, ExecPlan, OverlappedExec, or StreamTables")
+        "CommPlan, OverlappedExec, or StreamTables")
 
 
 def verify_program(prog, *, fanin_max: int = FANIN_MAX
                    ) -> List[PlanDiagnostic]:
     """Lint everything a compiled ``pselinv_dist.PSelInvProgram``
-    carries: the CommPlan IR plus whichever executor lowerings were
-    compiled (level-serial tables, overlapped rounds, stream tables) —
-    each cross-checked against the plan where applicable."""
+    carries: the CommPlan IR, the overlapped rounds and, when compiled,
+    the stream tables — each cross-checked against the plan where
+    applicable."""
     diags: List[PlanDiagnostic] = []
     plan = getattr(prog, "plan", None)
     if plan is not None:
         diags += check_plan(plan)
-    ex = getattr(prog, "exec_plan", None)
-    if ex is not None:
-        diags += check_exec(ex)
     ov = getattr(prog, "overlap_plan", None)
     if ov is not None:
         diags += check_overlap(ov, plan, fanin_max=fanin_max)

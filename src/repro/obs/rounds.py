@@ -231,8 +231,8 @@ def profile_rounds(engine, values, *, chunk: int = 1, reps: int = 3,
     ov = prog.overlap_plan
     if ov is None:
         raise ValueError(
-            "profile_rounds needs an overlapped schedule — analyze with "
-            "PlanOptions(overlap=True) (default) or stream=True")
+            "profile_rounds needs an overlapped schedule — analyze the "
+            "engine through PSelInvEngine.analyze()")
     if not (isinstance(values, (tuple, list)) and len(values) == 2):
         values = engine.prepare_values(values)   # a matrix, not shards
     Lh, Dinv = values

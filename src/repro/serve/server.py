@@ -69,8 +69,7 @@ class ServeConfig:
     dynamic batch window; ``max_queue`` the admission bound (requests
     beyond it are REJECTED, the paper's bound-the-absorbed-work lesson
     applied to the request queue); ``bucket`` pads batches to power-of-2
-    buckets; ``batched_prep`` routes host factorization through the
-    stacked pass; ``prog_cache`` (a
+    buckets; ``prog_cache`` (a
     :class:`~.progcache.ProgramDiskCache`) serves batches through
     persisted AOT executables instead of the engine's jitted sweep —
     off by default so ``engine.trace_count`` stays the compile-count
@@ -82,7 +81,6 @@ class ServeConfig:
     max_queue: int = 256
     dtype: object = jnp.float32
     bucket: bool = True
-    batched_prep: bool = True
     default_timeout_ms: Optional[float] = None
     prog_cache: Optional[object] = None
 
@@ -249,22 +247,16 @@ class SelInvServer:
     def _prepare(self, eng: PSelInvEngine,
                  reqs: List[SolveRequest]) -> SolveValues:
         """Host numeric factorization for the batch: matrix-bearing
-        requests go through the stacked pass, pre-factorized value
-        requests slot in at their position."""
+        requests go through the stacked pass whatever their number,
+        pre-factorized value requests slot in at their position."""
         mat_idx = [i for i, r in enumerate(reqs) if r.values is None]
         if len(mat_idx) == len(reqs):        # all-matrix batch (the
-            mats = [r.matrix for r in reqs]  # common path): the stacked
-            if self.cfg.batched_prep and len(mats) > 1:  # prep already
-                return eng.prepare_values_many(mats)     # IS the batch
-            return stack_values([eng.prepare_values(M) for M in mats])
+            # common path): the stacked prep already IS the batch
+            return eng.prepare_values_many([r.matrix for r in reqs])
         per: List[Optional[SolveValues]] = [
             r.values if r.values is not None else None for r in reqs]
         if mat_idx:
-            mats = [reqs[i].matrix for i in mat_idx]
-            if self.cfg.batched_prep and len(mats) > 1:
-                mv = eng.prepare_values_many(mats)
-            else:
-                mv = stack_values([eng.prepare_values(M) for M in mats])
+            mv = eng.prepare_values_many([reqs[i].matrix for i in mat_idx])
             for j, i in enumerate(mat_idx):
                 per[i] = SolveValues(mv.Lh[j], mv.Dinv[j])
         return stack_values(per)

@@ -8,21 +8,22 @@ from conftest import run_sub
 def test_run_distributed_validates_grid_and_inputs():
     """An oversized process grid raises a ValueError naming the requested
     grid vs the available devices (it used to die in a cryptic numpy
-    reshape inside the device slicing), and ``prepare_inputs`` rejects a
-    matrix whose size is not a multiple of the block size with a real
+    reshape inside the device slicing), and ``analyze_structure`` rejects
+    a matrix whose size is not a multiple of the block size with a real
     ValueError (not an ``assert`` that vanishes under ``python -O``).
     The main pytest process is single-device, which is exactly the
     misconfiguration the grid check must catch."""
     from repro.core import sparse
-    from repro.core.pselinv_dist import prepare_inputs, run_distributed
+    from repro.core.engine import Grid, PSelInvEngine
+    from repro.core.pselinv_dist import analyze_structure
 
     A = sparse.laplacian_2d(12, 8)
     # a grid no host plausibly satisfies, so the check fires regardless
     # of how many devices this machine (or its XLA_FLAGS) exposes
     with pytest.raises(ValueError, match=r"grid 64x64 needs 4096 devices"):
-        run_distributed(A, b=8, pr=64, pc=64)
+        PSelInvEngine.analyze(A, b=8, grid=Grid(64, 64))
     with pytest.raises(ValueError, match=r"not a multiple of the supernode"):
-        prepare_inputs(A, b=7, pr=1, pc=1)
+        analyze_structure(A, b=7, pr=1, pc=1)
 
 
 def test_tree_collectives_match_builtins():
@@ -86,15 +87,17 @@ def test_distributed_pselinv_matches_oracle():
         import jax.numpy as jnp
         from repro.core import sparse
         from repro.core.trees import TreeKind
-        from repro.core.pselinv_dist import run_distributed, gather_blocks
+        from repro.core.engine import Grid, PlanOptions, PSelInvEngine
+        from repro.core.pselinv_dist import gather_blocks
         from repro.core.selinv import dense_selinv_oracle
         A = sparse.laplacian_2d(12, 8)
         ref = dense_selinv_oracle(A)
         for kind in (TreeKind.FLAT, TreeKind.SHIFTED):
-            out, prog = run_distributed(A, b=8, pr=4, pc=2, kind=kind,
-                                        dtype=jnp.float64)
-            blocks = gather_blocks(out, prog)
-            bs = prog.bs
+            eng = PSelInvEngine.analyze(A, b=8, grid=Grid(4, 2),
+                                        options=PlanOptions(kind=kind))
+            out = np.asarray(eng.solve(A, dtype=jnp.float64))
+            blocks = gather_blocks(out, eng)
+            bs = eng.bs
             err = 0.0
             for K in range(bs.nsuper):
                 err = max(err, abs(blocks[K, K]

@@ -10,9 +10,8 @@ contract.
 (c) batching — ``solve`` over a leading batch axis of B same-structure
     matrices is bit-identical (f64, ≤1e-12; observed exact) to a Python
     loop of single solves;
-(d) shim equivalence — ``run_distributed`` (now a thin shim over the
-    engine) returns exactly what the explicit PlanOptions engine path
-    returns, for both overlapped and level-serial options.
+(d) host prep — the batched BLAS-3 factorization, at B=1 and above,
+    matches the supernodal LU + triangular solves it replaced.
 """
 import numpy as np
 import pytest
@@ -130,35 +129,6 @@ def test_engine_batched_solve_matches_single_loop():
                     err = max(err, abs(blocks[I, K]
                                        - ref[I*8:(I+1)*8, K*8:(K+1)*8]).max())
             assert err < 1e-9, (i, err)
-        print("OK")
-    """, x64=True)
-
-
-def test_planoptions_roundtrip_through_run_distributed_shim():
-    """run_distributed(kind=..., overlap=...) is a pure shim: its output
-    equals the explicit PSelInvEngine path with the equivalent
-    PlanOptions, bit-for-bit, for both executors — and its program is
-    the engine's cached program object."""
-    run_sub("""
-        import numpy as np
-        import jax.numpy as jnp
-        from repro.core import sparse
-        from repro.core.engine import Grid, PlanOptions, PSelInvEngine
-        from repro.core.pselinv_dist import run_distributed
-        from repro.core.trees import TreeKind
-
-        A = sparse.laplacian_2d(12, 8)
-        for overlap in (True, False):
-            opts = PlanOptions(kind=TreeKind.SHIFTED, overlap=overlap)
-            eng = PSelInvEngine.analyze(A, b=8, grid=Grid(4, 2),
-                                        options=opts)
-            out_e = np.asarray(eng.solve(A, dtype=jnp.float64))
-            out_s, prog = run_distributed(A, b=8, pr=4, pc=2,
-                                          kind=TreeKind.SHIFTED,
-                                          dtype=jnp.float64,
-                                          overlap=overlap)
-            assert prog is eng.program, "shim bypassed the engine cache"
-            assert abs(out_s - out_e).max() == 0.0
         print("OK")
     """, x64=True)
 
@@ -349,37 +319,119 @@ def test_engine_bucketed_solve_shares_pow2_programs():
     """, x64=True)
 
 
-@pytest.mark.parametrize("nx,ny,b,shifts", [
-    (12, 8, 8, (0.0, 0.25, 1.0, 2.0)),
+def _reference_blocks(A, bs, nb, b):
+    """The host prep's math as the supernodal LU gives it: L̂ from
+    ``supernodal_lu.factorize`` + ``selinv.normalize_factors``, D⁻¹(K,K)
+    = U_KK⁻¹·L_KK⁻¹ by triangular solves, identity D⁻¹ on the padding
+    supernodes — dense (nb, nb, b, b) grids."""
+    import scipy.linalg as sla
+    import scipy.sparse as sp
+    from repro.core.selinv import normalize_factors
+    from repro.core.supernodal_lu import factorize
+    lu = factorize(sp.csr_matrix(A), bs=bs)
+    Lhat, _ = normalize_factors(lu)
+    Lh = np.zeros((nb, nb, b, b))
+    Dinv = np.zeros((nb, nb, b, b))
+    for (I, K), blk in Lhat.items():
+        Lh[I, K] = np.asarray(blk)
+    for K in range(bs.nsuper):
+        linv = sla.solve_triangular(np.asarray(lu.Ldiag[K]), np.eye(b),
+                                    lower=True, unit_diagonal=True)
+        Dinv[K, K] = sla.solve_triangular(np.asarray(lu.Udiag[K]), linv,
+                                          lower=False)
+    for K in range(bs.nsuper, nb):
+        Dinv[K, K] = np.eye(b)
+    return Lh, Dinv
+
+
+def _prepared_blocks(mats, b, grid, tmp_path):
+    """``engine.prepare_values`` of each matrix at ``grid``, gathered
+    to dense (nb, nb, b, b) grids: in this process at 1×1, in a child
+    on a host mesh of ``pr*pc`` devices otherwise."""
+    from repro.core.pselinv_dist import gather_blocks
+    pr, pc = grid
+    if grid == (1, 1):
+        eng = PSelInvEngine.analyze(mats[0], b=b, grid=Grid(1, 1),
+                                    options=PlanOptions())
+        return [(gather_blocks(v.Lh, eng), gather_blocks(v.Dinv, eng))
+                for v in map(eng.prepare_values, mats)]
+    import scipy.sparse as sp
+    for i, M in enumerate(mats):
+        sp.save_npz(tmp_path / f"A{i}.npz", sp.csr_matrix(M))
+    run_sub(f"""
+        import numpy as np, scipy.sparse as sp
+        from repro.core.engine import Grid, PlanOptions, PSelInvEngine
+        from repro.core.pselinv_dist import gather_blocks
+        mats = [sp.load_npz("{tmp_path}/A%d.npz" % i)
+                for i in range({len(mats)})]
+        eng = PSelInvEngine.analyze(mats[0], b={b}, grid=Grid({pr}, {pc}),
+                                    options=PlanOptions())
+        for i, M in enumerate(mats):
+            v = eng.prepare_values(M)
+            np.save("{tmp_path}/Lh%d.npy" % i, gather_blocks(v.Lh, eng))
+            np.save("{tmp_path}/Dinv%d.npy" % i,
+                    gather_blocks(v.Dinv, eng))
+    """, ndev=pr * pc, x64=True)
+    return [(np.load(tmp_path / f"Lh{i}.npy"),
+             np.load(tmp_path / f"Dinv{i}.npy")) for i in range(len(mats))]
+
+
+def _assert_blocks_match(got, ref):
+    """≤1e-12 per block, absolute and relative to the block's largest
+    entry (so a structurally zero block must come out exactly zero)."""
+    assert got.shape == ref.shape
+    err = abs(got - ref).max(axis=(-2, -1))
+    assert err.max() <= 1e-12
+    assert (err <= 1e-12 * abs(ref).max(axis=(-2, -1))).all()
+
+
+@pytest.mark.parametrize("nx,ny,b,shifts,grid", [
+    pytest.param(12, 8, 8, (0.0, 0.25, 1.0, 2.0), (1, 1),
+                 id="12-8-8-shifts0"),
     # b=16: supernodes whose struct(K) cliques hold 3 and 4 members, so
     # the Schur update's (|C|·b, b) @ (b, |C|·b) GEMM spans several blocks
-    (16, 16, 16, (0.5,)),
-    (16, 16, 16, (0.0, 1.5)),
-    (16, 16, 16, (0.0, 0.25, 1.0, 2.0)),
+    pytest.param(16, 16, 16, (0.5,), (1, 1), id="16-16-16-shifts1"),
+    pytest.param(16, 16, 16, (0.0, 1.5), (1, 1), id="16-16-16-shifts2"),
+    pytest.param(16, 16, 16, (0.0, 0.25, 1.0, 2.0), (1, 1),
+                 id="16-16-16-shifts3"),
+    # 9 supernodes padded to 10 (2×2) and 12 (4×2): block-cyclic shards
+    # with identity D⁻¹ on the padding supernodes
+    pytest.param(9, 8, 8, (0.0, 1.0), (2, 2), id="9-8-8-grid2x2"),
+    pytest.param(9, 8, 8, (0.0, 1.0), (4, 2), id="9-8-8-grid4x2"),
 ])
-def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts):
-    """The stacked host factorization is numerically the per-matrix
-    path: prepare_values_many over shifted copies matches a loop of
-    prepare_values to ≤1e-12 (f64), absolute and relative to each
-    block's largest entry, for batches of 1, 2 and 4 and cliques of up
-    to 4 supernodes; and a bad-pattern member fails with its batch
-    index named while the pure per-matrix error is unchanged."""
+def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts,
+                                                     grid, tmp_path):
+    """The stacked host factorization is numerically the supernodal LU
+    + triangular solves it replaced: prepare_values_many over shifted
+    copies, and engine.prepare_values (B=1) of each, match the
+    reference to ≤1e-12 (f64), absolute and relative to each block's
+    largest entry, for batches of 1, 2 and 4, cliques of up to 4
+    supernodes and grids that pad the supernode count; a bad-pattern
+    member fails with its batch index named, while one matrix alone
+    fails with the pattern check's own message."""
     import scipy.sparse as sp
-    from repro.core.engine import stack_values
+    from repro.core.pselinv_dist import analyze_structure, gather_blocks
     A = sparse.laplacian_2d(nx, ny)
     I_A = sp.identity(A.shape[0])
     mats = [A + c * I_A for c in shifts]
+    bs, nb = analyze_structure(A, b, *grid)
+    refs = [_reference_blocks(M, bs, nb, b) for M in mats]
+    if grid != (1, 1):
+        assert nb > bs.nsuper
+    for got, ref in zip(_prepared_blocks(mats, b, grid, tmp_path), refs,
+                        strict=True):
+        for g, r in zip(got, ref):
+            _assert_blocks_match(g, r)
+    if grid != (1, 1):
+        return
     eng = PSelInvEngine.analyze(A, b=b, grid=Grid(1, 1),
                                 options=PlanOptions())
     if b == 16:
         assert max(len(s) for s in eng.bs.struct) >= 3
     many = eng.prepare_values_many(mats)
-    loop = stack_values([eng.prepare_values(M) for M in mats])
-    assert many.Lh.shape == loop.Lh.shape
-    for got, ref in ((many.Lh, loop.Lh), (many.Dinv, loop.Dinv)):
-        err = abs(got - ref).max(axis=(-2, -1))
-        assert err.max() <= 1e-12
-        assert (err <= 1e-12 * abs(ref).max(axis=(-2, -1))).all()
+    for i, (Lh, Dinv) in enumerate(refs):
+        _assert_blocks_match(gather_blocks(many.Lh[i], eng), Lh)
+        _assert_blocks_match(gather_blocks(many.Dinv[i], eng), Dinv)
     # a member whose pattern escapes the structure names its index
     n = A.shape[0]
     B = sp.lil_matrix(A)
@@ -388,6 +440,8 @@ def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts):
     with pytest.raises(ValueError,
                        match=r"matrix 2 of 3:.*outside the analyzed"):
         eng.prepare_values_many(bad)
+    with pytest.raises(ValueError, match=r"^matrix has \d+ nonzero"):
+        eng.prepare_values(B)
 
 
 def _split_entries(A, parts=3):

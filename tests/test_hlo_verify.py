@@ -1,8 +1,8 @@
 """HloLint tests (``core/hlo_verify.py`` + ``core/hlo_ir.py``): the
 compiled-artifact verifier is itself verified.
 
-(a) clean corpus — every shipped executor lowering (level-serial /
-    overlapped / gated stream under both ``axis_factored`` settings,
+(a) clean corpus — every shipped executor lowering (overlapped /
+    gated stream under both ``axis_factored`` settings,
     single and vmapped-batched) traces, lowers and lints with **zero
     ERROR diagnostics** at the jaxpr and StableHLO layers — on an
     abstract mesh, so the 8×4 bigmesh case runs without devices;
@@ -70,7 +70,7 @@ def stream_art(stream_prog):
 
 @pytest.fixture(scope="module")
 def ov_prog():
-    return _program(16, 4, 2, overlap=True)
+    return _program(16, 4, 2)
 
 
 @pytest.fixture(scope="module")
@@ -123,7 +123,7 @@ def test_bigmesh_8x4_compiled_lints_without_devices():
     needs no physical devices."""
     import jax
     assert jax.device_count() < 32
-    for opts in (dict(overlap=True), dict(stream=True)):
+    for opts in (dict(), dict(stream=True)):
         prog = _program(32, 8, 4, **opts)
         assert _errors(HV.lint_program(prog)) == [], f"opts={opts}"
 

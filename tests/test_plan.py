@@ -1,15 +1,15 @@
 """CommPlan IR tests: the single-derivation guarantees.
 
 (a) bytes equivalence — per-rank byte counts summed over the *compiled*
-    executor rounds (level-serial AND cross-level overlapped) equal
+    overlapped rounds (with and without a Û liveness window) equal
     ``simulator.volumes`` on the same structure/grid/tree-kind
     (simulated bytes == executed bytes);
-(b) oracle — the IR sweeps (overlapped and level-serial) match the dense
-    inverse on the selected pattern for several (pr, pc, TreeKind)
-    combinations, and agree with the legacy unrolled sweep;
+(b) oracle — the overlapped sweep matches the serial selected inverse
+    and the dense inverse on the selected pattern for several
+    (pr, pc, TreeKind) combinations;
 (c) overlap — the global round stream respects every producer→consumer
     round dependence, coalesces multi-block (src,dst) payloads, and
-    issues fewer ppermute rounds than the level-serial path;
+    issues fewer ppermute rounds than single-block transfers;
 (d) fast-path drift — ``volumes_fast`` is bit-identical to the slow
     ``volumes`` for all four TreeKinds, including HYBRID at the
     flat/shifted boundary participant counts (24 and 25);
@@ -23,7 +23,7 @@ import scipy.sparse as sp
 from conftest import run_sub
 
 from repro.core import sparse
-from repro.core.plan import (build_plan, compile_exec, etree_levels,
+from repro.core.plan import (build_plan, etree_levels,
                              exec_byte_counts, merge_round_lists,
                              peak_arena_blocks, ppermute_round_count,
                              schedule_overlapped, tree_for)
@@ -44,11 +44,13 @@ def lap_bs():
                          [TreeKind.FLAT, TreeKind.BINARY, TreeKind.SHIFTED])
 def test_exec_bytes_match_volumes(lap_bs, pr, pc, kind):
     """The bytes the compiled device program moves are the bytes the
-    simulator accounts — same plan, independent accounting paths."""
+    simulator accounts — same plan, independent accounting paths — also
+    when a one-level Û window recycles the arena and reshapes the
+    rounds."""
     _, bs = lap_bs
     grid = Grid2D(pr, pc)
     plan = build_plan(bs, grid, kind, nb=12)
-    out_e, inc_e = exec_byte_counts(compile_exec(plan))
+    out_e, inc_e = exec_byte_counts(schedule_overlapped(plan, window=1))
     out_v, inc_v = volumes(bs, grid, kind)
     z = np.zeros(grid.size)
     for k in ("xfer", "col-bcast"):
@@ -68,7 +70,7 @@ def test_exec_bytes_match_volumes(lap_bs, pr, pc, kind):
 def test_overlapped_bytes_match_volumes(lap_bs, pr, pc, kind):
     """Coalescing + cross-level interleaving move the same bytes in fewer
     rounds: the overlapped stream's per-rank byte counts equal the
-    simulator's volumes (and hence the level-serial executor's)."""
+    simulator's volumes."""
     _, bs = lap_bs
     grid = Grid2D(pr, pc)
     plan = build_plan(bs, grid, kind, nb=12)
@@ -148,15 +150,14 @@ def test_overlapped_respects_round_dependences(lap_bs, kind):
 @pytest.mark.parametrize("pr,pc", [(4, 2), (2, 2)])
 def test_overlapped_fewer_rounds_and_coalescing(lap_bs, pr, pc):
     """The overlapped+coalesced stream issues strictly fewer ppermute
-    rounds than the level-serial path, some round carries a multi-block
+    rounds than it moves blocks, some round carries a multi-block
     (src,dst) payload, and every round still satisfies the ppermute
     constraint (unique sources / destinations across pairs, lane count
     within the coalescing cap)."""
     _, bs = lap_bs
     plan = build_plan(bs, Grid2D(pr, pc), TreeKind.SHIFTED, nb=12)
-    ex = compile_exec(plan)
     ov = schedule_overlapped(plan, coalesce_max=8)
-    assert ppermute_round_count(ov) < ppermute_round_count(ex)
+    assert ppermute_round_count(ov) < sum(len(r.edges) for r in ov.rounds)
     assert any(r.width > 1 for r in ov.rounds)
     for rnd in ov.rounds:
         if not rnd.perm:        # local-copy-only rounds are legal
@@ -353,22 +354,18 @@ def test_no_live_generations_alias_a_slot(window):
 @pytest.mark.parametrize("nx,max_rounds", [(16, 28), (32, 35)])
 def test_recycled_arena_peak_and_rounds(nx, max_rounds):
     """The acceptance envelope of the arena recycling + copy-free L̂
-    gathers: at grid 4×2 the overlapped executor's peak footprint
-    (arena + the resident input L̂ shard) lands strictly *below* the
-    level-serial executor's transient peak (~0.9×; before the copy-free
-    gathers it was ~1.2×, before slot recycling ~3× at nb=32) while the
-    ppermute round counts hold the coalesced-overlap wins (28 @ nb=16,
-    35 @ nb=32 — the shift-aware packer's offset grouping pays one
-    round here at 4×2 and wins two back at 8×4, for a stream wire cut
-    from ~36× to ~1.6× unrolled), and the schedule simulator carries
-    the peak so the bench trajectory can regression-guard it."""
+    gathers at grid 4×2: the ppermute round counts hold the
+    coalesced-overlap wins (28 @ nb=16, 35 @ nb=32 — the shift-aware
+    packer's offset grouping pays one round here at 4×2 and wins two
+    back at 8×4, for a stream wire cut from ~36× to ~1.6× unrolled), a
+    one-level window never grows the peak footprint (arena + the
+    resident input L̂ shard), and the schedule simulator carries the
+    peak so the bench trajectory can regression-guard it."""
     bs = symbolic_factorize(
         sp.csr_matrix(sparse.laplacian_2d(nx, 8)), max_supernode=8)
     plan = build_plan(bs, Grid2D(4, 2), TreeKind.SHIFTED, nb=nx)
-    ex = compile_exec(plan)
     ov = schedule_overlapped(plan)
     assert ppermute_round_count(ov) <= max_rounds
-    assert peak_arena_blocks(ov) < peak_arena_blocks(ex)
     sim = simulate_schedule(round_schedule_from_overlap(ov, plan))
     assert sim.peak_arena_blocks == peak_arena_blocks(ov)
     # a tighter window trades rounds for an even smaller arena but must
@@ -485,7 +482,7 @@ def test_hybrid_kind_bit_identical_below_boundary():
                                                   kind=kind)),
                 make_sweep_stream)
             outs[kind, "ov"] = run(
-                build_program(bs, nb, b, pr, pc, kind, overlap=True),
+                build_program(bs, nb, b, pr, pc, kind),
                 make_sweep_overlapped)
         for ex in ("st", "ov"):
             d = abs(outs[TreeKind.HYBRID, ex]
@@ -513,25 +510,21 @@ def test_plan_padding_supernodes(lap_bs):
     assert plan.nb == 18
     assert set(range(bs.nsuper, 18)) <= set(plan.diag_only)
     assert all(op.supernode < bs.nsuper for op in plan.ops)
-    ex = compile_exec(plan)
-    assert len(ex.diag_set_root) == len(plan.diag_only)
+    ov = schedule_overlapped(plan)
+    assert len(ov.diag_set_root) == len(plan.diag_only)
 
 
 def test_packed_rounds_respect_ppermute_constraint(lap_bs):
     """Every compiled round has unique sources and destinations."""
     _, bs = lap_bs
     plan = build_plan(bs, Grid2D(4, 2), TreeKind.SHIFTED, nb=12)
-    ex = compile_exec(plan)
     nrounds = 0
-    for lv in ex.levels:
-        for rounds in (lv.xfer_in, lv.bcast, lv.reduce, lv.xfer_out,
-                       lv.diag_reduce):
-            for rnd in rounds:
-                srcs = [s for s, _ in rnd.perm]
-                dsts = [d for _, d in rnd.perm]
-                assert len(set(srcs)) == len(srcs)
-                assert len(set(dsts)) == len(dsts)
-                nrounds += 1
+    for rnd in schedule_overlapped(plan).rounds:
+        srcs = [s for s, _ in rnd.perm]
+        dsts = [d for _, d in rnd.perm]
+        assert len(set(srcs)) == len(srcs)
+        assert len(set(dsts)) == len(dsts)
+        nrounds += bool(rnd.perm)
     assert nrounds > 0
 
 
@@ -561,31 +554,31 @@ def test_batched_rounds_uses_shared_merge():
 
 def test_ir_sweep_matches_oracle_multi_grid():
     """The overlapped IR sweep (the default executor) reproduces the
-    dense inverse on the selected pattern for several grid shapes / tree
-    kinds, and agrees with both the level-serial IR executor and the
-    legacy unrolled executor."""
+    serial selected inverse (``selinv.selected_inverse``) to 1e-12 and
+    the dense inverse on the selected pattern for several grid shapes /
+    tree kinds."""
     run_sub("""
         import numpy as np
         import jax.numpy as jnp
         from repro.core import sparse
+        from repro.core.engine import Grid, PlanOptions, PSelInvEngine
         from repro.core.trees import TreeKind
-        from repro.core.pselinv_dist import run_distributed, gather_blocks
-        from repro.core.selinv import dense_selinv_oracle
+        from repro.core.pselinv_dist import gather_blocks
+        from repro.core.selinv import dense_selinv_oracle, selected_inverse
         A = sparse.laplacian_2d(12, 8)
         ref = dense_selinv_oracle(A)
+        serial, _ = selected_inverse(A, max_supernode=8)
         for (pr, pc, kind) in ((2, 4, TreeKind.SHIFTED),
                                (2, 2, TreeKind.FLAT),
                                (4, 2, TreeKind.BINARY)):
-            out, prog = run_distributed(A, b=8, pr=pr, pc=pc, kind=kind,
-                                        dtype=jnp.float64)   # overlapped
-            out_s, _ = run_distributed(A, b=8, pr=pr, pc=pc, kind=kind,
-                                       dtype=jnp.float64, overlap=False)
-            out_u, _ = run_distributed(A, b=8, pr=pr, pc=pc, kind=kind,
-                                       dtype=jnp.float64, pipelined=False)
-            assert abs(out - out_s).max() < 1e-12, (pr, pc, kind)
-            assert abs(out - out_u).max() < 1e-12, (pr, pc, kind)
-            blocks = gather_blocks(out, prog)
-            bs = prog.bs
+            eng = PSelInvEngine.analyze(A, b=8, grid=Grid(pr, pc),
+                                        options=PlanOptions(kind=kind))
+            out = np.asarray(eng.solve(A, dtype=jnp.float64))
+            blocks = gather_blocks(out, eng)
+            d = max(abs(blocks[I, J] - np.asarray(blk)).max()
+                    for (I, J), blk in serial.items())
+            assert d < 1e-12, (pr, pc, kind, d)
+            bs = eng.bs
             err = 0.0
             for K in range(bs.nsuper):
                 err = max(err, abs(blocks[K, K]
@@ -603,10 +596,10 @@ def test_overlapped_recycled_matches_serial_nb32():
     """End-to-end oracle under *forced* Û slot reuse: nb=32 on grid 4×2
     with window=1 (every level recycles the previous level's compact Û
     slots, plus the always-shared partial/S regions) must match the
-    level-serial executor bit-tight (≤1e-12 in f64) and the dense
-    inverse on the selected pattern — the executed proof that the
-    generation anti-dependences make aliasing safe, not just the host
-    replay."""
+    serial selected inverse (``selinv.selected_inverse``) bit-tight
+    (≤1e-12 in f64) and the dense inverse on the selected pattern — the
+    executed proof that the generation anti-dependences make aliasing
+    safe, not just the host replay."""
     run_sub("""
         import numpy as np
         import jax, jax.numpy as jnp
@@ -614,14 +607,15 @@ def test_overlapped_recycled_matches_serial_nb32():
         from repro.compat import shard_map
         from repro.core import sparse
         from repro.core.trees import TreeKind
-        from repro.core.pselinv_dist import (build_program, make_sweep,
+        from repro.core.pselinv_dist import (analyze_structure,
+                                             build_program,
                                              make_sweep_overlapped,
-                                             prepare_inputs, gather_blocks,
-                                             run_distributed)
-        from repro.core.selinv import dense_selinv_oracle
+                                             prepare_values, gather_blocks)
+        from repro.core.selinv import dense_selinv_oracle, selected_inverse
         A = sparse.laplacian_2d(32, 8)
         b, pr, pc = 8, 4, 2
-        bs, nb, Lh_s, Dinv_s = prepare_inputs(A, b, pr, pc)
+        bs, nb = analyze_structure(A, b, pr, pc)
+        Lh_s, Dinv_s = prepare_values(A, bs, nb, b, pr, pc)
         devs = np.array(jax.devices()[:pr * pc]).reshape(pr * pc)
         mesh = Mesh(devs, ("xy",))
         Lh = jnp.asarray(Lh_s, jnp.float64)
@@ -633,16 +627,17 @@ def test_overlapped_recycled_matches_serial_nb32():
                                    out_specs=P("xy")))
             return np.asarray(fn(Lh, Dinv))
 
-        prog_s = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED)
-        out_s = run(prog_s, make_sweep)
         prog_w = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED,
-                               overlap=True, window=1)
+                               window=1)
         assert prog_w.overlap_plan.arena_blocks < 400  # recycled arena
         out_w = run(prog_w, make_sweep_overlapped)
-        assert abs(out_w - out_s).max() < 1e-12, abs(out_w - out_s).max()
+        blocks = gather_blocks(out_w, prog_w)
+        serial, _ = selected_inverse(A, max_supernode=b)
+        d = max(abs(blocks[I, J] - np.asarray(blk)).max()
+                for (I, J), blk in serial.items())
+        assert d < 1e-12, d
 
         ref = dense_selinv_oracle(A)
-        blocks = gather_blocks(out_w, prog_w)
         err = 0.0
         for K in range(bs.nsuper):
             err = max(err, abs(blocks[K, K]

@@ -3,14 +3,10 @@ import numpy as np
 import pytest
 
 from repro.core import sparse
-from repro.core.plan import build_plan, compile_exec, schedule_overlapped
 from repro.core.schedule import Grid2D, pselinv_events
-from repro.core.simulator import (NetworkModel, _msgs_vector,
-                                  round_schedule_from_exec,
-                                  round_schedule_from_overlap, simulate,
-                                  simulate_schedule, volume_stats, volumes,
-                                  volumes_fast)
-from repro.core.symbolic import symbolic_factorize, symbolic_factorize_elements
+from repro.core.simulator import (NetworkModel, _msgs_vector, simulate,
+                                  volume_stats, volumes, volumes_fast)
+from repro.core.symbolic import symbolic_factorize_elements
 from repro.core.trees import HYBRID_FLAT_MAX, TreeKind
 
 
@@ -115,28 +111,3 @@ def test_msgs_vector_resolves_hybrid():
     assert not np.array_equal(
         _msgs_vector(TreeKind.HYBRID, 0, big, 5, n),
         _msgs_vector(TreeKind.SHIFTED, 0, big, 0, n))
-
-
-def test_simulate_schedule_overlap_not_slower():
-    """The executed-timeline accounting: the overlapped round stream is
-    never slower than the level-serial stream of the same plan, and both
-    move the volumes' bytes."""
-    import scipy.sparse as sp
-    A = sparse.laplacian_2d(12, 8)
-    bs = symbolic_factorize(sp.csr_matrix(A), max_supernode=8)
-    grid = Grid2D(4, 2)
-    for kind in (TreeKind.FLAT, TreeKind.SHIFTED):
-        plan = build_plan(bs, grid, kind, nb=12)
-        rs = simulate_schedule(
-            round_schedule_from_exec(compile_exec(plan), plan))
-        ro = simulate_schedule(
-            round_schedule_from_overlap(schedule_overlapped(plan), plan))
-        assert ro.total_time <= rs.total_time
-        out_v, _ = volumes(bs, grid, kind)
-        z = np.zeros(grid.size)
-        for k in ("xfer", "col-bcast"):
-            np.testing.assert_allclose(ro.send_bytes.get(k, z),
-                                       out_v.get(k, z))
-        np.testing.assert_allclose(ro.recv_bytes["row-reduce"],
-                                   out_v["row-reduce"])
-        np.testing.assert_allclose(rs.compute_time, ro.compute_time)

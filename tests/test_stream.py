@@ -11,12 +11,11 @@
     still equal executed bytes) and ``round_schedule_of`` routes stream
     programs through it;
 (c) execution — ``make_sweep_stream`` (one ``lax.fori_loop`` body) is
-    f64 bit-identical to the unrolled overlapped executor and the
-    level-serial oracle at nb=16 (tier-1) and nb=32 (``slow`` marker,
-    excluded from tier-1 by default);
+    f64 bit-identical to the unrolled overlapped executor and matches the
+    serial selected inverse to 1e-12 at nb=16 (tier-1) and nb=32
+    (``slow`` marker, excluded from tier-1 by default);
 (d) wiring — ``PlanOptions(stream=True)`` flows through engine
-    analyze/solve/stats (compile metrics included), and the deprecated
-    ``run_distributed``/``prepare_inputs`` shims warn.
+    analyze/solve/stats (compile metrics included).
 """
 import numpy as np
 import pytest
@@ -158,18 +157,6 @@ def test_round_schedule_of_routes_stream_programs():
     assert len(rs.events) == len(rs_o.events)
     assert simulate_schedule(rs).total_time == \
         simulate_schedule(rs_o).total_time
-
-
-def test_stream_requires_overlap():
-    """stream=True without the overlapped lowering is a contradiction —
-    rejected at the options layer and at build_program."""
-    from repro.core.pselinv_dist import build_program
-    with pytest.raises(ValueError, match="overlap=True"):
-        PlanOptions(stream=True, overlap=False)
-    bs = symbolic_factorize(
-        sp.csr_matrix(sparse.laplacian_2d(4, 8)), max_supernode=8)
-    with pytest.raises(ValueError, match="overlap=True"):
-        build_program(bs, 4, 8, 1, 1, overlap=False, stream=True)
 
 
 def test_schedule_stream_single_device():
@@ -334,9 +321,9 @@ def test_stream_tables_grid8x4():
 
 def test_stream_executor_bit_identical_nb16():
     """End-to-end f64: the fori_loop stream executor matches the
-    unrolled overlapped executor and the level-serial executor exactly
-    (≤1e-12 asserted, 0.0 observed) and the dense oracle on the selected
-    pattern, at nb=16 on grid 4×2."""
+    unrolled overlapped executor exactly (≤1e-12 asserted, 0.0
+    observed), the serial selected inverse to ≤1e-12 and the dense
+    oracle on the selected pattern, at nb=16 on grid 4×2."""
     run_sub("""
         import numpy as np
         import jax, jax.numpy as jnp
@@ -347,11 +334,10 @@ def test_stream_executor_bit_identical_nb16():
         from repro.core.trees import TreeKind
         from repro.core.pselinv_dist import (analyze_structure,
                                              build_program, gather_blocks,
-                                             make_sweep,
                                              make_sweep_overlapped,
                                              make_sweep_stream,
                                              prepare_values)
-        from repro.core.selinv import dense_selinv_oracle
+        from repro.core.selinv import dense_selinv_oracle, selected_inverse
         A = sparse.laplacian_2d(16, 8)
         b, pr, pc = 8, 4, 2
         bs, nb = analyze_structure(A, b, pr, pc)
@@ -370,16 +356,16 @@ def test_stream_executor_bit_identical_nb16():
         prog_t = build_program(bs, nb, b, pr, pc,
                                options=PlanOptions(stream=True))
         out_t = run(prog_t, make_sweep_stream)
-        prog_o = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED,
-                               overlap=True)
+        prog_o = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED)
         out_o = run(prog_o, make_sweep_overlapped)
-        prog_s = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED)
-        out_s = run(prog_s, make_sweep)
         assert abs(out_t - out_o).max() <= 1e-12, abs(out_t - out_o).max()
-        assert abs(out_t - out_s).max() <= 1e-12, abs(out_t - out_s).max()
+        blocks = gather_blocks(out_t, prog_t)
+        serial, _ = selected_inverse(A, max_supernode=b)
+        d = max(abs(blocks[I, J] - np.asarray(blk)).max()
+                for (I, J), blk in serial.items())
+        assert d <= 1e-12, d
 
         ref = dense_selinv_oracle(A)
-        blocks = gather_blocks(out_t, prog_t)
         err = 0.0
         for K in range(bs.nsuper):
             err = max(err, abs(blocks[K, K]
@@ -397,8 +383,8 @@ def test_stream_executor_bit_identical_nb16():
 def test_stream_executor_bit_identical_nb32():
     """The nb=32 acceptance case (slow — excluded from tier-1 by the
     default ``-m "not slow"``; run with ``-m slow``): stream vs unrolled
-    overlapped vs serial oracle, f64, including a recycled arena
-    (window=1) stream."""
+    overlapped vs the serial selected inverse, f64, including a recycled
+    arena (window=1) stream."""
     run_sub("""
         import numpy as np
         import jax, jax.numpy as jnp
@@ -409,11 +395,10 @@ def test_stream_executor_bit_identical_nb32():
         from repro.core.trees import TreeKind
         from repro.core.pselinv_dist import (analyze_structure,
                                              build_program, gather_blocks,
-                                             make_sweep,
                                              make_sweep_overlapped,
                                              make_sweep_stream,
                                              prepare_values)
-        from repro.core.selinv import dense_selinv_oracle
+        from repro.core.selinv import dense_selinv_oracle, selected_inverse
         A = sparse.laplacian_2d(32, 8)
         b, pr, pc = 8, 4, 2
         bs, nb = analyze_structure(A, b, pr, pc)
@@ -436,16 +421,17 @@ def test_stream_executor_bit_identical_nb32():
                                   options=PlanOptions(stream=True,
                                                       window=1)),
                     make_sweep_stream)
-        out_o = run(build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED,
-                                  overlap=True), make_sweep_overlapped)
-        prog_s = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED)
-        out_s = run(prog_s, make_sweep)
+        prog_o = build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED)
+        out_o = run(prog_o, make_sweep_overlapped)
         assert abs(out_t - out_o).max() <= 1e-12, abs(out_t - out_o).max()
-        assert abs(out_t - out_s).max() <= 1e-12, abs(out_t - out_s).max()
-        assert abs(out_w - out_s).max() <= 1e-12, abs(out_w - out_s).max()
+        assert abs(out_w - out_o).max() <= 1e-12, abs(out_w - out_o).max()
+        blocks = gather_blocks(out_t, prog_o)
+        serial, _ = selected_inverse(A, max_supernode=b)
+        d = max(abs(blocks[I, J] - np.asarray(blk)).max()
+                for (I, J), blk in serial.items())
+        assert d <= 1e-12, d
 
         ref = dense_selinv_oracle(A)
-        blocks = gather_blocks(out_t, prog_s)
         err = 0.0
         for K in range(bs.nsuper):
             err = max(err, abs(blocks[K, K]
@@ -465,8 +451,9 @@ def test_stream_executor_bit_identical_grid8x4():
     """The tentpole's target scale: a 32-host-device 8×4 grid
     (``bigmesh`` marker — run with ``-m bigmesh``), where the flat ring
     would execute 31 permutes every round. The gated stream executor is
-    f64 bit-identical to the unrolled overlapped executor and the
-    level-serial oracle, and its executed wire matches the simulator."""
+    f64 bit-identical to the unrolled overlapped executor, matches the
+    serial selected inverse to 1e-12, and its executed wire matches the
+    simulator."""
     run_sub("""
         import numpy as np
         import jax, jax.numpy as jnp
@@ -479,10 +466,10 @@ def test_stream_executor_bit_identical_grid8x4():
         from repro.core.trees import TreeKind
         from repro.core.pselinv_dist import (analyze_structure,
                                              build_program, gather_blocks,
-                                             make_sweep,
                                              make_sweep_overlapped,
                                              make_sweep_stream,
                                              prepare_values)
+        from repro.core.selinv import selected_inverse
         A = sparse.laplacian_2d(32, 8)
         b, pr, pc = 8, 8, 4
         bs, nb = analyze_structure(A, b, pr, pc)
@@ -503,12 +490,14 @@ def test_stream_executor_bit_identical_grid8x4():
         assert executed_wire_bytes(prog_t) == \\
             stream_wire_bytes(prog_t.stream_tables, b)
         out_t = run(prog_t, make_sweep_stream)
-        out_o = run(build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED,
-                                  overlap=True), make_sweep_overlapped)
-        out_s = run(build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED),
-                    make_sweep)
+        out_o = run(build_program(bs, nb, b, pr, pc, TreeKind.SHIFTED),
+                    make_sweep_overlapped)
         assert abs(out_t - out_o).max() <= 1e-12, abs(out_t - out_o).max()
-        assert abs(out_t - out_s).max() <= 1e-12, abs(out_t - out_s).max()
+        blocks = gather_blocks(out_t, prog_t)
+        serial, _ = selected_inverse(A, max_supernode=b)
+        d = max(abs(blocks[I, J] - np.asarray(blk)).max()
+                for (I, J), blk in serial.items())
+        assert d <= 1e-12, d
         print("OK")
     """, ndev=32, x64=True, timeout=600)
 
@@ -594,15 +583,3 @@ def test_stream_engine_session_end_to_end():
         assert sim.total_time == base.simulate().total_time
         print("OK")
     """, x64=True, timeout=600)
-
-
-def test_shims_emit_deprecation_warning():
-    """The documented-deprecated ``run_distributed``/``prepare_inputs``
-    shims actually warn, pointing at PSelInvEngine."""
-    from repro.core.pselinv_dist import prepare_inputs, run_distributed
-    A = sparse.laplacian_2d(4, 8)
-    with pytest.warns(DeprecationWarning, match="PSelInvEngine"):
-        prepare_inputs(A, b=8, pr=1, pc=1)
-    with pytest.warns(DeprecationWarning, match="PSelInvEngine"):
-        out, prog = run_distributed(A, b=8, pr=1, pc=1)
-    assert np.isfinite(np.asarray(out)).all()

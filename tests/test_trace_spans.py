@@ -192,6 +192,54 @@ def test_serve_batch_children_cover_it_and_carry_rids(traced):
     assert not [s for s in spans if s.name == "jax.compile"]
 
 
+_PREP = ("engine.prepare_values", "engine.prepare_values_many")
+
+
+@pytest.mark.parametrize("via", ["direct", "served_batch_of_one"])
+def test_one_matrix_prep_is_one_span_the_bench_reads_once(traced, via):
+    """``prep_s_per_matrix.served`` sums ``engine.prepare_values`` and
+    ``engine.prepare_values_many``. One matrix, prepared directly or
+    served alone, yields exactly one of them — the direct call the
+    first, the served batch of one the second — which covers
+    ``prep.check``, ``prep.densify``, ``prep.factor`` and
+    ``prep.layout``, each with ``B=1``; neither name nests in the other,
+    so the reader counts the matrix once, for that span's time."""
+    from repro.serve import SelInvServer, ServeConfig
+
+    A = sparse.laplacian_2d(16, 16)
+    if via == "direct":
+        eng = PSelInvEngine.analyze(A, b=8, grid=Grid(1, 1))
+        traced.clear()
+        eng.prepare_values(A)
+        want = "engine.prepare_values"
+    else:
+        srv = SelInvServer(ServeConfig(b=8, grid=Grid(1, 1)))
+        srv.submit(A)                      # compile the batch of 1 first
+        srv.drain()
+        traced.clear()
+        r = srv.submit(A + 0.5 * sp.identity(A.shape[0]))
+        srv.drain()
+        assert r.done() and r.result(0).shape[0] == 1
+        want = "engine.prepare_values_many"
+    spans = traced.spans()
+    prep = [s for s in spans if s.name in _PREP]
+    assert [s.name for s in prep] == [want]
+    (top,) = prep
+    by_id = {s.span_id: s for s in spans}
+    up = top.parent_id
+    while up is not None:
+        assert by_id[up].name not in _PREP
+        up = by_id[up].parent_id
+    for step in ("prep.check", "prep.densify", "prep.factor",
+                 "prep.layout"):
+        (s,) = [x for x in spans if x.name == step]
+        assert s.parent_id == top.span_id and s.attrs["B"] == 1
+        assert top.t0_us <= s.t0_us and s.t1_us <= top.t1_us
+    run = {"spans": [(s.name, s.dur_us / 1e6, s.attrs) for s in spans]}
+    assert bench_run.read_metric(REPO, "prep_s_per_matrix.served", run) \
+        == pytest.approx(top.dur_us / 1e6)
+
+
 def test_prep_densify_counts_the_members_it_summed(traced):
     """A batch member stored with duplicate entries takes the summing
     path, and ``prep.densify`` says so: ``summed`` counts it and not the
@@ -299,8 +347,7 @@ def test_sweep_permute_scope_on_every_ppermute_2x2():
         from repro.core import sparse
         from repro.core.engine import Grid, PlanOptions, PSelInvEngine
         A = sparse.laplacian_2d(16, 8)
-        for opts in (PlanOptions(), PlanOptions(stream=True),
-                     PlanOptions(overlap=False)):
+        for opts in (PlanOptions(), PlanOptions(stream=True)):
             eng = PSelInvEngine.analyze(A, b=8, grid=Grid(2, 2),
                                         options=opts)
             sd = jax.ShapeDtypeStruct(
@@ -373,11 +420,11 @@ _COMM_2X2 = """
 """
 
 
-@pytest.mark.parametrize("opts", ["", "stream=True", "overlap=False"],
-                         ids=["overlapped", "stream", "level-serial"])
+@pytest.mark.parametrize("opts", ["", "stream=True"],
+                         ids=["overlapped", "stream"])
 def test_comm_counters_on_solve_span_2x2(opts):
     """At grid 2x2: ``wire_bytes`` is the simulator's executed wire,
-    ``rounds`` the compiled permutes of an unrolled executor (the
+    ``rounds`` the compiled permutes of the overlapped executor (the
     stream's loop body holds one per comm slot), the most a device
     receives is at least the mean, the gauges carry them, and a solve
     counts nothing again: off it records nothing, on it stamps the

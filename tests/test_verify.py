@@ -1,7 +1,7 @@
 """PlanLint tests (``core/verify.py``): the verifier is itself verified.
 
 (a) clean corpus — every shipped plan shape (nb=16/32, grids 4×2 and
-    8×4, level-serial / overlapped / stream lowerings, both
+    8×4, overlapped / stream lowerings, both
     ``axis_factored`` settings, windowed and unwindowed Û pools) lints
     with **zero ERROR diagnostics**, entirely host-side;
 (b) mutation self-test — each corruption class the checker pipeline
@@ -27,8 +27,7 @@ import scipy.sparse as sp
 
 from repro.core import sparse
 from repro.core import verify as V
-from repro.core.plan import (PlanOptions, build_plan, compile_exec,
-                             schedule_overlapped)
+from repro.core.plan import PlanOptions, build_plan, schedule_overlapped
 from repro.core.schedule import Grid2D
 from repro.core.stream import lower_stream
 from repro.core.symbolic import symbolic_factorize
@@ -82,7 +81,6 @@ def test_shipped_plans_lint_clean(nx, nb, pr, pc):
     plan = build_plan(_structure(nx), Grid2D(pr, pc), TreeKind.SHIFTED,
                       nb=nb)
     assert _errors(V.check_plan(plan)) == []
-    assert _errors(V.check_exec(compile_exec(plan))) == []
     for window in (None, 1):
         ov = schedule_overlapped(plan, window=window)
         assert _errors(V.check_overlap(ov, plan)) == [], \
@@ -304,9 +302,24 @@ def test_verify_artifact_dispatch(ov_plan, stream_tables):
     assert _errors(V.verify_artifact(plan)) == []
     assert _errors(V.verify_artifact(ov, plan)) == []
     assert _errors(V.verify_artifact(stream_tables, plan)) == []
-    assert _errors(V.verify_artifact(compile_exec(plan))) == []
     with pytest.raises(TypeError, match="verify_artifact"):
         V.verify_artifact(object())
+
+
+def test_build_program_default_is_the_overlapped_executor():
+    """``build_program`` with no options builds what every cell runs:
+    the overlapped round stream, no other executor lowering, and a
+    program PlanLint passes clean."""
+    import dataclasses
+    from repro.core.pselinv_dist import build_program, pad_nb
+    bs = _structure(16)
+    prog = build_program(bs, pad_nb(bs.nsuper, 4, 2), 8, 4, 2)
+    assert prog.overlap_plan is not None and prog.stream_tables is None
+    # the program carries the plan and its two executor lowerings only
+    assert {f.name for f in dataclasses.fields(prog)} == {
+        "nb", "b", "pr", "pc", "kind", "bs", "plan", "overlap_plan",
+        "stream_tables"}
+    assert _errors(V.verify_program(prog)) == []
 
 
 def test_lint_report_format():

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """HloLint CLI — run the compiled-artifact verifier
 (``core/hlo_verify.py``) over a generated structure corpus: every
-shipped executor lowering (level-serial, overlapped, gated stream under
-both ``axis_factored`` settings) is traced and lowered on an abstract
+shipped executor lowering (overlapped, gated stream under both
+``axis_factored`` settings) is traced and lowered on an abstract
 mesh and its jaxpr / StableHLO layers are cross-checked against the
 plan tables — permute conformance, loop trip counts, wire-byte
 conservation, hot-path hygiene. No physical devices are needed (the
@@ -53,8 +53,7 @@ DEFAULT_CORPUS = [
 
 #: the executor lowerings every case lints at the compiled layer
 EXECUTORS = [
-    ("exec", PlanOptions(overlap=False)),
-    ("overlap", PlanOptions(overlap=True)),
+    ("overlap", PlanOptions()),
     ("stream", PlanOptions(stream=True)),
     ("stream(axis_factored=False)",
      PlanOptions(stream=True, axis_factored=False)),
