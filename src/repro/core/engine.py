@@ -19,7 +19,10 @@ shard_map sweep **once**, and caches the whole session keyed on
 a second ``analyze`` with an identical structure returns the *same*
 engine, compiled program included. ``solve`` moves values only: the
 host numeric factorization (when given a matrix) plus one call of the
-cached jitted sweep — no symbolic work, no re-lowering, no retrace.
+cached jitted sweep — no symbolic work, no re-lowering, no retrace. A
+matrix the engine factorizes itself crosses to the device as its
+factor's structural blocks alone (:class:`PackedValues`), which a
+small compiled program unpacks there into the sweep's dense inputs.
 
 **Multi-matrix batching** comes from the same structure/value split:
 the compiled tables are value-independent, so ``solve`` accepts a
@@ -48,16 +51,17 @@ from ..obs import compiles
 from ..obs.registry import REGISTRY
 from ..obs.trace import TRACER
 from .plan import (PlanOptions, peak_arena_blocks, ppermute_round_count)
-from .pselinv_dist import (PSelInvProgram, analyze_structure, build_program,
-                           check_grid_devices,
+from .pselinv_dist import (PackTables, PSelInvProgram, analyze_structure,
+                           build_program, check_grid_devices,
                            make_sweep_overlapped, make_sweep_stream,
-                           pad_nb, prepare_values, prepare_values_many,
+                           pack_tables, pad_nb, prepare_packed_many,
+                           prepare_values, prepare_values_many,
                            validate_uniform_widths)
 from .schedule import BYTES_PER_ELT, Grid2D
 from .symbolic import BlockStructure
 
 __all__ = ["Grid", "PlanOptions", "PSelInvEngine", "SolveValues",
-           "structure_key", "stack_values", "bucket_size", "to_device"]
+           "PackedValues", "structure_key", "stack_values", "bucket_size"]
 
 # jax.compile spans for every trace / lower / compile while TRACER is on
 compiles.install()
@@ -71,6 +75,18 @@ class SolveValues(NamedTuple):
     """One matrix's numeric payload in device layout: ``Lh`` and ``Dinv``
     shaped (P, nbr, nbc, b, b) — or (B, P, nbr, nbc, b, b) with a
     leading batch axis for multi-matrix solves."""
+    Lh: np.ndarray
+    Dinv: np.ndarray
+
+
+class PackedValues(NamedTuple):
+    """One matrix's numeric payload as only its structural blocks — the
+    f64 lists of :class:`~.pselinv_dist.PackTables`: ``Lh`` shaped
+    (P, nL, b, b) and ``Dinv`` (P, nD, b, b), or with a leading batch
+    axis. The engine's own factorization emits them
+    (:meth:`PSelInvEngine.prepare_packed`); :meth:`PSelInvEngine
+    .to_device` ships them and rebuilds the dense shards on the
+    device."""
     Lh: np.ndarray
     Dinv: np.ndarray
 
@@ -108,25 +124,6 @@ def bucket_size(B: int) -> int:
     if B < 1:
         raise ValueError(f"batch size must be >= 1, got {B}")
     return 1 << (B - 1).bit_length()
-
-
-def to_device(Lh, Dinv, dtype=None, bucket: Optional[int] = None):
-    """Cast ``Lh``/``Dinv`` to ``dtype`` (None keeps theirs) and move them
-    to the device; with ``bucket``, zero-pad the leading batch axis up to
-    it. Traced as ``engine.h2d`` (``B``, ``bytes``): while ``TRACER`` is
-    enabled the span ends when the arrays are on the device, so it times
-    the transfer and not its dispatch; disabled, nothing waits."""
-    B = Lh.shape[0] if Lh.ndim == 6 else 1
-    with TRACER.span("engine.h2d", B=B) as sp:
-        Lh = jnp.asarray(Lh, dtype=dtype)
-        Dinv = jnp.asarray(Dinv, dtype=dtype)
-        if bucket is not None and bucket != B:
-            pad = ((0, bucket - B),) + ((0, 0),) * (Lh.ndim - 1)
-            Lh, Dinv = jnp.pad(Lh, pad), jnp.pad(Dinv, pad)
-        if TRACER.enabled:
-            jax.block_until_ready((Lh, Dinv))
-            sp.set(bytes=int(Lh.nbytes + Dinv.nbytes))
-    return Lh, Dinv
 
 
 def _approx_nbytes(obj, _seen=None, _depth=0) -> int:
@@ -196,6 +193,12 @@ class PSelInvEngine:
     _round_schedule: Optional[object] = None
     _table_bytes: Optional[int] = field(default=None, repr=False)
     _comm: Optional[Dict[str, float]] = field(default=None, repr=False)
+    _pack: Optional[PackTables] = field(default=None, repr=False)
+    _unpackers: Dict[Tuple, object] = field(default_factory=dict,
+                                            repr=False)
+    #: batches :meth:`to_device` shipped as structural blocks / dense
+    h2d_packed_batches: int = 0
+    h2d_dense_batches: int = 0
 
     # ---- the structure cache (class-level, all sessions) --------------
     _cache: ClassVar["OrderedDict[Tuple, PSelInvEngine]"] = OrderedDict()
@@ -274,6 +277,7 @@ class PSelInvEngine:
                          program=program, mesh=Mesh(devs, ("xy",)),
                          key=key)
             engine.comm_counters()
+            engine.pack_tables()
         with cls._cache_lock:
             # somebody may have raced us past the miss above; keep the
             # first published session so `analyze` stays idempotent
@@ -379,17 +383,144 @@ class PSelInvEngine:
                 Lh, Dinv = Lh.astype(dtype), Dinv.astype(dtype)
         return SolveValues(Lh, Dinv)
 
+    def prepare_packed(self, mats: Sequence) -> PackedValues:
+        """The batched host factorization of :meth:`prepare_values_many`
+        (same span, same f64 blocks) emitting only the structural blocks:
+        (B, P, n, b, b) :class:`PackedValues` for :meth:`to_device`."""
+        with TRACER.span("engine.prepare_values_many", B=len(mats)):
+            return PackedValues(*prepare_packed_many(
+                mats, self.bs, self.nb, self.b, self.pack_tables()))
+
+    def pack_tables(self) -> PackTables:
+        """Where each structural block of L̂ and D⁻¹ sits in this
+        session's device layout (:class:`~.pselinv_dist.PackTables`),
+        made once per session (:meth:`analyze` calls it)."""
+        if self._pack is None:
+            self._pack = pack_tables(self.bs, self.nb, self.grid.pr,
+                                     self.grid.pc)
+        return self._pack
+
+    def sharding(self, batched: bool):
+        """The placement of the sweep's value inputs on this session's
+        mesh: shards over "xy", after the batch axis when ``batched``."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh,
+                             P(None, "xy") if batched else P("xy"))
+
+    def to_device(self, values, dtype=None, bucket: Optional[int] = None):
+        """Move one solve's values to the device as the sweep's dense
+        ``(Lh, Dinv)`` inputs, placed by :meth:`sharding` (arrays already
+        placed so stay as they are); ``bucket`` zero-pads the batch axis
+        up to it, ``dtype`` casts (None keeps theirs).
+
+        :class:`PackedValues` are cast on the host, padded to the bucket
+        there, sent in one ``device_put`` and unpacked into zeroed dense
+        shards by :meth:`unpacker`'s program, each device filling its
+        own. Any other pair of arrays is cast and placed whole, and
+        padded on the device. Traced as ``engine.h2d`` (``B``,
+        ``packed`` 1/0, ``blocks`` per lane, ``bytes`` sent): while
+        ``TRACER`` is enabled the span ends when the arrays are on the
+        device, so it times the transfer and not its dispatch; disabled,
+        nothing waits. Counted in ``h2d_packed_batches`` /
+        ``h2d_dense_batches``."""
+        Lh, Dinv = values
+        if not isinstance(values, PackedValues):
+            self.h2d_dense_batches += 1
+            return self._dense_to_device(Lh, Dinv, dtype, bucket)
+        self.h2d_packed_batches += 1
+        batched = Lh.ndim == 5
+        B = Lh.shape[0] if batched else 1
+        lanes = bucket or B
+        dt = jax.dtypes.canonicalize_dtype(dtype or Lh.dtype)
+        with TRACER.span("engine.h2d", B=B, packed=1,
+                         blocks=self.pack_tables().blocks) as sp:
+            host = []
+            for x in (Lh, Dinv):
+                if lanes == B:
+                    host.append(np.asarray(x, dtype=dt))
+                else:
+                    pad = np.zeros((lanes,) + x.shape[1:], dtype=dt)
+                    pad[:B] = x
+                    host.append(pad)
+            sent = jax.device_put(tuple(host), self.sharding(batched))
+            out = self.unpacker(lanes if batched else None, dt)(*sent)
+            if TRACER.enabled:
+                jax.block_until_ready(out)
+                sp.set(bytes=int(sum(x.nbytes for x in host)))
+        return out
+
+    def _dense_to_device(self, Lh, Dinv, dtype, bucket):
+        B = Lh.shape[0] if Lh.ndim == 6 else 1
+        sharding = self.sharding(Lh.ndim == 6)
+        with TRACER.span("engine.h2d", B=B, packed=0,
+                         blocks=int(np.prod(Lh.shape[-5:-2])) * 2) as sp:
+            # device arrays cross nothing; the pad lanes are made there
+            host = [not isinstance(x, jax.Array) for x in (Lh, Dinv)]
+            Lh = jnp.asarray(Lh, dtype=dtype, device=sharding)
+            Dinv = jnp.asarray(Dinv, dtype=dtype, device=sharding)
+            sent = sum(x.nbytes for x, h in zip((Lh, Dinv), host) if h)
+            if bucket is not None and bucket != B:
+                pad = ((0, bucket - B),) + ((0, 0),) * (Lh.ndim - 1)
+                Lh, Dinv = jnp.pad(Lh, pad), jnp.pad(Dinv, pad)
+            if TRACER.enabled:
+                jax.block_until_ready((Lh, Dinv))
+                sp.set(bytes=int(sent))
+        return Lh, Dinv
+
+    def unpacker(self, lanes: Optional[int], dtype):
+        """The compiled program that turns shipped :class:`PackedValues`
+        into the sweep's dense inputs: one ``shard_map`` over the mesh in
+        which each device gathers its own shard's listed blocks into the
+        dense ``(…, nbr, nbc, b, b)`` grid, zero where nothing is listed
+        (an out-of-range take, filled). ``lanes`` is the batch bucket,
+        None for one matrix. AOT-compiled once per (lanes, dtype) and
+        kept on the session; uncounted (``trace_count`` is the sweep's)."""
+        dtype = jax.dtypes.canonicalize_dtype(dtype)
+        key = (lanes, dtype.name)
+        with self._jit_lock:
+            comp = self._unpackers.get(key)
+        if comp is not None:
+            return comp
+        t = self.pack_tables()
+        b, nbr, nbc = self.b, t.nbr, t.nbc
+
+        def unpack(Lh, Dinv):
+            idx = jax.lax.axis_index("xy")
+
+            def one(x, take):
+                lead = x.shape[:-3]           # (1,) or (lanes, 1)
+                x = x.reshape((-1,) + x.shape[-3:])
+                g = jnp.take(jnp.asarray(take), idx, axis=0)
+                out = jnp.take(x, g, axis=1, mode="fill", fill_value=0)
+                return out.reshape(lead + (nbr, nbc, b, b))
+            return one(Lh, t.lh_take), one(Dinv, t.d_take)
+
+        batched = lanes is not None
+        sh = self.sharding(batched)
+        spec = sh.spec
+        lead = (lanes,) if batched else ()
+        fn = jax.jit(shard_map(unpack, mesh=self.mesh, in_specs=(spec, spec),
+                               out_specs=(spec, spec)),
+                     out_shardings=(sh, sh))   # as the sweep was warmed
+        comp = fn.trace(*(jax.ShapeDtypeStruct(lead + pos.shape + (b, b),
+                                               dtype, sharding=sh)
+                          for pos in (t.lh_pos, t.d_pos))).lower().compile()
+        with self._jit_lock:
+            return self._unpackers.setdefault(key, comp)
+
     def solve(self, values, dtype=jnp.float32, *, bucket: bool = False):
         """Selected inversion of one matrix — or a whole batch.
 
         ``values`` is a matrix (numeric-factorized here against the
-        cached structure), a :class:`SolveValues`, or a plain
-        ``(Lh, Dinv)`` pair. Arrays of rank 5 ((P, nbr, nbc, b, b))
-        solve one matrix; rank 6 ((B, P, nbr, nbc, b, b), the leading
-        **batch axis**) solve B same-structure matrices through one
-        vmapped sweep call. Returns the A⁻¹ shards in the same layout
-        (rank 5 or 6). ``dtype`` casts the values (f32 default); pass
-        ``None`` to keep the arrays' own dtype.
+        cached structure and shipped as :class:`PackedValues`), a
+        :class:`PackedValues`, a :class:`SolveValues`, or a plain
+        ``(Lh, Dinv)`` pair. Arrays of rank 5 ((P, nbr, nbc, b, b);
+        packed, rank 4) solve one matrix; rank 6 ((B, P, nbr, nbc, b,
+        b), the leading **batch axis**; packed, rank 5) solve B
+        same-structure matrices through one vmapped sweep call. Returns
+        the A⁻¹ shards in the dense layout (rank 5 or 6). ``dtype``
+        casts the values (f32 default); pass ``None`` to keep the
+        arrays' own dtype.
 
         ``bucket=True`` pads a batched solve up to the next power-of-2
         bucket (:func:`bucket_size`) with zero-valued lanes and slices
@@ -398,19 +529,23 @@ class PSelInvEngine:
         batches of 3, 5, 13 all ride the B∈{4, 8, 16} programs (the
         serving layer's retrace bound)."""
         if _is_matrix(values):
-            values = self.prepare_values(values)
-        Lh, Dinv = values
-        if Lh.ndim not in (5, 6):
+            with TRACER.span("engine.prepare_values"):
+                Lh, Dinv = prepare_packed_many([values], self.bs, self.nb,
+                                               self.b, self.pack_tables())
+            values = PackedValues(Lh[0], Dinv[0])
+        # packed blocks carry one axis fewer than the dense shards
+        rank = np.ndim(values[0]) + isinstance(values, PackedValues)
+        if rank not in (5, 6):
             raise ValueError(
                 f"values must be rank 5 (single) or rank 6 (leading "
-                f"batch axis), got shape {Lh.shape}")
+                f"batch axis), got shape {np.shape(values[0])}")
         self.solve_calls += 1
-        batched = Lh.ndim == 6
-        B = Lh.shape[0] if batched else 1
+        batched = rank == 6
+        B = np.shape(values[0])[0] if batched else 1
         with TRACER.span("engine.solve", B=B) as sp:
-            Lh, Dinv = to_device(Lh, Dinv, dtype,
-                                 bucket_size(B) if batched and bucket
-                                 else None)
+            Lh, Dinv = self.to_device(values, dtype,
+                                      bucket_size(B) if batched and bucket
+                                      else None)
             out = self.jitted(batched=batched)(Lh, Dinv)
             if TRACER.enabled:
                 # per element shipped: the solve's dtype, every lane
@@ -425,10 +560,10 @@ class PSelInvEngine:
     def solve_many(self, mats: Sequence, dtype=jnp.float32, *,
                    bucket: bool = False):
         """Convenience: numeric-factorize the same-structure matrices in
-        one stacked :meth:`prepare_values_many` pass and run ONE batched
+        one stacked :meth:`prepare_packed` pass and run ONE batched
         solve; ``bucket`` pads the batch to its power-of-2 bucket so odd
         batch lengths share compiled programs."""
-        return self.solve(self.prepare_values_many(mats), dtype=dtype,
+        return self.solve(self.prepare_packed(mats), dtype=dtype,
                           bucket=bucket)
 
     def comm_counters(self) -> Dict[str, float]:
@@ -662,7 +797,9 @@ class PSelInvEngine:
                "cache_hits": cls.cache_hits,
                "cache_misses": cls.cache_misses,
                "cache_evictions": cls.cache_evictions,
-               "solve_calls": self.solve_calls}
+               "solve_calls": self.solve_calls,
+               "h2d_packed_batches": self.h2d_packed_batches,
+               "h2d_dense_batches": self.h2d_dense_batches}
         if self.options.stream:
             from .stream import stream_shifts_per_round, stream_wire_bytes
             st = self.program.stream_tables

@@ -61,6 +61,7 @@ from .trees import TreeKind
 __all__ = ["PSelInvProgram", "build_program",
            "make_sweep_overlapped", "make_sweep_stream",
            "analyze_structure", "prepare_values", "prepare_values_many",
+           "PackTables", "pack_tables", "prepare_packed_many",
            "check_values_pattern", "gather_blocks"]
 
 
@@ -879,11 +880,113 @@ def _scatter_blocks(csr: Sequence, nb0: int, b: int, span) -> np.ndarray:
     return W
 
 
+@dataclass(frozen=True, eq=False)
+class PackTables:
+    """Where the structurally nonzero blocks of L̂ and D⁻¹ sit in the
+    device layout — structure only, made once per session.
+
+    Each device shard ``p`` of the ``pr × pc`` grid keeps its own list of
+    L̂ blocks (every ``L̂(C, K)``, ``C ∈ struct(K)``, in K-then-struct
+    order) and of D⁻¹ blocks (every diagonal ``D⁻¹(K, K)``, the grid
+    padding's identity blocks included), padded to the longest list over
+    the shards. ``lh_pos``/``d_pos`` (P, n) give each listed block's flat
+    local slot ``r·nbc + c`` in its shard, ``nbr·nbc`` on padding;
+    ``lh_take``/``d_take`` (P, nbr·nbc) invert them — the list index
+    each dense slot reads, ``n`` (out of range, so zero) where no block
+    is listed. ``lh_shard``/``lh_slot`` (in K-then-struct order, K's
+    run at ``lh_start[K]:lh_start[K + 1]``) and ``d_shard``/``d_slot``
+    (by K) are where the factor loop writes each block. Read off
+    :func:`_shard_blocks`, so they cannot drift from the one layout
+    rule."""
+    nbr: int
+    nbc: int
+    lh_pos: np.ndarray
+    d_pos: np.ndarray
+    lh_take: np.ndarray
+    d_take: np.ndarray
+    lh_shard: np.ndarray
+    lh_slot: np.ndarray
+    lh_start: np.ndarray
+    d_shard: np.ndarray
+    d_slot: np.ndarray
+
+    @property
+    def blocks(self) -> int:
+        """Blocks shipped per matrix: both padded lists of every
+        shard."""
+        return self.lh_pos.size + self.d_pos.size
+
+
+def pack_tables(bs: BlockStructure, nb: int, pr: int, pc: int
+                ) -> PackTables:
+    """The :class:`PackTables` of a structure on a ``pr × pc`` grid."""
+    P, nbr, nbc = pr * pc, nb // pr, nb // pc
+    # every global block's (shard, local slot), by sharding a grid of ids
+    ids = _shard_blocks(np.arange(nb * nb).reshape(nb, nb, 1, 1), nb, 1,
+                        pr, pc).reshape(P, nbr * nbc)
+    home = np.empty(nb * nb, dtype=np.int64)
+    local = np.empty(nb * nb, dtype=np.int64)
+    home[ids] = np.arange(P)[:, None]
+    local[ids] = np.arange(nbr * nbc)[None, :]
+
+    def listed(rows, cols):
+        g = np.asarray(rows, dtype=np.int64) * nb + cols
+        shard = home[g]
+        counts = np.bincount(shard, minlength=P)
+        order = np.argsort(shard, kind="stable")
+        slot = np.empty_like(shard)
+        slot[order] = (np.arange(shard.size)
+                       - np.repeat(np.cumsum(counts) - counts, counts))
+        n = max(int(counts.max()), 1)
+        pos = np.full((P, n), nbr * nbc, dtype=np.int64)
+        pos[shard, slot] = local[g]
+        take = np.full((P, nbr * nbc), n, dtype=np.int32)
+        take[shard, local[g]] = slot
+        return shard, slot, pos, take
+
+    sizes = [len(s) for s in bs.struct]
+    rows = np.concatenate([np.asarray(s, dtype=np.int64)
+                           for s in bs.struct] + [np.zeros(0, np.int64)])
+    cols = np.repeat(np.arange(bs.nsuper), sizes)
+    lh_shard, lh_slot, lh_pos, lh_take = listed(rows, cols)
+    d_shard, d_slot, d_pos, d_take = listed(np.arange(nb), np.arange(nb))
+    return PackTables(nbr=nbr, nbc=nbc, lh_pos=lh_pos, d_pos=d_pos,
+                      lh_take=lh_take, d_take=d_take,
+                      lh_shard=lh_shard, lh_slot=lh_slot,
+                      lh_start=np.concatenate([[0], np.cumsum(sizes)]),
+                      d_shard=d_shard, d_slot=d_slot)
+
+
+def _unpack_blocks(packed: np.ndarray, pos: np.ndarray, nbr: int,
+                   nbc: int) -> np.ndarray:
+    """Packed ``(…, P, n, b, b)`` blocks → the dense ``(…, P, nbr, nbc,
+    b, b)`` shards, zero where nothing is listed: one host scatter of
+    the blocks :class:`PackTables`' ``pos`` places."""
+    lead = packed.shape[:-4]
+    P, _, b, _ = packed.shape[-4:]
+    out = np.zeros(lead + (P, nbr * nbc, b, b), dtype=packed.dtype)
+    p, k = np.nonzero(pos < nbr * nbc)
+    out[..., p, pos[p, k], :, :] = packed[..., p, k, :, :]
+    return out.reshape(lead + (P, nbr, nbc, b, b))
+
+
 def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
                         b: int, pr: int, pc: int
                         ) -> Tuple[np.ndarray, np.ndarray]:
     """Batched host factorization: B same-structure matrices → stacked
-    ``(B, pr*pc, nbr, nbc, b, b)`` shards in ONE structure-driven pass.
+    ``(B, pr*pc, nbr, nbc, b, b)`` shards in ONE structure-driven pass —
+    :func:`prepare_packed_many`'s blocks, scattered into the dense
+    layout inside its ``prep.layout`` span."""
+    tables = pack_tables(bs, nb, pr, pc)
+    return _factor(mats, bs, nb, b, tables, dense=True)
+
+
+def prepare_packed_many(mats: Sequence, bs: BlockStructure, nb: int,
+                        b: int, tables: PackTables
+                        ) -> Tuple[np.ndarray, np.ndarray]:
+    """Batched host factorization emitting only the structural blocks:
+    f64 ``(B, P, n, b, b)`` L̂ and D⁻¹ lists in the order ``tables``
+    gives (:class:`PackTables`), the padding slots zero.
 
     Right-looking supernodal elimination over the filled structure, in
     supernode order, with no pivoting across supernodes — all in f64,
@@ -898,11 +1001,9 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
       ``A(C,C) -= L(C,K)·U(K,C) = L̂(C,K)·A(K,C)``, one
       ``(|C|·b, b) @ (b, |C|·b)`` GEMM.
 
-    The dense (nb0, nb0) block workspace is the same asymptotic
-    footprint as the device layout emitted here, and is freed before
-    the layout copies. Numerics match the supernodal LU + triangular
-    solves of ``supernodal_lu.factorize`` to rounding (≤1e-12, asserted
-    in tests).
+    The grid padding's supernodes get identity D⁻¹. Numerics match the
+    supernodal LU + triangular solves of ``supernodal_lu.factorize`` to
+    rounding (≤1e-12, asserted in tests).
 
     Raises ``ValueError`` naming the offending batch *index* (for B > 1)
     when any matrix's pattern escapes the analyzed structure — callers
@@ -913,7 +1014,17 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     scattered into the zeroed block workspace; with ``nnz``, the entries
     scattered, and ``summed``, how many matrices took the
     duplicate-summing path), ``prep.factor`` (the supernode loop, L̂ and
-    D⁻¹) and ``prep.layout``, each with ``B``."""
+    D⁻¹) and ``prep.layout`` (the padding's identity blocks; for
+    :func:`prepare_values_many` also the dense scatter), each with
+    ``B``."""
+    return _factor(mats, bs, nb, b, tables, dense=False)
+
+
+def _factor(mats: Sequence, bs: BlockStructure, nb: int, b: int,
+            tables: PackTables, dense: bool
+            ) -> Tuple[np.ndarray, np.ndarray]:
+    """:func:`prepare_packed_many`, and with ``dense`` its blocks
+    scattered into the ``(B, P, nbr, nbc, b, b)`` layout."""
     if not len(mats):
         raise ValueError("prepare_values_many needs at least one matrix")
     B, nb0 = len(mats), bs.nsuper
@@ -934,28 +1045,32 @@ def prepare_values_many(mats: Sequence, bs: BlockStructure, nb: int,
     # below is exact
     with TRACER.span("prep.densify", B=B) as span:
         W = _scatter_blocks(csr, nb0, b, span)
+    t = tables
     with TRACER.span("prep.factor", B=B):
-        Lh = np.zeros((B, nb, nb, b, b))
-        Dinv = np.zeros((B, nb, nb, b, b))
+        Lh = np.zeros((B,) + t.lh_pos.shape + (b, b))
+        Dinv = np.zeros((B,) + t.d_pos.shape + (b, b))
         bidx = np.arange(B)
         for K in range(nb0):
             DKK = np.linalg.inv(W[:, K, K])        # = U_KK⁻¹·L_KK⁻¹
-            Dinv[:, K, K] = DKK
+            Dinv[:, t.d_shard[K], t.d_slot[K]] = DKK
             C = [int(i) for i in bs.struct[K]]
             if not C:
                 continue
             c = len(C)
             LhCK = W[:, C, K].reshape(B, c * b, b) @ DKK
-            Lh[:, C, K] = LhCK.reshape(B, c, b, b)
+            at = slice(t.lh_start[K], t.lh_start[K + 1])
+            Lh[:, t.lh_shard[at], t.lh_slot[at]] = LhCK.reshape(B, c, b, b)
             # A(C,C) -= L̂(C,K)·A(K,C) over the whole clique, one GEMM
             AKC = W[:, K, C].transpose(0, 2, 1, 3).reshape(B, b, c * b)
             W[np.ix_(bidx, C, C)] -= (LhCK @ AKC).reshape(
                 B, c, b, c, b).transpose(0, 1, 3, 2, 4)
         del W                         # room for the layout's copies
     with TRACER.span("prep.layout", B=B):
-        Dinv[:, range(nb0, nb), range(nb0, nb)] = np.eye(b)  # padding
-        return (_shard_blocks(Lh, nb, b, pr, pc),
-                _shard_blocks(Dinv, nb, b, pr, pc))
+        Dinv[:, t.d_shard[nb0:], t.d_slot[nb0:]] = np.eye(b)  # padding
+        if dense:
+            return (_unpack_blocks(Lh, t.lh_pos, t.nbr, t.nbc),
+                    _unpack_blocks(Dinv, t.d_pos, t.nbr, t.nbc))
+        return Lh, Dinv
 
 
 def check_grid_devices(pr: int, pc: int) -> None:

@@ -12,8 +12,10 @@ from the :class:`~.batcher.StructureBatcher` and serves each one:
 - per-request pattern check (``check_values_pattern``) so a request
   whose values escape its claimed structure fails *alone* — its batch
   neighbors still solve, bit-identical to their unbatched solves;
-- batched host factorization (``prepare_values_many``) — the supernode
-  loop runs once for the whole batch;
+- batched host factorization (``prepare_packed``) — the supernode
+  loop runs once for the whole batch and emits only the factor's
+  structural blocks, which cross the host-device link and are unpacked
+  on the device;
 - one bucket-padded ``engine.solve`` call (odd batch lengths ride the
   power-of-2 programs), or the on-disk AOT program cache when
   configured;
@@ -38,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.engine import (Grid, PlanOptions, PSelInvEngine, SolveValues,
-                           bucket_size, stack_values, to_device)
+                           bucket_size, stack_values)
 from ..core.pselinv_dist import check_values_pattern
 from ..obs.trace import TRACER
 from .batcher import (BatchWindow, RequestStatus, RequestTimedOut,
@@ -106,7 +108,9 @@ class SelInvServer:
         """The warm engine for A's sparsity pattern. First sight of a
         pattern runs symbolic analysis + compile via
         ``PSelInvEngine.analyze`` (itself structure-cached); every
-        later submit of the pattern is a fingerprint dict hit."""
+        later submit of the pattern is a fingerprint dict hit. A new
+        engine gets its unpack program compiled for every batch shape
+        the window can form, so none compiles while serving."""
         fp = _pattern_fingerprint(A)
         skey = self._fp2skey.get(fp)
         if skey is not None:
@@ -116,6 +120,9 @@ class SelInvServer:
         eng = PSelInvEngine.analyze(A, b=self.cfg.b, grid=self.cfg.grid,
                                     options=self.cfg.options)
         skey = eng.key[0]
+        cfg = self.cfg
+        for B in range(1, cfg.window.max_batch + 1):
+            eng.unpacker(bucket_size(B) if cfg.bucket else B, cfg.dtype)
         self._fp2skey[fp] = skey
         self._engines[skey] = eng
         return eng
@@ -244,15 +251,16 @@ class SelInvServer:
                     r._finish(RequestStatus.FAILED, error=ServeError(
                         f"batch of {len(live)} failed: {e}"))
 
-    def _prepare(self, eng: PSelInvEngine,
-                 reqs: List[SolveRequest]) -> SolveValues:
+    def _prepare(self, eng: PSelInvEngine, reqs: List[SolveRequest]):
         """Host numeric factorization for the batch: matrix-bearing
         requests go through the stacked pass whatever their number,
-        pre-factorized value requests slot in at their position."""
+        pre-factorized value requests slot in at their position. An
+        all-matrix batch comes back as :class:`PackedValues`, a mixed
+        one as dense :class:`SolveValues`."""
         mat_idx = [i for i, r in enumerate(reqs) if r.values is None]
         if len(mat_idx) == len(reqs):        # all-matrix batch (the
             # common path): the stacked prep already IS the batch
-            return eng.prepare_values_many([r.matrix for r in reqs])
+            return eng.prepare_packed([r.matrix for r in reqs])
         per: List[Optional[SolveValues]] = [
             r.values if r.values is not None else None for r in reqs]
         if mat_idx:
@@ -261,15 +269,14 @@ class SelInvServer:
                 per[i] = SolveValues(mv.Lh[j], mv.Dinv[j])
         return stack_values(per)
 
-    def _execute(self, eng: PSelInvEngine, vals: SolveValues,
-                 B: int, bkt: int):
+    def _execute(self, eng: PSelInvEngine, vals, B: int, bkt: int):
         """One device-side sweep for the batch: the engine's counted
         jitted program (the default — ``trace_count`` stays the
         one-compile-per-(structure, bucket) ground truth) or a persisted
         AOT executable from the program cache."""
         if self.cfg.prog_cache is not None:
             comp = self.cfg.prog_cache.get(eng, bkt, self.cfg.dtype)
-            Lh, Dv = to_device(vals.Lh, vals.Dinv, self.cfg.dtype, bkt)
+            Lh, Dv = eng.to_device(vals, self.cfg.dtype, bkt)
             return comp(Lh, Dv)[:B]
         return eng.solve(vals, dtype=self.cfg.dtype,
                          bucket=self.cfg.bucket)
@@ -383,10 +390,14 @@ class SelInvServer:
     def stats(self) -> Dict:
         """One coherent serving snapshot: request/latency/occupancy
         metrics, queue depth, per-structure compiled-bucket census, the
-        engine structure-cache health counters, and the program-cache
+        batches its engines shipped as structural blocks or dense
+        (``h2d_packed_batches``, ``h2d_dense_batches``), the engine
+        structure-cache health counters, and the program-cache
         hit/miss/store counters when one is configured."""
         out = self.metrics.snapshot()
         out["queue_depth"] = self._batcher.pending()
+        for k in ("h2d_packed_batches", "h2d_dense_batches"):
+            out[k] = sum(getattr(e, k) for e in self._engines.values())
         out["structures"] = {
             skey[:12]: {"buckets_used":
                         sorted(self._buckets_used.get(skey, ())),

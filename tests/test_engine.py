@@ -344,6 +344,37 @@ def _reference_blocks(A, bs, nb, b):
     return Lh, Dinv
 
 
+def _dense_factor_loop(mats, bs, nb, b, pr, pc):
+    """The batched factor loop as it was before it emitted packed
+    blocks: L̂ and D⁻¹ written into two dense f64 ``(B, nb, nb, b, b)``
+    grids, identity D⁻¹ on the padding supernodes, then sharded — the
+    bitwise reference of ``prepare_values_many``'s dense output."""
+    from types import SimpleNamespace
+    from repro.core.pselinv_dist import (_scatter_blocks, _shard_blocks,
+                                         check_values_pattern)
+    B, nb0 = len(mats), bs.nsuper
+    csr = [check_values_pattern(M, bs, b) for M in mats]
+    W = _scatter_blocks(csr, nb0, b, SimpleNamespace(set=lambda **kw: 0))
+    Lh = np.zeros((B, nb, nb, b, b))
+    Dinv = np.zeros((B, nb, nb, b, b))
+    bidx = np.arange(B)
+    for K in range(nb0):
+        DKK = np.linalg.inv(W[:, K, K])
+        Dinv[:, K, K] = DKK
+        C = [int(i) for i in bs.struct[K]]
+        if not C:
+            continue
+        c = len(C)
+        LhCK = W[:, C, K].reshape(B, c * b, b) @ DKK
+        Lh[:, C, K] = LhCK.reshape(B, c, b, b)
+        AKC = W[:, K, C].transpose(0, 2, 1, 3).reshape(B, b, c * b)
+        W[np.ix_(bidx, C, C)] -= (LhCK @ AKC).reshape(
+            B, c, b, c, b).transpose(0, 1, 3, 2, 4)
+    Dinv[:, range(nb0, nb), range(nb0, nb)] = np.eye(b)
+    return (_shard_blocks(Lh, nb, b, pr, pc),
+            _shard_blocks(Dinv, nb, b, pr, pc))
+
+
 def _prepared_blocks(mats, b, grid, tmp_path):
     """``engine.prepare_values`` of each matrix at ``grid``, gathered
     to dense (nb, nb, b, b) grids: in this process at 1×1, in a child
@@ -406,15 +437,23 @@ def test_prepare_values_many_matches_per_matrix_path(nx, ny, b, shifts,
     copies, and engine.prepare_values (B=1) of each, match the
     reference to ≤1e-12 (f64), absolute and relative to each block's
     largest entry, for batches of 1, 2 and 4, cliques of up to 4
-    supernodes and grids that pad the supernode count; a bad-pattern
-    member fails with its batch index named, while one matrix alone
-    fails with the pattern check's own message."""
+    supernodes and grids that pad the supernode count; its dense shards
+    are bitwise those of the factor loop that wrote dense grids (before
+    the loop emitted packed blocks); a bad-pattern member fails with its
+    batch index named, while one matrix alone fails with the pattern
+    check's own message."""
     import scipy.sparse as sp
-    from repro.core.pselinv_dist import analyze_structure, gather_blocks
+    from repro.core.pselinv_dist import (analyze_structure, gather_blocks,
+                                         prepare_values_many)
     A = sparse.laplacian_2d(nx, ny)
     I_A = sp.identity(A.shape[0])
     mats = [A + c * I_A for c in shifts]
     bs, nb = analyze_structure(A, b, *grid)
+    got = prepare_values_many(mats, bs, nb, b, *grid)
+    for g, r in zip(got, _dense_factor_loop(mats, bs, nb, b, *grid),
+                    strict=True):
+        assert g.dtype == r.dtype and g.shape == r.shape
+        assert np.array_equal(g.view(np.uint64), r.view(np.uint64))
     refs = [_reference_blocks(M, bs, nb, b) for M in mats]
     if grid != (1, 1):
         assert nb > bs.nsuper
@@ -538,3 +577,98 @@ def test_prepare_values_many_never_densifies(monkeypatch):
     got = eng.prepare_values_many(mats)
     assert np.array_equal(got.Lh, ref.Lh)
     assert np.array_equal(got.Dinv, ref.Dinv)
+
+
+_PACKED_TRANSFER = """
+import numpy as np
+import scipy.sparse as sp
+import jax.numpy as jnp
+from repro.core import sparse
+from repro.core.engine import Grid, PSelInvEngine, PackedValues
+
+A = sparse.laplacian_2d(9, 8)
+I = sp.identity(A.shape[0])
+mats = [A + c * I for c in (0.0, 0.5, 2.0)]
+eng = PSelInvEngine.analyze(A, b=8, grid=Grid({pr}, {pc}))
+assert (eng.nb > eng.bs.nsuper) == ({pr} > 1)   # padding supernodes
+dense = eng.prepare_values_many(mats)
+packed = eng.prepare_packed(mats)
+assert packed.Lh.shape[2] < eng.nb * eng.nb // ({pr} * {pc})
+
+
+def same(x, y):
+    x, y = np.asarray(x), np.asarray(y)
+    return (x.dtype == y.dtype and x.shape == y.shape
+            and np.array_equal(x.view(np.uint32), y.view(np.uint32)))
+
+
+# what the dense path sent before: a host cast and a device zero-pad
+pad = ((0, 1),) + ((0, 0),) * 5
+want = [jnp.pad(jnp.asarray(x, jnp.float32), pad) for x in dense]
+got = eng.to_device(packed, jnp.float32, 4)
+assert all(same(g, w) for g, w in zip(got, want, strict=True))
+assert all(g.sharding == eng.sharding(True) for g in got)
+# one matrix, no batch axis
+one = PackedValues(packed.Lh[1], packed.Dinv[1])
+got = eng.to_device(one, jnp.float32)
+assert all(same(g, jnp.asarray(x[1], jnp.float32))
+           for g, x in zip(got, dense, strict=True))
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_packed_transfer_is_bitwise_the_dense_one(grid):
+    """Shipping the structural blocks and unpacking them on the device
+    gives, to the bit, the arrays the dense path gave: the host's f32
+    cast of the dense shards, zero-padded from B=3 to bucket 4 by
+    ``jnp.pad`` (pad lanes all zero, D⁻¹ included), placed as the sweep
+    takes them — on a grid whose padding supernodes carry identity D⁻¹
+    blocks too — and for one matrix without the batch axis."""
+    pr, pc = grid
+    out = run_sub(_PACKED_TRANSFER.format(pr=pr, pc=pc), ndev=pr * pc)
+    assert "OK" in out
+
+
+_SWEEP_TEXT = """
+import hashlib
+import jax
+import jax.numpy as jnp
+from repro.core import sparse
+from repro.core.engine import Grid, PSelInvEngine
+
+eng = PSelInvEngine.analyze(sparse.laplacian_2d(16, 16), b=8,
+                            grid=Grid({pr}, {pc}))
+assert eng.nb == 32
+shape = ((4,) if {batched} else ()) + (
+    {pr} * {pc}, eng.nb // {pr}, eng.nb // {pc}, 8, 8)
+sd = jax.ShapeDtypeStruct(shape, jnp.float32)
+fn = jax.jit(eng._shard_mapped_sweep({batched}, counted=False))
+print(hashlib.sha256(fn.trace(sd, sd).lower().as_text().encode())
+      .hexdigest())
+"""
+
+#: sha256 of the overlapped sweep's StableHLO text (jax 0.9.0), as the
+#: sweep lowered before the served batch shipped packed blocks
+_SWEEP_SHA256 = {
+    (1, 1, False): "d1f396a3d626d80f30a9415451928be3"
+                   "7edb47aa79b5ad325f3cb373cb909f6a",
+    (1, 1, True): "290d010711bbdcec937f721494d87cba"
+                  "a626813a4965dcaaf9ffdebe8ea11f0b",
+    (2, 2, False): "5738aefa6142c5209e6c55596d88d428"
+                   "c576a7ca449d34fb8e3d515460f8115d",
+    (2, 2, True): "b9f0844253fed0dcc31cba5cb84ff4a6"
+                  "86c16325b29901e61b6705f7df2eaa33",
+}
+
+
+@pytest.mark.parametrize("pr,pc,batched", list(_SWEEP_SHA256),
+                         ids=lambda v: str(v))
+def test_sweep_stablehlo_is_unchanged(pr, pc, batched):
+    """The overlapped sweep at nb=32 — single and batched, grid 1×1 and
+    2×2 — lowers to the same StableHLO text as before the transfer took
+    packed blocks: the unpack is a program of its own, and the sweep is
+    given the dense inputs it always took."""
+    out = run_sub(_SWEEP_TEXT.format(pr=pr, pc=pc, batched=batched),
+                  ndev=pr * pc)
+    assert out.split()[-1] == _SWEEP_SHA256[(pr, pc, batched)]

@@ -301,6 +301,75 @@ def test_server_through_progcache(tmp_path):
     assert st["prog_cache"]["stores"] == 1
 
 
+_PACKED_SERVE = """
+import numpy as np
+import scipy.sparse as sp
+import jax.numpy as jnp
+from repro.core import sparse
+from repro.core.engine import Grid, PSelInvEngine
+from repro.serve import (BatchWindow, RequestStatus, SelInvServer,
+                         ServeConfig)
+
+A = sparse.laplacian_2d(9, 8)
+I = sp.identity(A.shape[0])
+mats = [A + c * I for c in (0.25, 1.0, 3.0)]
+srv = SelInvServer(ServeConfig(
+    b=8, grid=Grid({pr}, {pc}), dtype=jnp.float32,
+    window=BatchWindow(max_batch=4, max_wait_ms=1.0)))
+eng = srv.engine_for(A)
+st0 = srv.stats()
+reqs = [srv.submit(M) for M in mats]
+srv.pump(force=True)
+assert all(r.status is RequestStatus.SOLVED for r in reqs)
+st = srv.stats()
+assert st["h2d_packed_batches"] - st0["h2d_packed_batches"] == 1
+assert st["h2d_dense_batches"] == st0["h2d_dense_batches"]
+# the same batch forced through dense SolveValues: the same bits
+dense = np.asarray(eng.solve(eng.prepare_values_many(mats),
+                             dtype=jnp.float32, bucket=True))
+for i, r in enumerate(reqs):
+    got = np.asarray(r.result())
+    assert np.array_equal(got.view(np.uint32), dense[i].view(np.uint32))
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_served_packed_batch_is_bitwise_the_dense_one(grid):
+    """A served all-matrix batch ships its factors packed, and its
+    answers are bitwise those of the same batch solved through dense
+    ``SolveValues`` — the sweep is given the same inputs either way."""
+    pr, pc = grid
+    assert "OK" in run_sub(_PACKED_SERVE.format(pr=pr, pc=pc),
+                           ndev=pr * pc)
+
+
+def test_mixed_batch_of_values_and_matrices_is_served(g11_server):
+    """Pre-factorized values and matrices in one batch take the dense
+    path and are served correctly: each answer is bitwise the one the
+    same position gets in an all-matrix batch of the same matrices."""
+    srv = g11_server
+    A = sparse.laplacian_2d(12, 8)
+    I_A = sp.identity(A.shape[0])
+    mats = [A + c * I_A for c in (0.0, 0.5, 1.0)]
+    eng = srv.engine_for(A)
+    st0 = srv.stats()
+    mixed = [srv.submit_values(eng, eng.prepare_values(mats[0])),
+             srv.submit(mats[1]), srv.submit(mats[2])]
+    assert srv.pump(force=True) == 1
+    whole = [srv.submit(M) for M in mats]
+    srv.pump(force=True)
+    st = srv.stats()
+    assert st["h2d_dense_batches"] - st0["h2d_dense_batches"] == 1
+    assert st["h2d_packed_batches"] - st0["h2d_packed_batches"] == 1
+    for m, w in zip(mixed, whole, strict=True):
+        assert m.status is w.status is RequestStatus.SOLVED
+        got, want = np.asarray(m.result()), np.asarray(w.result())
+        assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    ref = np.asarray(eng.solve(mats[0], dtype=jnp.float32))
+    assert abs(np.asarray(mixed[0].result()) - ref).max() <= _F32_TOL
+
+
 # ---------------------------------------------------------------------
 # failure isolation on a real 2x1 mesh (subprocess, f64)
 # ---------------------------------------------------------------------

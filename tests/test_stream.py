@@ -532,7 +532,8 @@ def test_stream_engine_session_end_to_end():
         # demand
         cache_keys = {"table_bytes", "cache_engines", "cache_hits",
                       "cache_misses", "cache_evictions"}
-        gauge_keys = {"solve_calls"}
+        gauge_keys = {"solve_calls", "h2d_packed_batches",
+                      "h2d_dense_batches"}
         comm_keys = {"ppermute_rounds", "wire_bytes", "recv_bytes_max",
                      "recv_bytes_mean"}
         s = eng.stats()

@@ -186,10 +186,62 @@ def test_serve_batch_children_cover_it_and_carry_rids(traced):
     (h2d,) = [s for s in spans if s.name == "engine.h2d"]
     assert parent("engine.h2d") == "engine.solve"
     assert h2d.attrs["B"] == 3
-    # bucket 4 of f32 values: what went to the device, padding included
-    nb = srv.engine_for(A).nb
-    assert h2d.attrs["bytes"] == 2 * 4 * nb * nb * 8 * 8 * 4
+    # bucket 4 of f32 structural blocks: what went to the device,
+    # padding included
+    blocks = srv.engine_for(A).pack_tables().blocks
+    assert h2d.attrs["packed"] == 1 and h2d.attrs["blocks"] == blocks
+    assert h2d.attrs["bytes"] == 4 * blocks * 8 * 8 * 4
     assert not [s for s in spans if s.name == "jax.compile"]
+
+
+def test_packed_batch_after_dense_warmup_compiles_nothing(traced):
+    """As the served benchmark warms up — ``engine_for``, then a dense
+    solve of zeros for every batch length the window can form — a
+    served batch of matrices then traces nothing: its unpack programs
+    were compiled by ``engine_for`` and its sweep by the dense warm-up.
+    Its ``engine.h2d`` span says the blocks went packed, how many per
+    lane and how many bytes, and ``stats()`` counts the batch. A dense
+    warm-up's ``bytes`` are its host arrays, not the lanes padded on the
+    device."""
+    from repro.core.engine import SolveValues
+    from repro.serve import BatchWindow, SelInvServer, ServeConfig
+
+    A = sparse.laplacian_2d(16, 16)
+    srv = SelInvServer(ServeConfig(
+        b=8, grid=Grid(1, 1),
+        window=BatchWindow(max_batch=4, max_wait_ms=1.0)))
+    eng = srv.engine_for(A)
+    # the engine is the process's cached session: count from here
+    st0 = srv.stats()
+    zeros = np.zeros((4, 1, eng.nb, eng.nb, 8, 8), np.float32)
+    for B in range(1, 5):
+        np.asarray(eng.solve(SolveValues(zeros[:B], zeros[:B]),
+                             bucket=True))
+    dense = [s.attrs for s in traced.spans() if s.name == "engine.h2d"]
+    assert [(a["packed"], a["B"], a["blocks"], a["bytes"]) for a in dense] \
+        == [(0, B, 2 * eng.nb ** 2, B * zeros[0].nbytes * 2)
+            for B in range(1, 5)]
+    traced.clear()
+    mats = [A + s * sp.identity(A.shape[0]) for s in (0.0, 0.5, 1.0)]
+    reqs = [srv.submit(M) for M in mats]
+    srv.drain()
+    assert all(r.done() and r.result(0).shape[0] == 1 for r in reqs)
+    spans = traced.spans()
+    assert not [s for s in spans if s.name == "jax.compile"
+                and s.attrs["stage"] == "trace"]
+    run = {"spans": [(s.name, s.dur_us / 1e6, s.attrs) for s in spans]}
+    assert bench_run.read_metric(REPO, "window_compiles.served", run) == 0
+    (h2d,) = [s for s in spans if s.name == "engine.h2d"]
+    t = eng.pack_tables()
+    assert h2d.attrs["packed"] == 1 and h2d.attrs["B"] == 3
+    assert h2d.attrs["blocks"] == t.blocks
+    # 4 lanes (bucket 4) of every shard's padded lists, f32 8×8 blocks
+    assert h2d.attrs["bytes"] == 4 * (t.lh_pos.size + t.d_pos.size) \
+        * 8 * 8 * 4
+    st = srv.stats()
+    assert {k: st[k] - st0[k] for k in ("h2d_packed_batches",
+                                        "h2d_dense_batches")} \
+        == {"h2d_packed_batches": 1, "h2d_dense_batches": 4}
 
 
 _PREP = ("engine.prepare_values", "engine.prepare_values_many")
